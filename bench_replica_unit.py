@@ -24,6 +24,11 @@ gates progress — stated honestly in the record as checkpointing=off.
 
 Usage: python bench_replica_unit.py [--n 100] [--blocks 16] [--batch 128]
            [--modes plain,qc] [--out bench_results/replica_unit_r05.jsonl]
+
+Platform selection follows --verifier: ``tpu`` requires JAX's default
+backend to be a TPU and exits nonzero anywhere else; ``cpu`` selects the
+CPU platform in-process. Every record carries `platform`, `device_kind`
+and `device_count`.
 """
 
 from __future__ import annotations
@@ -36,12 +41,7 @@ import sys
 import time
 from typing import Dict, List
 
-if os.environ.get("BENCH_FORCE_CPU") == "1":
-    # exercise --verifier tpu plumbing without the chip (must run before
-    # any simple_pbft_tpu import touches a jax backend)
-    from simple_pbft_tpu import force_cpu
-
-    force_cpu()
+import simple_pbft_tpu
 
 
 def _emit(rec: dict, out_path: str | None) -> None:
@@ -146,18 +146,14 @@ async def run_mode(
         # the per-replica form of the TPU thesis: one replica, verify
         # offloaded through the coalescing service (async dispatch
         # overlaps the device pass with the next sweep's decode)
-        import simple_pbft_tpu
         from simple_pbft_tpu.crypto.coalesce import VerifyService
         from simple_pbft_tpu.crypto.tpu_verifier import TpuVerifier
 
         simple_pbft_tpu.enable_jit_cache()
         dev = TpuVerifier(initial_keys=n + n_clients + 8)
-        # default warm budget covers a maximal drain sweep; RU_MAX_SWEEP
-        # shrinks it for CPU smoke runs (each bucket is a 40-150 s
-        # compile on a small CPU host; cached on the chip host)
+        # warm budget covers a maximal drain sweep
         dev.warm_for_population(
-            [kp.pub for kp in keys.values()],
-            max_sweep=int(os.environ.get("RU_MAX_SWEEP", "4096")),
+            [kp.pub for kp in keys.values()], max_sweep=4096
         )
         svc = VerifyService(dev)
 
@@ -214,12 +210,10 @@ async def run_mode(
         "sig_cache_hits": replica.metrics.get("sig_cache_hits", 0),
         "checkpointing": "emit-only (no peers answer)",
         "verifier": getattr(replica.verifier, "name", "?"),
+        **simple_pbft_tpu.device_stamp(),
     }
     if svc is not None:
-        import jax
-
         rec.update(
-            platform=jax.devices()[0].platform,
             svc_device_passes=svc.device_passes,
             svc_cpu_passes=svc.cpu_passes,
             # null until a device pass ran — the EMA's constructor seed
@@ -245,6 +239,9 @@ async def main() -> None:
         "--out", default=os.path.join("bench_results", "replica_unit_r05.jsonl")
     )
     args = ap.parse_args()
+    simple_pbft_tpu.select_platform(
+        args.verifier == "tpu", "bench_replica_unit.py --verifier tpu"
+    )
     for mode in args.modes.split(","):
         mode = mode.strip()
         assert mode in ("plain", "qc"), mode

@@ -1,7 +1,7 @@
 """Static device cost model for the verify kernels (ISSUE 14).
 
-This codifies the analysis that produced
-``bench_results/verify_1m_decomposition_r05.md``: for each jit shape
+This codifies the analysis of the round-5 builder memo on the verify
+kernel (2026-07-31, not reproduced since): for each jit shape
 (mode, window, bucket) the kernel's dominant resource draws are an
 analytic function of the geometry —
 

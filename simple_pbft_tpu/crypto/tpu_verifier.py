@@ -423,9 +423,8 @@ class WireBatch:
 
     Window extraction, limb decomposition and the sign bit move onto the
     device (ops/comb.fused_verify_wire_kernel), so this is ~100 bytes on
-    the host->device link per signature instead of ~290 — the e2e
-    throughput bound when the chip sits behind a network tunnel, and
-    saved HBM/PCIe traffic when it doesn't."""
+    the host->device link per signature instead of ~290, and the host
+    sheds the unpack work."""
 
     def __init__(self, n: int, wire: np.ndarray, a_idx: np.ndarray,
                  precheck: np.ndarray):
@@ -491,6 +490,29 @@ _JIT_CACHE: Dict[str, object] = {}
 # host) instead of one compile plus N-1 cache hits. Steady-state cost is
 # nil: a single chip serializes execution anyway.
 _DEVICE_LOCK = threading.Lock()
+
+
+class _CompileWatch:
+    """Counts, while open, the XLA compile requests that consulted the
+    persistent cache and how many of them it served (jax.monitoring
+    events) — what tells a warm bucket that was read from disk from one
+    that was compiled. Both stay 0 when nothing compiled (the program
+    was already live in this process) or the cache is off."""
+
+    def __enter__(self) -> "_CompileWatch":
+        self.requests = 0
+        self.hits = 0
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    def __exit__(self, *_exc) -> None:
+        jax.monitoring.unregister_event_listener(self._on_event)
 
 
 def _shared_jit(mode: str):
@@ -589,20 +611,26 @@ class TpuVerifier:
                 # the kernel on its LOCAL batch shard, so the Pallas
                 # Mosaic accumulator needs no GSPMD partitioning rule
                 # and stays active on TPU meshes (accum resolves per
-                # backend: Pallas on TPU — the measured ~28% win — XLA
-                # fori_loop on the CPU dryrun mesh). Per-shard batches
-                # stay powers of two (bucket sizes / power-of-two mesh),
-                # which the kernel's batch inversion requires.
-                try:
-                    from jax import shard_map
-                except ImportError:  # pragma: no cover — older jax
-                    from jax.experimental.shard_map import shard_map
-
+                # backend: Pallas on TPU, XLA fori_loop on the CPU mesh).
+                # Per-shard batches stay powers of two (bucket sizes /
+                # power-of-two mesh), which the kernel's batch inversion
+                # requires.
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as PS
 
                 # wire kernel: args are (wire (B,96), a_idx (B,),
                 # f_table (replicated), precheck (B,)) — batch axis
-                # LEADS the wire array, so shards split rows
+                # LEADS the wire array, so shards split rows.
+                #
+                # check_vma=False: under jax 0.9.0's varying-axes check a
+                # pallas_call fails to trace here — compiled, because its
+                # out_shape carries no vma ("vma on jax.ShapeDtypeStruct
+                # must not be None"); interpreted (how tier-1 runs this
+                # path), inside the interpreter's own grid scan even with
+                # the vma given ("Scan carry input and output got
+                # mismatched varying manual axes"). The body has no
+                # collectives and every output is per-shard, so the
+                # check has nothing to protect.
                 self._fn = jax.jit(
                     shard_map(
                         functools.partial(
@@ -614,6 +642,7 @@ class TpuVerifier:
                             PS(axis),
                         ),
                         out_specs=PS(axis),
+                        check_vma=False,
                     )
                 )
             else:
@@ -664,6 +693,13 @@ class TpuVerifier:
         self.post_warm_compiles = 0
         self.bucket_hits: Dict[int, int] = {}
         self._warm_done = False
+        # one row per warmed bucket: wall seconds of its first pass
+        # (compile or cache load included) and the persistent cache's
+        # part in it — see _CompileWatch
+        self.warm_log: List[dict] = []
+        # items answered by the over-cap CPU fallback instead of the
+        # device (keys beyond the bank's max_keys)
+        self.overcap_fallback_items = 0
 
     @classmethod
     def for_population(
@@ -729,7 +765,15 @@ class TpuVerifier:
         # bank slot, skewing the very capacity this warmup pins
         dummy = BatchItem(bytes(31), b"", bytes(64))
         for b in buckets:
-            self.verify_batch([dummy] * b)
+            t0 = time.perf_counter()
+            with _CompileWatch() as watch:
+                self.verify_batch([dummy] * b)
+            self.warm_log.append({
+                "bucket": b,
+                "seconds": round(time.perf_counter() - t0, 3),
+                "compile_requests": watch.requests,
+                "cache_hits": watch.hits,
+            })
 
     def _record_shape(self, size: int) -> bool:
         """Track the jit signature this dispatch hits. Must run AFTER
@@ -766,7 +810,24 @@ class TpuVerifier:
             "shape_compiles": self.shape_compiles,
             "post_warm_compiles": self.post_warm_compiles,
             "bucket_hits": {str(k): v for k, v in sorted(self.bucket_hits.items())},
+            "overcap_fallback_items": self.overcap_fallback_items,
         }
+
+    def lowered_text(self, size: int) -> str:
+        """The program this verifier's jit lowers to at batch bucket
+        `size` and the bank's current table shape, as text. chip_smoke.py
+        reads it to show the Pallas accumulator went through Mosaic (a
+        ``tpu_custom_call``) and was not interpreted."""
+        if self._mode != "fused":
+            raise ValueError("lowered_text covers the fused wire kernel only")
+        struct = jax.ShapeDtypeStruct
+        rows = self._bank._cap * self._bank._rows_per_key
+        return self._fn.lower(
+            struct((size, 96), jnp.uint8),
+            struct((size,), jnp.int32),
+            struct((rows, comb.ROW), jnp.int32),
+            struct((size,), jnp.bool_),
+        ).as_text()
 
     def verify_batch(self, items: Sequence[BatchItem]) -> List[bool]:
         return self.dispatch_batch(items)()
@@ -896,6 +957,7 @@ class TpuVerifier:
                 fb_out = self._cpu_fb.verify_batch(
                     [items[i] for i in fallback]
                 )
+                self.overcap_fallback_items += len(fallback)
                 for i, ok_i in zip(fallback, fb_out):
                     verdict[i] = ok_i
             return verdict[: prep.n].tolist()

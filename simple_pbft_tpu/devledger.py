@@ -1,8 +1,8 @@
 """Device-plane event ledger (ISSUE 14 tentpole).
 
 The verify plane's cost claim — bandwidth-bound at 777k verifies/s/chip
-with a measured route to ~1.05M (`bench_results/
-verify_1m_decomposition_r05.md`) — was produced by hand, once. Every
+with a route to ~1.05M (a round-5 builder memo, 2026-07-31, not
+reproduced since) — was produced by hand, once. Every
 other plane got continuous instrumentation (spans in PR 4, wire
 accounting in PR 9); the device plane, where per-role crypto cost
 dominates, stayed a markdown memo. This module is the continuously-

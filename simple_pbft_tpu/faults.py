@@ -921,8 +921,8 @@ class StallableDevice:
     """Wraps a device verifier (the dispatch_batch protocol VerifyService
     consumes); while stalled, every finisher blocks until the stall
     expires or release() is called. Dispatch itself stays fast — the
-    stall models a device/tunnel that accepted work and went silent, the
-    r5 qc256 wedge shape the VerifyService watchdog must catch."""
+    stall models a device that accepted work and went silent, the r5
+    qc256 wedge shape the VerifyService watchdog must catch."""
 
     def __init__(self, inner) -> None:
         self._inner = inner

@@ -5,6 +5,16 @@ starts 4 node processes and fires one client (run.bat:19-26). This
 launcher generates a fresh deployment, spawns N replica processes, runs a
 client workload against them, prints the client's stats line, and tears
 everything down — cross-platform, any committee size.
+
+Each node is its own process, and a chip belongs to one process: the
+first node to touch JAX holds it and every other one fails at start-up
+(on the v5e machine, within seconds: "Unable to initialize backend 'tpu':
+ABORTED: ... libtpu multi-process lockfile"; PR 21 chip run). So
+``--verifier tpu`` is refused for more than one node. The arrangement that drives the chip today is the in-process
+committee — ``committee.LocalCommittee`` with every replica sharing one
+``VerifyService``, as chip_smoke.py (through ``node.make_verifier``) and
+bench_consensus.py build it; a verify-service process that replica
+processes submit to is ROADMAP R7.
 """
 
 from __future__ import annotations
@@ -32,6 +42,18 @@ def main() -> None:
                     "node (<deploy>/log/r*.spans.jsonl; join with "
                     "tools/slot_trace.py)")
     args = ap.parse_args()
+    if args.verifier == "tpu" and args.n > 1:
+        sys.exit(
+            f"launch: --verifier tpu with -n {args.n} would start "
+            f"{args.n} processes that each need the chip, and a chip "
+            "belongs to ONE process (the rest fail at start-up on the "
+            "libtpu lockfile). "
+            "Run the committee in one process instead: "
+            "`python chip_smoke.py` or `python bench_consensus.py "
+            "--verifier tpu` (a LocalCommittee whose replicas share one "
+            "VerifyService). Separate replica processes sharing a chip "
+            "wait on ROADMAP R7."
+        )
 
     from . import deploy
 

@@ -1,10 +1,13 @@
 """ctypes loader for the native host-prep library (pbft_native.cpp).
 
-The shared object is built on demand with g++ (cached next to the source,
-rebuilt when the source is newer) and loaded via ctypes — no pybind11
-dependency. Every entry point has a pure-Python fallback so the framework
-works on machines without a toolchain; `available()` reports which path is
-active and the bench records it.
+The shared object is built on demand with g++ next to the source and
+loaded via ctypes — no pybind11 dependency. The built file's name carries
+a hash of the source's content and the compiler flags (`_ensure_built`),
+so what runs is always a build of the source that is there: a binary is
+never trusted for its mtime, and never loaded when its source is absent.
+Every entry point has a pure-Python fallback so the framework works on
+machines without a toolchain; `status()` reports which path is active
+and chip_smoke.py refuses to call the chip proven on the fallback.
 
 API (numpy in, numpy out, zero per-item Python work):
 - challenge_batch(r, a, msgs) -> (n, 32) uint8 little-endian scalars
@@ -15,22 +18,21 @@ API (numpy in, numpy out, zero per-item Python work):
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
 import threading
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 log = logging.getLogger(__name__)
 
 _SRC = os.path.join(os.path.dirname(__file__), "pbft_native.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "_pbft_native.so")
 _SRC_BLS = os.path.join(os.path.dirname(__file__), "bls381.cpp")
-_SO_BLS = os.path.join(os.path.dirname(__file__), "_bls381.so")
 _SRC_ED = os.path.join(os.path.dirname(__file__), "ed25519.cpp")
-_SO_ED = os.path.join(os.path.dirname(__file__), "_ed25519.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -48,12 +50,18 @@ _u8p = np.ctypeslib.ndpointer(dtype=np.uint8, flags="C_CONTIGUOUS")
 _i64p = np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")
 
 
-def _build_so(src: str, so: str, extra=()) -> bool:
+# source path -> {"path": the .so in use, "built": compiled by THIS
+# process (False = a build of the same source and flags was already
+# there)}; what status() reports per library
+_builds: Dict[str, dict] = {}
+
+
+def _build_so(src: str, so: str, flags: Sequence[str]) -> bool:
     # per-process temp name: concurrent builders (multi-process launch,
     # parallel test workers) must never interleave linker output in a
     # shared file; os.replace keeps the final install atomic
     tmp = f"{so}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", *extra, "-shared", "-fPIC", "-o", tmp, src]
+    cmd = ["g++", *flags, "-o", tmp, src]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so)
@@ -69,27 +77,55 @@ def _build_so(src: str, so: str, extra=()) -> bool:
         return False
 
 
-def _load_library(src: str, so: str, configure, extra=()) -> Optional[ctypes.CDLL]:
-    """Shared build-on-demand loader: rebuild when the source is newer
-    (tolerating a missing source by using the cached .so), CDLL-load,
-    then run ``configure(lib)`` (argtypes + optional selftest; raise
-    AttributeError for stale exports, return None to reject). Any
-    failure degrades to the caller's Python fallback."""
-    name = os.path.basename(so)
-    if not _ensure_built(src, so, extra=extra):
+def _ensure_built(src: str, extra: Sequence[str] = ()) -> Optional[str]:
+    """Path of a build of `src` (building it if needed), None when there
+    is no source or no working compiler. Shared by the ctypes loader
+    below and the extension loader.
+
+    The file name is `_<stem>.<hash>.so`, the hash taken over the
+    source's CONTENT and the flags: a copy of the tree may give files any
+    mtime, and a binary left behind by other source must not be picked up
+    — so freshness is a property of the name, and an absent source means
+    nothing is loaded. Builds of older content are removed."""
+    flags = ["-O3", *extra, "-shared", "-fPIC"]
+    try:
+        with open(src, "rb") as f:
+            body = f.read()
+    except OSError as e:
+        log.warning("native source unreadable (%s) — using Python fallback", e)
+        return None
+    digest = hashlib.sha256(body + "\0".join(flags).encode()).hexdigest()[:16]
+    stem = os.path.splitext(os.path.basename(src))[0]
+    prefix = os.path.join(os.path.dirname(src), f"_{stem}")
+    so = f"{prefix}.{digest}.so"
+    built = not os.path.exists(so)
+    if built:
+        if not _build_so(src, so, flags):
+            return None
+        for stale in glob.glob(f"{prefix}*.so"):
+            if stale != so:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass
+    _builds[src] = {"path": so, "built": built}
+    return so
+
+
+def _load_library(src: str, configure, extra=()) -> Optional[ctypes.CDLL]:
+    """Shared build-on-demand loader: `_ensure_built`, CDLL-load, then
+    run ``configure(lib)`` (argtypes + optional selftest; return None to
+    reject). Any failure degrades to the caller's Python fallback."""
+    so = _ensure_built(src, extra=extra)
+    if so is None:
         return None
     try:
         lib = ctypes.CDLL(so)
     except OSError as e:
-        log.warning("%s load failed: %s — using Python fallback", name, e)
+        log.warning("%s load failed: %s — using Python fallback",
+                    os.path.basename(so), e)
         return None
-    try:
-        return configure(lib)
-    except AttributeError as e:
-        # a stale cached .so missing newer exports (e.g. source file
-        # absent so no rebuild happened): degrade to the Python path
-        log.warning("%s stale/incomplete: %s — Python fallback", name, e)
-        return None
+    return configure(lib)
 
 
 def _configure_hostprep(lib):
@@ -112,7 +148,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if not _tried:
             _tried = True
             _lib = _load_library(
-                _SRC, _SO, _configure_hostprep, extra=("-fopenmp",)
+                _SRC, _configure_hostprep, extra=("-fopenmp",)
             )
         return _lib
 
@@ -149,7 +185,7 @@ def _load_bls() -> Optional[ctypes.CDLL]:
     with _bls_lock:
         if not _bls_tried:
             _bls_tried = True
-            _bls_lib = _load_library(_SRC_BLS, _SO_BLS, _configure_bls)
+            _bls_lib = _load_library(_SRC_BLS, _configure_bls)
         return _bls_lib
 
 
@@ -170,7 +206,7 @@ def _load_ed() -> Optional[ctypes.CDLL]:
     with _ed_lock:
         if not _ed_tried:
             _ed_tried = True
-            _ed_lib = _load_library(_SRC_ED, _SO_ED, _configure_ed)
+            _ed_lib = _load_library(_SRC_ED, _configure_ed)
         return _ed_lib
 
 
@@ -422,7 +458,6 @@ def sha512_batch(msgs: Sequence[bytes]) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 _SRC_CANON = os.path.join(os.path.dirname(__file__), "canonjson.cpp")
-_SO_CANON = os.path.join(os.path.dirname(__file__), "_canonjson.so")
 _canon_lock = threading.Lock()
 _canon_mod = None
 _canon_tried = False
@@ -432,19 +467,6 @@ def _python_includes():
     import sysconfig
 
     return [f"-I{sysconfig.get_path('include')}"]
-
-
-def _ensure_built(src: str, so: str, extra=()) -> bool:
-    """Shared freshness check + build-on-demand (used by the ctypes
-    loader below and the extension loader): rebuild when the source is
-    newer, tolerate a missing source by trusting the cached .so."""
-    try:
-        fresh = os.path.exists(so) and (
-            os.path.getmtime(so) >= os.path.getmtime(src)
-        )
-    except OSError:  # source missing: use the existing .so as-is
-        fresh = os.path.exists(so)
-    return fresh or _build_so(src, so, extra=extra)
 
 
 def _load_canonjson():
@@ -459,15 +481,14 @@ def _load_canonjson():
         if _canon_tried:
             return _canon_mod
         _canon_tried = True  # every exit below is final (no per-call retry)
-        if not _ensure_built(_SRC_CANON, _SO_CANON, extra=_python_includes()):
+        so = _ensure_built(_SRC_CANON, extra=_python_includes())
+        if so is None:
             return None
         try:
             import importlib.machinery
             import importlib.util
 
-            loader = importlib.machinery.ExtensionFileLoader(
-                "_canonjson", _SO_CANON
-            )
+            loader = importlib.machinery.ExtensionFileLoader("_canonjson", so)
             spec = importlib.util.spec_from_loader("_canonjson", loader)
             mod = importlib.util.module_from_spec(spec)
             loader.exec_module(mod)
@@ -510,3 +531,21 @@ def canonjson_encode(obj):
 
 def canonjson_available() -> bool:
     return _load_canonjson() is not None
+
+
+def status() -> Dict[str, dict]:
+    """Which of the four native libraries are in use (loading, and if
+    need be building, each one now), from which file, and whether this
+    process compiled it."""
+    loaded = {
+        _SRC: available(),
+        _SRC_ED: ed25519_available(),
+        _SRC_BLS: bls_available(),
+        _SRC_CANON: canonjson_available(),
+    }
+    return {
+        os.path.splitext(os.path.basename(src))[0]: {
+            "loaded": ok, **_builds.get(src, {"path": None, "built": False})
+        }
+        for src, ok in loaded.items()
+    }

@@ -38,42 +38,55 @@ def make_transport(name: str, node_id: str, dep: "deploy.Deployment"):
 
 def make_verifier(
     name: str,
-    dep=None,
+    pubkeys=None,
     verify_max_pending: int = 65536,
     verify_deadline: float = 60.0,
+    max_batch: int = 8192,
+    cpu_cutoff=None,
 ):
+    """Build the named verify backend. For ``tpu``, `pubkeys` is the
+    deployment's published key population (committee + enrolled
+    clients): the one process that holds the chip — a node, or
+    chip_smoke.py's in-process committee — gets the same object from
+    here. `max_batch` and `cpu_cutoff` are VerifyService's own knobs at
+    their defaults; only chip_smoke.py's CPU dry run shrinks them."""
     if name == "tpu":
+        from . import enable_jit_cache
         from .crypto.coalesce import VerifyService
         from .crypto.tpu_verifier import TpuVerifier
 
+        # before the first jit: the warm below is minutes of XLA
+        # compiles that a restart must not pay twice
+        enable_jit_cache()
         # overload knobs (docs/RESILIENCE.md): bounded admission rejects
         # with Overloaded past max_pending; the dispatch-deadline
         # watchdog fails a stalled device sweep over to the CPU verifier
         # and quarantines the device path (deadline <= 0 disables it)
         svc_kw = dict(
+            max_batch=max_batch,
+            cpu_cutoff=cpu_cutoff,
             max_pending=verify_max_pending,
             dispatch_deadline=verify_deadline if verify_deadline > 0 else None,
         )
-        if dep is None:
+        if pubkeys is None:
             return VerifyService(TpuVerifier(), **svc_kw)
         # Size the key bank to the deployment's published key population
         # and pre-pay the device compiles before serving traffic: the
         # jit signature includes the table shape, so a bank growing
-        # under live traffic means minutes-long compiles mid-consensus
-        # (the round-4 consensus-on-chip zero-commit bug). The warm runs
-        # THROUGH the service (shape-stable coalescing, ISSUE 3): a
-        # coalesced take can reach the service's max_batch even when one
-        # replica's drain sweep is smaller, so warming only the sweep
-        # bound left the top buckets cold — the r5 qc256 8127-item pile
-        # compiled mid-run. The VerifyService wrapper gives the node
-        # async non-blocking dispatch and a CPU path for tiny sweeps
-        # (one process = one replica here, so coalescing is across
-        # consecutive sweeps rather than replicas).
-        pubkeys = list(dep.cfg.pubkeys.values())
+        # under live traffic means minutes-long compiles mid-consensus.
+        # The warm covers every bucket a coalesced take can reach — the
+        # service's max_batch, not one replica's drain sweep (ISSUE 3:
+        # warming only the sweep bound left the top buckets cold and the
+        # r5 qc256 8127-item pile compiled mid-run). The VerifyService
+        # wrapper gives the caller async non-blocking dispatch and a CPU
+        # path for tiny sweeps.
+        pubkeys = list(pubkeys)
         svc = VerifyService(
             TpuVerifier(initial_keys=len(pubkeys) + 32), **svc_kw
         )
-        svc.warm_for_population(pubkeys, max_sweep=4096)
+        svc.warm_for_population(pubkeys, max_sweep=max_batch)
+        for row in svc.device.warm_log:
+            logging.info("verifier warm: %s", row)
         return svc
     if name == "cpu":
         return best_cpu_verifier()
@@ -173,7 +186,7 @@ async def run_node(args) -> None:
     verifier = await asyncio.to_thread(
         make_verifier,
         args.verifier,
-        dep,
+        dep.cfg.pubkeys.values(),
         verify_max_pending=args.verify_max_pending,
         verify_deadline=args.verify_deadline,
     )

@@ -396,9 +396,7 @@ def extract_windows_dev(data: jnp.ndarray, wbits: int, count: int) -> jnp.ndarra
 
     Exists so the verify kernel can take RAW wire bytes: the host then
     transfers 32 bytes per scalar instead of `count` int32 windows (3.3x
-    fewer bytes over the host->device link — which is the e2e bound when
-    the device sits behind a network tunnel, and still saves HBM traffic
-    when it doesn't). TPUs have no 64-bit lanes, so instead of the numpy
+    fewer bytes over the host->device link). TPUs have no 64-bit lanes, so instead of the numpy
     version's uint64 word trick each window gathers its (at most) three
     covering bytes and shifts in int32 — all static indexing, fused by
     XLA into the kernel prologue."""

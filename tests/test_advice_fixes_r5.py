@@ -5,19 +5,10 @@
   churn (mirrors NativeEdVerifier._row_for's policy).
 - ops/comb.negate_rows fails loudly with RuntimeError (not a stripped
   assert) when called on packed-layout tables.
-- chip_daemon logs each malformed queue-override spec once per file
-  version, not once per queue poll.
 """
-
-import json
-import os
-import sys
 
 import numpy as np
 import pytest
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 # ---------------------------------------------------------------------------
@@ -104,41 +95,3 @@ def test_negate_rows_raises_runtime_error_on_packed_layout():
     # covered by the kernel-vs-oracle suites)
     rows = np.asarray(comb.base_table())
     assert comb.negate_rows(rows).shape == rows.shape
-
-
-# ---------------------------------------------------------------------------
-# chip_daemon: malformed override spec logs once per file version
-# ---------------------------------------------------------------------------
-
-
-def test_override_spec_logged_once_per_file_version(tmp_path, monkeypatch):
-    import chip_daemon
-
-    override = tmp_path / "chip_queue_test.json"
-    logged = []
-    monkeypatch.setattr(chip_daemon, "QUEUE_OVERRIDE", str(override))
-    monkeypatch.setattr(chip_daemon, "_log", lambda msg: logged.append(msg))
-    chip_daemon._override_complained.clear()
-
-    # one good spec + one malformed (args not a list)
-    override.write_text(json.dumps([
-        {"exp": "ok_exp", "kind": "consensus", "args": ["--configs", "1"]},
-        {"exp": "bad_exp", "kind": "consensus", "args": "not-a-list"},
-    ]))
-    for _ in range(5):  # five queue polls
-        out = chip_daemon._override_experiments()
-        assert [e["exp"] for e in out] == ["ok_exp"]
-    assert len(logged) == 1  # malformed spec complained about ONCE
-    assert "bad_exp" in logged[0]
-
-    # editing the file re-arms the complaint (new version, new log line)
-    os.utime(override, (1, 1))  # distinct mtime stamp
-    chip_daemon._override_experiments()
-    assert len(logged) == 2
-
-    # unreadable file: same once-per-version rule
-    override.write_text("{not json")
-    chip_daemon._override_experiments()
-    chip_daemon._override_experiments()
-    assert len(logged) == 3
-    assert "unreadable" in logged[2]

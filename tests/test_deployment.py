@@ -199,7 +199,7 @@ class TestDeployDocs:
                 self, pubkeys, (8,)
             ),
         ):
-            svc = make_verifier("tpu", dep)
+            svc = make_verifier("tpu", dep.cfg.pubkeys.values())
         # node.py wraps the device verifier in the coalescing service;
         # the sizing/registration contract lives on the device verifier
         v = svc.device

@@ -3,7 +3,7 @@
 
 The r05 verify-plane verdict — bandwidth-bound on table-row gathers at
 777k verifies/s/chip with a route to ~1.05M — lived in a hand-written
-memo (``bench_results/verify_1m_decomposition_r05.md``). This tool
+builder memo (2026-07-31, not reproduced since). This tool
 recomputes that decomposition from live artifacts, per run:
 
 - the **device ledger** (``simple_pbft_tpu/devledger.py``): per-dispatch
