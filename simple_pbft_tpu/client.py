@@ -162,60 +162,75 @@ class Client:
     async def _recv_loop(self) -> None:
         while True:
             raw = await self.transport.recv()
+            # one loop.client section per drained batch, not per reply: a
+            # queue that holds more is read on without a suspension either
+            # way (asyncio.Queue.get returns at once when it is not empty)
+            spans.begin(spans.LOOP_CLIENT)
+            taken = 0
             try:
-                msg = Message.from_wire(raw)
-            except ValueError:
-                continue
-            if isinstance(msg, ConfigReply):
-                self._on_config_reply(msg)
-                continue
-            if not isinstance(msg, Reply) or msg.client_id != self.id:
-                continue
-            if msg.sender not in self.cfg.replica_ids:
-                continue  # only replicas may answer; f+1 matching assumes it
-            fut = self._waiters.get(msg.timestamp)
-            confirming = msg.timestamp in self._confirming
-            if (fut is None or fut.done()) and not confirming:
-                # nobody is waiting on this timestamp (late replies after
-                # f+1 matched, or stale retransmissions): skip the
-                # signature check — at committee size n the client
-                # otherwise pays n-(f+1) wasted verifies per request.
-                # (A speculatively-accepted ts awaiting final-commit
-                # confirmation still verifies: the f+1 final quorum the
-                # confirmation trusts must be signature-checked.)
-                continue
-            if self.cfg.verify_signatures:
-                if msg.mac:
-                    # point-to-point fast path: HMAC under the shared key
-                    # with the claimed sender (crypto/mac.py)
-                    from .crypto import mac as mac_mod
+                while raw is not None:
+                    taken += 1
+                    self._on_wire(raw)
+                    raw = self.transport.recv_nowait()
+            finally:
+                spans.end(spans.LOOP_CLIENT, taken)
 
-                    key = self._mac.key_for(msg.sender)
-                    if key is None or not mac_mod.tag_valid(
-                        key, msg.signing_payload(), msg.mac
-                    ):
-                        continue
-                else:
-                    pub = self.cfg.pubkey(msg.sender)
-                    if pub is None or not msg.sig:
-                        continue
-                    try:
-                        sig = bytes.fromhex(msg.sig)
-                    except ValueError:
-                        continue
-                    ok = self.verifier.verify_batch(
-                        [
-                            BatchItem(
-                                pubkey=pub, msg=msg.signing_payload(), sig=sig
-                            )
-                        ]
-                    )
-                    if not ok[0]:
-                        continue
-            if fut is None or fut.done():
-                self._on_confirm(msg)
+    def _on_wire(self, raw: bytes) -> None:
+        """One frame off the transport: decode, authenticate, count."""
+        try:
+            msg = Message.from_wire(raw)
+        except ValueError:
+            return
+        if isinstance(msg, ConfigReply):
+            self._on_config_reply(msg)
+            return
+        if not isinstance(msg, Reply) or msg.client_id != self.id:
+            return
+        if msg.sender not in self.cfg.replica_ids:
+            return  # only replicas may answer; f+1 matching assumes it
+        fut = self._waiters.get(msg.timestamp)
+        confirming = msg.timestamp in self._confirming
+        if (fut is None or fut.done()) and not confirming:
+            # nobody is waiting on this timestamp (late replies after
+            # f+1 matched, or stale retransmissions): skip the
+            # signature check — at committee size n the client
+            # otherwise pays n-(f+1) wasted verifies per request.
+            # (A speculatively-accepted ts awaiting final-commit
+            # confirmation still verifies: the f+1 final quorum the
+            # confirmation trusts must be signature-checked.)
+            return
+        if self.cfg.verify_signatures:
+            if msg.mac:
+                # point-to-point fast path: HMAC under the shared key
+                # with the claimed sender (crypto/mac.py)
+                from .crypto import mac as mac_mod
+
+                key = self._mac.key_for(msg.sender)
+                if key is None or not mac_mod.tag_valid(
+                    key, msg.signing_payload(), msg.mac
+                ):
+                    return
             else:
-                self._on_reply(msg)
+                pub = self.cfg.pubkey(msg.sender)
+                if pub is None or not msg.sig:
+                    return
+                try:
+                    sig = bytes.fromhex(msg.sig)
+                except ValueError:
+                    return
+                ok = self.verifier.verify_batch(
+                    [
+                        BatchItem(
+                            pubkey=pub, msg=msg.signing_payload(), sig=sig
+                        )
+                    ]
+                )
+                if not ok[0]:
+                    return
+        if fut is None or fut.done():
+            self._on_confirm(msg)
+        else:
+            self._on_reply(msg)
 
     def _on_reply(self, msg: Reply) -> None:
         ts = msg.timestamp
@@ -490,40 +505,43 @@ class Client:
         applied by this call — see the exception's docstring before
         resubmitting non-idempotent operations)."""
         ts = next(self._ts)
-        # completion floor: everything below the oldest still-outstanding
-        # submit is answered and will never be retransmitted (see
-        # messages.Request.ack — this is what lets replicas fold replay
-        # state without NACKing a pipelined sibling still in flight)
-        floor = min(self._waiters, default=ts) - 1
-        req = Request(
-            client_id=self.id, timestamp=ts, operation=operation, ack=floor
-        )
-        self.signer.sign_msg(req)
-        raw = req.to_wire()
-        fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        self._waiters[ts] = fut
-        self._inflight_raw[ts] = raw
-        tracer = self.tracer
-        rid = tracer.rid_if_sampled(self.id, ts) if tracer is not None else None
-        traced = rid is not None
-        if traced:
-            tracer.emit("submit", rid, op_bytes=len(operation))
-        t_sub = clock.now()
-        self._submit_t0[ts] = t_sub  # confirmation latency anchors here
         try:
-            # first attempt: primary (+ hedged backups); afterwards:
-            # broadcast (classic PBFT retransmission — backups forward to
-            # the primary and arm view-change timers)
-            primary = self.cfg.primary(self.view_hint)
-            await self.transport.send(primary, raw)
-            ids = self.cfg.replica_ids
-            if self.hedge and len(ids) > 1:
-                start = ids.index(primary) if primary in ids else 0
-                for k in range(self.hedge):
-                    # rotate targets per request so hedged load spreads
-                    rid = ids[(start + 1 + (ts + k) % (len(ids) - 1)) % len(ids)]
-                    if rid != primary:
-                        await self.transport.send(rid, raw)
+            # the loop is held from here to the first wait: signing,
+            # encoding and the sends (transport/local delivers inline)
+            with spans.held(spans.LOOP_CLIENT):
+                # completion floor: everything below the oldest still-outstanding
+                # submit is answered and will never be retransmitted (see
+                # messages.Request.ack — this is what lets replicas fold replay
+                # state without NACKing a pipelined sibling still in flight)
+                floor = min(self._waiters, default=ts) - 1
+                req = Request(
+                    client_id=self.id, timestamp=ts, operation=operation, ack=floor
+                )
+                self.signer.sign_msg(req)
+                raw = req.to_wire()
+                fut: asyncio.Future = asyncio.get_running_loop().create_future()
+                self._waiters[ts] = fut
+                self._inflight_raw[ts] = raw
+                tracer = self.tracer
+                rid = tracer.rid_if_sampled(self.id, ts) if tracer is not None else None
+                traced = rid is not None
+                if traced:
+                    tracer.emit("submit", rid, op_bytes=len(operation))
+                t_sub = clock.now()
+                self._submit_t0[ts] = t_sub  # confirmation latency anchors here
+                # first attempt: primary (+ hedged backups); afterwards:
+                # broadcast (classic PBFT retransmission — backups forward to
+                # the primary and arm view-change timers)
+                primary = self.cfg.primary(self.view_hint)
+                await self.transport.send(primary, raw)
+                ids = self.cfg.replica_ids
+                if self.hedge and len(ids) > 1:
+                    start = ids.index(primary) if primary in ids else 0
+                    for k in range(self.hedge):
+                        # rotate targets per request so hedged load spreads
+                        rid = ids[(start + 1 + (ts + k) % (len(ids) - 1)) % len(ids)]
+                        if rid != primary:
+                            await self.transport.send(rid, raw)
             for attempt in range(retries + 1):
                 try:
                     # a SupersededError set on the future raises here

@@ -76,10 +76,17 @@ class LocalCommittee:
         return committee
 
     def start(self) -> None:
+        from . import clock
+
         for r in self.replicas:
             r.start()
         for c in self.clients:
             c.start()
+        if self.lag_gauge is None and not clock.simulated():
+            # the heartbeat (loop.lag, loop.offcpu, loop.unattributed,
+            # gc.pause): one loop runs every node here, so one serves all.
+            # Not under the sim: a 50 ms timer would fill its event trace
+            self.attach_loop_lag()
 
     async def stop(self) -> None:
         import asyncio
@@ -140,14 +147,17 @@ class LocalCommittee:
         self.knob_registry = registry_for_committee(self)
         return self.knob_registry
 
-    def attach_loop_lag(self, interval: float = 0.1):
-        """Start the committee's event-loop lag gauge (ISSUE 4: one loop
-        runs every in-process node, so one gauge serves them all — a
-        starved dispatcher core shows in every node's snapshot). Call
-        from inside the running loop; stop via ``await
-        committee.lag_gauge.stop()`` (committee.stop() does it too)."""
+    def attach_loop_lag(self, interval: float = 0.05):
+        """The committee's heartbeat, the event-loop lag gauge (ISSUE 4:
+        one loop runs every in-process node, so one gauge serves them
+        all — a starved dispatcher core shows in every node's snapshot).
+        ``start()`` begins it; this returns the running one, or starts it
+        for a committee driven without ``start()``. Call from inside the
+        running loop; ``committee.stop()`` stops it."""
         from .telemetry import LoopLagGauge
 
+        if self.lag_gauge is not None:
+            return self.lag_gauge  # start() began it: one per loop
         self.lag_gauge = LoopLagGauge(interval=interval)
         self.lag_gauge.start()
         return self.lag_gauge

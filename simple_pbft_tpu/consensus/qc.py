@@ -557,4 +557,5 @@ async def verify_qc_async(cfg, qc: QuorumCert) -> bool:
     if clock.simulated():
         # pbftlint: disable=PBL001 -- sim-only branch: clock.simulated() gates it off every production loop; blocking a simulated loop is the determinism contract, not a stall
         return verify_qc(cfg, qc)
-    return await asyncio.wrap_future(qc_lane().submit(cfg, qc))
+    with spans.parked():  # suspends under loop.route: not loop-held time
+        return await asyncio.wrap_future(qc_lane().submit(cfg, qc))

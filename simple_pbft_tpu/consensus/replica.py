@@ -499,18 +499,22 @@ class Replica:
         round k's commits"; VERDICT round-1 weak #6)."""
         while self._running:
             raw = await self.transport.recv()
+            # begin/end, not `with spans.held`: every sweep pays this
+            spans.begin(spans.LOOP_INGEST)
             sweep = [raw]
-            while len(sweep) < self.max_drain:
-                nxt = self.transport.recv_nowait()
-                if nxt is None:
-                    break
-                sweep.append(nxt)
             try:
+                while len(sweep) < self.max_drain:
+                    nxt = self.transport.recv_nowait()
+                    if nxt is None:
+                        break
+                    sweep.append(nxt)
                 job = self._start_sweep(sweep)
             except Exception:
                 log.exception("%s: sweep decode failed", self.id)
                 self.metrics["sweep_errors"] += 1
                 continue
+            finally:
+                spans.end(spans.LOOP_INGEST, len(sweep))
             try:
                 await self._queue.put(job)
             except asyncio.CancelledError:
@@ -729,14 +733,36 @@ class Replica:
             out, fresh, fresh_keys = await clock.off_thread(
                 self._cache_filter, items
             )
+            verdict = self._submit_fresh(fresh)
         else:
-            out, fresh, fresh_keys = self._cache_filter(items)
-        if fresh:
-            verdicts = await asyncio.wrap_future(self.verifier.submit(fresh))
-            self._cache_store(fresh_keys, verdicts, out)
+            # one section for the synchronous head of a sweep's verify,
+            # the submit charged out of it
+            spans.begin(spans.LOOP_SIGCACHE)
+            try:
+                out, fresh, fresh_keys = self._cache_filter(items)
+                verdict = self._submit_fresh(fresh)
+            finally:
+                spans.end(spans.LOOP_SIGCACHE, len(items))
+        if verdict is not None:
+            verdicts = await verdict
+            spans.begin(spans.LOOP_SIGCACHE)
+            try:
+                self._cache_store(fresh_keys, verdicts, out)
+            finally:
+                spans.end(spans.LOOP_SIGCACHE, len(fresh))
         self.metrics["sig_cache_hits"] += len(items) - len(fresh)
         self._record_verify(len(fresh), clock.now() - t0)
         return out
+
+    def _submit_fresh(self, fresh: List[BatchItem]):
+        """Hand a sweep's uncached items to the verify service; the
+        verdict's future, or None where the cache answered everything."""
+        if not fresh:
+            return None
+        t0 = clock.now()
+        verdict = asyncio.wrap_future(self.verifier.submit(fresh))
+        spans.charge(spans.LOOP_VERIFY_SUBMIT, clock.now() - t0, len(fresh))
+        return verdict
 
     async def _finish_sweep(self, decoded, sig_spans, verify_task) -> None:
         if not decoded:
@@ -756,38 +782,50 @@ class Replica:
                 self.metrics["messages_shed"] += len(decoded)
                 self.metrics["degraded_mode"] = 1
                 return
-            accepted = []
-            for msg, (s, e) in zip(decoded, sig_spans):
-                if s == e:
-                    # structurally inadmissible or redundant (no signature
-                    # items were even collected) — NOT a forged signature;
-                    # keeping bad_sig clean of these preserves it as the
-                    # Byzantine-signature alarm
-                    self.metrics["dropped_precheck"] += 1
-                elif all(bitmap[s:e]):
-                    accepted.append(msg)
-                else:
-                    self.metrics["bad_sig"] += 1
-        for msg in accepted:
-            if self.auditor is not None:
-                # the audit tap: every message past signature verification
-                # (QuorumCerts are audited post-pairing in _on_qc instead —
-                # an unverified aggregate must never become evidence)
-                self.auditor.observe_message(msg)
-            if msg.sender in self._replica_set:
-                # heartbeat evidence for the dead-target fast-path: any
-                # surviving message from a committee member proves it
-                # alive NOW (one dict store; read by ViewChanger)
-                self.peer_seen[msg.sender] = clock.now()
-            await self._route(msg)
-        await self._propose_if_ready()
-        self.stats.sweep_ms.record((clock.now() - t0) * 1e3)
+        # the loop is held from the verdict to the end of the sweep: the
+        # stages nested below (sign_vote, send, execute, sign_reply) take
+        # their own time out of loop.route's
+        spans.begin(spans.LOOP_ROUTE)
+        try:
+            if self.cfg.verify_signatures:
+                accepted = []
+                for msg, (s, e) in zip(decoded, sig_spans):
+                    if s == e:
+                        # structurally inadmissible or redundant (no
+                        # signature items were even collected) — NOT a
+                        # forged signature; keeping bad_sig clean of
+                        # these preserves it as the Byzantine-signature
+                        # alarm
+                        self.metrics["dropped_precheck"] += 1
+                    elif all(bitmap[s:e]):
+                        accepted.append(msg)
+                    else:
+                        self.metrics["bad_sig"] += 1
+            for msg in accepted:
+                if self.auditor is not None:
+                    # the audit tap: every message past signature
+                    # verification (QuorumCerts are audited post-pairing
+                    # in _on_qc instead — an unverified aggregate must
+                    # never become evidence)
+                    self.auditor.observe_message(msg)
+                if msg.sender in self._replica_set:
+                    # heartbeat evidence for the dead-target fast-path:
+                    # any surviving message from a committee member
+                    # proves it alive NOW (one dict store; read by
+                    # ViewChanger)
+                    self.peer_seen[msg.sender] = clock.now()
+                await self._route(msg)
+            await self._propose_if_ready()
+            self.stats.sweep_ms.record((clock.now() - t0) * 1e3)
+        finally:
+            spans.end(spans.LOOP_ROUTE, len(accepted))
 
     async def process_sweep(self, sweep: List[bytes]) -> None:
         """Decode a sweep of wire messages, batch-verify every signature in
         it with ONE verifier call, then route the survivors. (Direct-drive
         entry for tests; the runtime pipelines the same two halves.)"""
-        decoded, sig_spans, verify_task = self._start_sweep(sweep)
+        with spans.held(spans.LOOP_INGEST, len(sweep)):
+            decoded, sig_spans, verify_task = self._start_sweep(sweep)
         await self._finish_sweep(decoded, sig_spans, verify_task)
 
     def _batch_items(self, msg: Message) -> List[BatchItem]:
@@ -1109,9 +1147,10 @@ class Replica:
             if len(self.relay_buffer) < 65536:  # bounded
                 self.relay_buffer[key] = req
             self.vc.arm()
-            await self.transport.send(
-                self.cfg.primary(self.view), req.to_wire()
-            )
+            with spans.held(spans.LOOP_SEND):
+                await self.transport.send(
+                    self.cfg.primary(self.view), req.to_wire()
+                )
 
     async def _propose_if_ready(self) -> None:
         """Primary: cut ALL pending requests into one block and propose.
@@ -1147,7 +1186,8 @@ class Replica:
             digest=PrePrepare.block_digest(block),
             block=block,
         )
-        self.signer.sign_msg(pp)
+        with spans.held(spans.LOOP_SIGN_VOTE):
+            self.signer.sign_msg(pp)
         self.metrics["proposed_blocks"] += 1
         self.metrics["proposed_requests"] += len(block)
         if self.auditor is not None:
@@ -1157,8 +1197,11 @@ class Replica:
         # trace envelope (unsigned, outside the signed fields — decode
         # drops it before payload reconstruction) on the freshly signed
         # wire frame; no-op unless the trace plane is enabled
-        pp_wire = trace.stamp(pp.to_wire(), trace.PREPREPARE, pp.view, seq, self.id)
-        await self.transport.broadcast(pp_wire, self.cfg.replica_ids)
+        with spans.held(spans.LOOP_SEND, len(self.cfg.replica_ids) - 1):
+            pp_wire = trace.stamp(
+                pp.to_wire(), trace.PREPREPARE, pp.view, seq, self.id
+            )
+            await self.transport.broadcast(pp_wire, self.cfg.replica_ids)
         await self._on_phase(pp)  # self-delivery
 
     # ------------------------------------------------------------------
@@ -1287,9 +1330,11 @@ class Replica:
             self.metrics["qc_shed_overload"] += 1
             return None, set()
         self.metrics["qc_aggregate_failed"] += 1
-        good = await clock.off_thread(
-            qc_mod.bisect_bad_shares, self.cfg, phase, view, seq, digest, shares
-        )
+        with spans.parked():  # suspends under loop.route
+            good = await clock.off_thread(
+                qc_mod.bisect_bad_shares,
+                self.cfg, phase, view, seq, digest, shares,
+            )
         bad = set(shares) - set(good)
         self.metrics["qc_bad_shares"] += len(bad)
         if len(good) < self.cfg.quorum:
@@ -1501,22 +1546,34 @@ class Replica:
         # transits the transport recv seam, so its arrival is logged here
         self.qstats.note_vote(phase, act.view, act.seq, self.id)
         if self.cfg.qc_mode:
-            vote.bls_share = qc_mod.sign_share(
-                self.bls_sk, phase, act.view, act.seq, act.digest
-            )
-            self.signer.sign_msg(vote)
+            with spans.held(spans.LOOP_SIGN_VOTE):
+                vote.bls_share = qc_mod.sign_share(
+                    self.bls_sk, phase, act.view, act.seq, act.digest
+                )
+                self.signer.sign_msg(vote)
             primary = self.cfg.primary(act.view)
             if primary == self.id:
                 await self._on_phase(vote)  # our own share, directly
             else:
-                wire = trace.stamp(
-                    vote.to_wire(), phase, act.view, act.seq, self.id
-                )
-                await self.transport.send(primary, wire)
+                with spans.held(spans.LOOP_SEND):
+                    wire = trace.stamp(
+                        vote.to_wire(), phase, act.view, act.seq, self.id
+                    )
+                    await self.transport.send(primary, wire)
             return
-        self.signer.sign_msg(vote)
-        wire = trace.stamp(vote.to_wire(), phase, act.view, act.seq, self.id)
-        await self.transport.broadcast(wire, self.cfg.replica_ids)
+        spans.begin(spans.LOOP_SIGN_VOTE)
+        try:
+            self.signer.sign_msg(vote)
+        finally:
+            spans.end(spans.LOOP_SIGN_VOTE)
+        spans.begin(spans.LOOP_SEND)
+        try:
+            wire = trace.stamp(
+                vote.to_wire(), phase, act.view, act.seq, self.id
+            )
+            await self.transport.broadcast(wire, self.cfg.replica_ids)
+        finally:
+            spans.end(spans.LOOP_SEND, len(self.cfg.replica_ids) - 1)
         await self._on_phase(vote)  # count own vote
 
     # ------------------------------------------------------------------
@@ -1557,105 +1614,123 @@ class Replica:
                     now_pc - src.t_started,
                     node=self.id, view=act.view, seq=act.seq,
                 )
-            reqs = self._validate_block(act.block, act.digest)
-            if reqs is None:  # unreachable: admission validated on entry
-                self.metrics["exec_bad_block"] += 1
-                continue
-            if self.spec is not None:
-                # divergence gate BEFORE the block applies: a speculated
-                # digest losing to the committed one voids the fork
-                self.spec.before_finalize(act)
-            final_results: Dict[Tuple[str, int], str] = {}
-            for req in reqs:
-                self.relay_buffer.pop((req.client_id, req.timestamp), None)
-                if req.ack > self.client_ack.get(req.client_id, 0):
-                    self.client_ack[req.client_id] = req.ack
-                recent = self.recent_replies.get(req.client_id, {})
-                if req.timestamp in recent:
-                    # EXACT-ts replay that slipped into a block: no-op.
-                    # (A max-ts watermark here would skip lower timestamps
-                    # of a pipelined client whose requests committed out
-                    # of order after a failover — deadlocking the client.)
-                    self.metrics["exec_replay_skipped"] += 1
+            # one section per block: validation, the applies, reply
+            # construction and the speculation engine's finalize hooks
+            with spans.held(spans.LOOP_EXECUTE) as sec:
+                reqs = self._validate_block(act.block, act.digest)
+                if reqs is None:  # unreachable: admission validated on entry
+                    self.metrics["exec_bad_block"] += 1
                     continue
-                if req.timestamp <= self.client_watermark.get(req.client_id, 0):
-                    # At/below the folded watermark with no cached reply:
-                    # either a replay whose reply the checkpoint fold
-                    # already discarded, or a pipelined client's lower
-                    # timestamp that stayed in flight across a whole
-                    # checkpoint interval while a higher sibling executed.
-                    # Post-fold the two are indistinguishable, so never
-                    # re-apply (at-most-once execution) — but DO answer.
-                    # Watermark and reply cache are checkpoint state,
-                    # identical on every honest replica, so the client
-                    # gets f+1 matching SUPERSEDED replies (an explicit
-                    # "resubmit with a fresh timestamp") instead of
-                    # hanging forever on a silently dropped request.
-                    self.metrics["exec_replay_skipped"] += 1
-                    await self._send_superseded(act.view, act.seq, req)
-                    continue
-                if req.operation.startswith(RECONFIG_PREFIX):
-                    # committed membership change: stage it; activation
-                    # waits for the next checkpoint boundary so every
-                    # honest replica switches epochs at the same edge
-                    result = self._execute_reconfig(act.seq, req)
-                else:
-                    result = self.app.apply(req.operation)
-                final_results[(req.client_id, req.timestamp)] = result
-                self.metrics["committed_requests"] += 1
-                # one hash decides sampling for BOTH execute and reply
-                trace_rid = (
-                    self.tracer.rid_if_sampled(req.client_id, req.timestamp)
-                    if self.tracer is not None
-                    else None
-                )
-                if trace_rid:
-                    self.tracer.emit(
-                        "execute", trace_rid, view=act.view, seq=act.seq
+                sec.n = len(reqs)
+                if self.spec is not None:
+                    # divergence gate BEFORE the block applies: a speculated
+                    # digest losing to the committed one voids the fork
+                    self.spec.before_finalize(act)
+                final_results: Dict[Tuple[str, int], str] = {}
+                # replies are signed and sent one by one between the
+                # applies; their time is summed here and charged once
+                # per block
+                sent, signing, sending = 0, 0.0, 0.0
+                for req in reqs:
+                    self.relay_buffer.pop((req.client_id, req.timestamp), None)
+                    if req.ack > self.client_ack.get(req.client_id, 0):
+                        self.client_ack[req.client_id] = req.ack
+                    recent = self.recent_replies.get(req.client_id, {})
+                    if req.timestamp in recent:
+                        # EXACT-ts replay that slipped into a block: no-op.
+                        # (A max-ts watermark here would skip lower timestamps
+                        # of a pipelined client whose requests committed out
+                        # of order after a failover — deadlocking the client.)
+                        self.metrics["exec_replay_skipped"] += 1
+                        continue
+                    if req.timestamp <= self.client_watermark.get(req.client_id, 0):
+                        # At/below the folded watermark with no cached reply:
+                        # either a replay whose reply the checkpoint fold
+                        # already discarded, or a pipelined client's lower
+                        # timestamp that stayed in flight across a whole
+                        # checkpoint interval while a higher sibling executed.
+                        # Post-fold the two are indistinguishable, so never
+                        # re-apply (at-most-once execution) — but DO answer.
+                        # Watermark and reply cache are checkpoint state,
+                        # identical on every honest replica, so the client
+                        # gets f+1 matching SUPERSEDED replies (an explicit
+                        # "resubmit with a fresh timestamp") instead of
+                        # hanging forever on a silently dropped request.
+                        self.metrics["exec_replay_skipped"] += 1
+                        await self._send_superseded(act.view, act.seq, req)
+                        continue
+                    if req.operation.startswith(RECONFIG_PREFIX):
+                        # committed membership change: stage it; activation
+                        # waits for the next checkpoint boundary so every
+                        # honest replica switches epochs at the same edge
+                        result = self._execute_reconfig(act.seq, req)
+                    else:
+                        result = self.app.apply(req.operation)
+                    final_results[(req.client_id, req.timestamp)] = result
+                    self.metrics["committed_requests"] += 1
+                    # one hash decides sampling for BOTH execute and reply
+                    trace_rid = (
+                        self.tracer.rid_if_sampled(req.client_id, req.timestamp)
+                        if self.tracer is not None
+                        else None
                     )
-                reply = Reply(
-                    view=act.view,
-                    seq=act.seq,
-                    client_id=req.client_id,
-                    timestamp=req.timestamp,
-                    result=result,
-                    # deterministic (epoch activation is a function of
-                    # executed history): a stale client sees a higher
-                    # epoch in any reply and re-resolves the committee
-                    epoch=self.cfg.epoch,
-                )
-                self.recent_replies.setdefault(req.client_id, {})[
-                    req.timestamp
-                ] = reply
-                # Designated repliers: cfg.repliers replicas (f+1 plus a
-                # few loss-tolerance spares, rotating by seq) sign and
-                # transmit — f+1 matching is all the client can use, so
-                # the remaining signatures and sends were pure waste (at
-                # n=100: ~58 signs + client-side decodes per request).
-                # Everyone still CACHES the reply: if the designated set
-                # is unlucky (drops, faults), the client's retransmission
-                # hits the _on_request duplicate branch, where every
-                # replica signs-on-demand and resends the cached reply
-                # (the liveness fallback).
-                if (
-                    not self.retired
-                    and (self._index - act.seq) % self.cfg.n
-                    < self.cfg.repliers
-                ):
-                    self._auth_reply(reply)
-                    self.metrics["replies_sent"] += 1
-                    await self.transport.send(req.client_id, reply.to_wire())
                     if trace_rid:
                         self.tracer.emit(
-                            "reply", trace_rid, view=act.view, seq=act.seq
+                            "execute", trace_rid, view=act.view, seq=act.seq
                         )
-            if self.spec is not None:
-                # confirm (or roll back) the slot's speculation, and
-                # keep the fork in lockstep across unspeculated slots
-                self.spec.after_finalize(act, final_results)
-            if self.tracer is not None:
-                # executed: the slot's trace binding is complete
-                self.tracer.release_slot(act.view, act.seq)
+                    reply = Reply(
+                        view=act.view,
+                        seq=act.seq,
+                        client_id=req.client_id,
+                        timestamp=req.timestamp,
+                        result=result,
+                        # deterministic (epoch activation is a function of
+                        # executed history): a stale client sees a higher
+                        # epoch in any reply and re-resolves the committee
+                        epoch=self.cfg.epoch,
+                    )
+                    self.recent_replies.setdefault(req.client_id, {})[
+                        req.timestamp
+                    ] = reply
+                    # Designated repliers: cfg.repliers replicas (f+1 plus a
+                    # few loss-tolerance spares, rotating by seq) sign and
+                    # transmit — f+1 matching is all the client can use, so
+                    # the remaining signatures and sends were pure waste (at
+                    # n=100: ~58 signs + client-side decodes per request).
+                    # Everyone still CACHES the reply: if the designated set
+                    # is unlucky (drops, faults), the client's retransmission
+                    # hits the _on_request duplicate branch, where every
+                    # replica signs-on-demand and resends the cached reply
+                    # (the liveness fallback).
+                    if (
+                        not self.retired
+                        and (self._index - act.seq) % self.cfg.n
+                        < self.cfg.repliers
+                    ):
+                        t_sign = clock.now()
+                        self._auth_reply(reply)
+                        t_send = clock.now()
+                        self.metrics["replies_sent"] += 1
+                        await self.transport.send(
+                            req.client_id, reply.to_wire()
+                        )
+                        sent += 1
+                        signing += t_send - t_sign
+                        sending += clock.now() - t_send
+                        if trace_rid:
+                            self.tracer.emit(
+                                "reply", trace_rid, view=act.view, seq=act.seq
+                            )
+                if sent:
+                    spans.charge(spans.LOOP_SIGN_REPLY, signing, sent)
+                    spans.charge(spans.LOOP_SEND, sending, sent)
+                if self.spec is not None:
+                    # confirm (or roll back) the slot's speculation, and
+                    # keep the fork in lockstep across unspeculated slots
+                    self.spec.after_finalize(act, final_results)
+                if self.tracer is not None:
+                    # executed: the slot's trace binding is complete
+                    self.tracer.release_slot(act.view, act.seq)
             if self.executed_seq % self.cfg.checkpoint_interval == 0:
                 if (
                     self.pending_reconfig is not None
@@ -1697,8 +1772,10 @@ class Replica:
             superseded=1,
             epoch=self.cfg.epoch,
         )
-        self._auth_reply(reply)
-        await self.transport.send(req.client_id, reply.to_wire())
+        with spans.held(spans.LOOP_SIGN_REPLY):
+            self._auth_reply(reply)
+        with spans.held(spans.LOOP_SEND):
+            await self.transport.send(req.client_id, reply.to_wire())
 
     async def _send_spec_replies(self, replies) -> None:
         """Authenticate and transmit speculative replies (Reply.spec=1)
@@ -1708,10 +1785,15 @@ class Replica:
         from the final reply once it lands."""
         if not replies:
             return
-        for reply in replies:
-            self._auth_reply(reply)
-            self.metrics["spec_replies_sent"] += 1
-            await self.transport.send(reply.client_id, reply.to_wire())
+        # all signed, then all sent, in the same order as before: one
+        # section of each stage per list, not one per reply
+        with spans.held(spans.LOOP_SIGN_REPLY, len(replies)):
+            for reply in replies:
+                self._auth_reply(reply)
+        with spans.held(spans.LOOP_SEND, len(replies)):
+            for reply in replies:
+                self.metrics["spec_replies_sent"] += 1
+                await self.transport.send(reply.client_id, reply.to_wire())
 
     # ------------------------------------------------------------------
     # live membership reconfiguration (ISSUE 7 tentpole, pillar 3)
@@ -2048,13 +2130,14 @@ class Replica:
         self.checkpoint_digests[seq] = digest
         self.snapshots[seq] = snap
         cp = Checkpoint(seq=seq, state_digest=digest)
-        if self.cfg.qc_mode and self.bls_sk is not None:
-            # share for the aggregate checkpoint certificate (view pinned
-            # to 0: checkpoints are view-independent)
-            cp.bls_share = qc_mod.sign_share(
-                self.bls_sk, "checkpoint", 0, seq, digest
-            )
-        self.signer.sign_msg(cp)
+        with spans.held(spans.LOOP_SIGN_VOTE):
+            if self.cfg.qc_mode and self.bls_sk is not None:
+                # share for the aggregate checkpoint certificate (view
+                # pinned to 0: checkpoints are view-independent)
+                cp.bls_share = qc_mod.sign_share(
+                    self.bls_sk, "checkpoint", 0, seq, digest
+                )
+            self.signer.sign_msg(cp)
         if self.auditor is not None:
             # own checkpoint: the ledger line cross-node state-digest
             # agreement is computed from, and the local reference peers'
@@ -2064,7 +2147,10 @@ class Replica:
         if not self.retired:
             # an honest retiree keeps folding locally but stops feeding
             # the consensus plane (peers would role-gate the frame out)
-            await self.transport.broadcast(cp.to_wire(), self.cfg.replica_ids)
+            with spans.held(spans.LOOP_SEND, len(self.cfg.replica_ids) - 1):
+                await self.transport.broadcast(
+                    cp.to_wire(), self.cfg.replica_ids
+                )
 
     async def ensure_checkpoint_qc(self) -> None:
         """QC mode: aggregate the stored 2f+1 checkpoint shares at the

@@ -132,89 +132,93 @@ class SpeculationEngine:
         if self.max_depth and len(self.slots) >= self.max_depth:
             r.metrics["spec_skipped_depth"] += 1
             return None
-        reqs = r._validate_block(inst.block, inst.digest)
-        if reqs is None:
-            return None
-        if any(
-            req.operation.startswith(RECONFIG_PREFIX_) for req in reqs
-        ):
-            # membership changes have side effects outside the app
-            # (staging, epoch activation): never speculate them
-            r.metrics["spec_skipped_reconfig"] += 1
-            return None
-        rw = self._block_rw(reqs)
-        ooo = False
-        gap = [
-            g
-            for g in range(r.executed_seq + 1, seq)
-            if g not in self.slots
-        ]
-        if gap:
-            if rw is None:
-                return None  # unparsable ops: no disjointness proof
-            gap_rw = self._committed_gap_rw(gap)
-            if gap_rw is None:
-                r.metrics["spec_skipped_gap"] += 1
-                return None  # a gap slot is not committed-with-block
-            reads, writes = rw
-            g_reads, g_writes = gap_rw
-            if (writes & (g_reads | g_writes)) or (reads & g_writes):
-                r.metrics["spec_skipped_conflict"] += 1
+        # the loop is held while the block runs on the fork (ISSUE 26:
+        # loop.execute covers ordered AND speculative execution)
+        with spans.held(spans.LOOP_EXECUTE) as sec:
+            reqs = r._validate_block(inst.block, inst.digest)
+            if reqs is None:
                 return None
-            ooo = True
-        slot = SpecSlot(
-            seq=seq,
-            view=inst.view,
-            digest=inst.digest,
-            reads=rw[0] if rw else frozenset(),
-            writes=rw[1] if rw else frozenset(),
-            ooo=ooo,
-        )
-        replies: List[Reply] = []
-        # designated speculative repliers: the client needs 2f+1
-        # matching marks, so the rotation window is quorum + spares
-        # (cfg.spec_repliers); everyone still executes — the fork must
-        # stay consistent on every replica regardless of who transmits
-        designated = (r._index - seq) % r.cfg.n < r.cfg.spec_repliers
-        for req in reqs:
-            recent = r.recent_replies.get(req.client_id, {})
-            if (
-                req.timestamp in recent
-                or req.timestamp
-                <= r.client_watermark.get(req.client_id, 0)
+            sec.n = len(reqs)
+            if any(
+                req.operation.startswith(RECONFIG_PREFIX_) for req in reqs
             ):
-                continue  # replay: finalize will skip it identically
-            result = self.app.apply_spec(req.operation)
-            slot.results[(req.client_id, req.timestamp)] = result
-            if designated:
-                replies.append(
-                    Reply(
-                        view=inst.view,
-                        seq=seq,
-                        client_id=req.client_id,
-                        timestamp=req.timestamp,
-                        result=result,
-                        spec=1,
-                        epoch=r.cfg.epoch,
-                    )
-                )
-        self.slots[seq] = slot
-        r.metrics["spec_executed"] += 1
-        r.metrics["spec_requests"] += len(slot.results)
-        if ooo:
-            r.metrics["spec_ooo"] += 1
-        now = clock.now()
-        if inst.t_started:
-            # the speculative half of the phase.execute split: admission
-            # -> speculative reply, directly comparable per percentile
-            # against execute.final (admission -> applied in order)
-            dur = now - inst.t_started
-            r.stats.spec_reply_ms.record(dur * 1e3)
-            spans.record(
-                spans.EXECUTE_SPEC, dur,
-                node=r.id, view=inst.view, seq=seq,
+                # membership changes have side effects outside the app
+                # (staging, epoch activation): never speculate them
+                r.metrics["spec_skipped_reconfig"] += 1
+                return None
+            rw = self._block_rw(reqs)
+            ooo = False
+            gap = [
+                g
+                for g in range(r.executed_seq + 1, seq)
+                if g not in self.slots
+            ]
+            if gap:
+                if rw is None:
+                    return None  # unparsable ops: no disjointness proof
+                gap_rw = self._committed_gap_rw(gap)
+                if gap_rw is None:
+                    r.metrics["spec_skipped_gap"] += 1
+                    return None  # a gap slot is not committed-with-block
+                reads, writes = rw
+                g_reads, g_writes = gap_rw
+                if (writes & (g_reads | g_writes)) or (reads & g_writes):
+                    r.metrics["spec_skipped_conflict"] += 1
+                    return None
+                ooo = True
+            slot = SpecSlot(
+                seq=seq,
+                view=inst.view,
+                digest=inst.digest,
+                reads=rw[0] if rw else frozenset(),
+                writes=rw[1] if rw else frozenset(),
+                ooo=ooo,
             )
-        return replies
+            replies: List[Reply] = []
+            # designated speculative repliers: the client needs 2f+1
+            # matching marks, so the rotation window is quorum + spares
+            # (cfg.spec_repliers); everyone still executes — the fork must
+            # stay consistent on every replica regardless of who transmits
+            designated = (r._index - seq) % r.cfg.n < r.cfg.spec_repliers
+            for req in reqs:
+                recent = r.recent_replies.get(req.client_id, {})
+                if (
+                    req.timestamp in recent
+                    or req.timestamp
+                    <= r.client_watermark.get(req.client_id, 0)
+                ):
+                    continue  # replay: finalize will skip it identically
+                result = self.app.apply_spec(req.operation)
+                slot.results[(req.client_id, req.timestamp)] = result
+                if designated:
+                    replies.append(
+                        Reply(
+                            view=inst.view,
+                            seq=seq,
+                            client_id=req.client_id,
+                            timestamp=req.timestamp,
+                            result=result,
+                            spec=1,
+                            epoch=r.cfg.epoch,
+                        )
+                    )
+            self.slots[seq] = slot
+            r.metrics["spec_executed"] += 1
+            r.metrics["spec_requests"] += len(slot.results)
+            if ooo:
+                r.metrics["spec_ooo"] += 1
+            now = clock.now()
+            if inst.t_started:
+                # the speculative half of the phase.execute split: admission
+                # -> speculative reply, directly comparable per percentile
+                # against execute.final (admission -> applied in order)
+                dur = now - inst.t_started
+                r.stats.spec_reply_ms.record(dur * 1e3)
+                spans.record(
+                    spans.EXECUTE_SPEC, dur,
+                    node=r.id, view=inst.view, seq=seq,
+                )
+            return replies
 
     def _block_rw(
         self, reqs

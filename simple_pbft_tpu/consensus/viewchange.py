@@ -37,7 +37,7 @@ import random
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
-from .. import clock, trace
+from .. import clock, spans, trace
 from ..crypto.verifier import BatchItem
 from ..messages import (
     EMPTY_BLOCK_DIGEST,
@@ -885,7 +885,10 @@ class ViewChanger:
         if not qcs:
             return True
         cfg = self.r.cfg
-        return await clock.off_thread(qc_mod.verify_qcs_all, cfg, list(qcs))
+        with spans.parked():  # suspends under loop.route
+            return await clock.off_thread(
+                qc_mod.verify_qcs_all, cfg, list(qcs)
+            )
 
     # -- receiving ------------------------------------------------------
 

@@ -419,8 +419,13 @@ class VerifyService:
     def _dispatch_loop(self) -> None:
         while True:
             with self._cond:
-                while not self._closed and not self._can_dispatch_locked():
-                    self._cond.wait()
+                # the wait for a pile this thread may dispatch (nothing
+                # pending, or a big pile behind a full depth): annotated
+                # only, so a traced idle gap can be told from host work
+                with spans.annotation(spans.VERIFY_COLLECT):
+                    while (not self._closed
+                           and not self._can_dispatch_locked()):
+                        self._cond.wait()
                 if self._closed and not self._pending:
                     # FIFO shutdown: the sentinel reaches the completion
                     # thread only after every dispatched finisher, so no
@@ -512,7 +517,8 @@ class VerifyService:
                     )
                 t0 = time.perf_counter()
                 try:
-                    finisher = self._device.dispatch_batch(batch)
+                    with spans.annotation(spans.VERIFY_DEVICE):
+                        finisher = self._device.dispatch_batch(batch)
                 except BaseException as e:  # noqa: BLE001
                     # the annotation above was never consumed (the
                     # dispatch died before recording): clear it, or the
@@ -555,7 +561,8 @@ class VerifyService:
                             self._cond.notify_all()
                         continue
                 else:
-                    verdicts = finisher()
+                    with spans.annotation(spans.VERIFY_DEVICE):
+                        verdicts = finisher()
             except BaseException as e:  # noqa: BLE001
                 self._fail(subs, e)
             else:
@@ -600,7 +607,8 @@ class VerifyService:
 
         def run() -> None:
             try:
-                box["r"] = finisher()
+                with spans.annotation(spans.VERIFY_DEVICE):
+                    box["r"] = finisher()
             except BaseException as e:  # noqa: BLE001
                 box["e"] = e
             done.set()
@@ -691,7 +699,8 @@ class VerifyService:
         # chunk is verify.cpu_reroute — same code, different cause
         t0 = time.perf_counter()
         try:
-            verdicts = self._cpu.verify_batch(batch)
+            with spans.annotation(stage):
+                verdicts = self._cpu.verify_batch(batch)
         except BaseException as e:  # noqa: BLE001
             self._fail(subs, e)
             return

@@ -870,33 +870,36 @@ class TpuVerifier:
         items: Sequence[BatchItem],
         annotation: "tuple[float, int]" = (0.0, 1),
     ):
+        from .. import devledger, spans
+
         t_prep = time.perf_counter()
-        size = _bucket_size(max(len(items), self._align))
-        fallback: List[int] = []
-        if self._mode in ("comb", "fused"):
-            if self._mode == "fused":
-                prep, fallback = prepare_wire_batch(items, self._bank)
-                prep = prep.padded(size)
-                wire, a_idx, precheck = prep.arrays()
-                tables = self._bank.device_tables()
-                args = (wire, a_idx, tables, precheck)
+        # annotated while a profiler capture is open, so the trace's host
+        # planes show the prep beside the device's modules
+        with spans.annotation(spans.VERIFY_HOST_PREP):
+            size = _bucket_size(max(len(items), self._align))
+            fallback: List[int] = []
+            if self._mode in ("comb", "fused"):
+                if self._mode == "fused":
+                    prep, fallback = prepare_wire_batch(items, self._bank)
+                    prep = prep.padded(size)
+                    wire, a_idx, precheck = prep.arrays()
+                    tables = self._bank.device_tables()
+                    args = (wire, a_idx, tables, precheck)
+                else:
+                    prep, fallback = prepare_comb_batch(items, self._bank)
+                    prep = prep.padded(size)
+                    s_nib, k_nib, a_idx, r_y, r_sign, precheck = prep.arrays()
+                    tables = self._bank.device_tables()
+                    b_table = comb.base_table_device()
+                    args = (s_nib, k_nib, a_idx, tables, b_table, r_y, r_sign, precheck)
             else:
-                prep, fallback = prepare_comb_batch(items, self._bank)
-                prep = prep.padded(size)
-                s_nib, k_nib, a_idx, r_y, r_sign, precheck = prep.arrays()
-                tables = self._bank.device_tables()
-                b_table = comb.base_table_device()
-                args = (s_nib, k_nib, a_idx, tables, b_table, r_y, r_sign, precheck)
-        else:
-            prep = prepare_batch(items).padded(size)
-            args = prep.arrays()
-        compile_fresh = self._record_shape(size)
+                prep = prepare_batch(items).padded(size)
+                args = prep.arrays()
+            compile_fresh = self._record_shape(size)
         # host-side prep (nibble decomposition, padding, array builds)
         # is CPU work on the dispatcher's thread — if it rivals the
         # device RTT the pipeline is host-bound, and only a span can say
         # so (spans.py; the r5 "where do the other 96% go" question)
-        from .. import devledger, spans
-
         prep_s = time.perf_counter() - t_prep
         spans.record(spans.VERIFY_HOST_PREP, prep_s, n=len(items))
         # host->device upload: the freshly-built host arrays (persistent

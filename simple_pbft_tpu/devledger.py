@@ -35,10 +35,13 @@ plain int/float adds — observability, not control flow. Works under
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+log = logging.getLogger(__name__)
 
 LANE_ED25519 = "ed25519"
 LANE_BLS = "bls"
@@ -114,6 +117,7 @@ class DeviceLedger:
         self._tls = threading.local()
         self.node_id = ""
         self.profile_captures = 0
+        self.profile_failures = 0
         self.profile_last_dir: Optional[str] = None
         self._profile_armed = False
         self._reset_locked()
@@ -286,6 +290,7 @@ class DeviceLedger:
                 "lanes": lanes,
                 "shapes": shapes,
                 "profile_captures": self.profile_captures,
+                "profile_failures": self.profile_failures,
             }
             for k in TOP_MIRROR_KEYS:
                 out[k] = top_src[k] if top_src else _EMPTY_TOP[k]
@@ -323,10 +328,15 @@ class DeviceLedger:
 
     def arm_profile(self, out_dir: str, seconds: float) -> bool:
         """Arm ONE bounded ``jax.profiler`` trace capture on a sidecar
-        daemon thread — off-loop, never in a consensus path, never
-        raises, and a second arm while one is running is a no-op.
-        Artifacts land under ``out_dir`` (the flight dir in node.py /
-        bench_consensus). Returns whether a capture was armed."""
+        daemon thread — off-loop, never in a consensus path, and a second
+        arm while one is running is a no-op. While the capture is open
+        the span layer annotates (the heartbeat sees the open capture
+        within a tick: spans.sync_annotating): the loop-held stages and
+        the verify threads' stages land in the trace's host planes, on
+        the device planes' clock. A capture that fails is
+        logged and counted (``profile_failures``), never raised into the
+        node. Artifacts land under ``out_dir`` (the flight dir in node.py
+        / bench_consensus). Returns whether a capture was armed."""
         if not self._enabled or self._profile_armed or seconds <= 0:
             return False
         self._profile_armed = True
@@ -345,8 +355,9 @@ class DeviceLedger:
                     jax.profiler.stop_trace()
                 self.profile_captures += 1
                 self.profile_last_dir = out_dir
-            except Exception:  # noqa: BLE001 — capture is best-effort
-                pass
+            except Exception:  # noqa: BLE001 — the node outlives a capture
+                self.profile_failures += 1
+                log.exception("device profile capture into %s failed", out_dir)
             finally:
                 self._profile_armed = False
 

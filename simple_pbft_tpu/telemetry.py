@@ -553,37 +553,63 @@ class FlightRecorder:
 
 
 class LoopLagGauge:
-    """Event-loop scheduling-delay gauge: how late does a sleep wake up.
+    """The process's heartbeat: one task that sleeps ``interval`` and
+    reads the event loop's health at each wake-up.
 
-    A task sleeps ``interval`` and measures the overshoot — the time the
-    loop spent running OTHER callbacks past this task's due time. On a
-    healthy loop that is microseconds; a loop starved by a long callback
-    (a big batch prepped inline, a pairing that leaked onto the loop) or
-    a contended core (the r5 qc256 suspicion: one dispatcher core fed by
-    256 replicas) reads tens to hundreds of ms. Max + EMA land in every
-    snapshot, so starvation is a gauge, not an inference."""
+    How late a sleep wakes is the time the loop spent running OTHER
+    callbacks past this task's due time. On a healthy loop that is
+    microseconds; a loop starved by a long callback (a big batch prepped
+    inline, a pairing that leaked onto the loop) or a contended core (the
+    r5 qc256 suspicion: one dispatcher core fed by 256 replicas) reads
+    tens to hundreds of ms. Max + EMA land in every snapshot, so
+    starvation is a gauge, not an inference.
 
-    def __init__(self, interval: float = 0.1):
+    Each tick also feeds the span layer (ISSUE 26; spans.LoopBeat):
+    ``loop.lag``, ``loop.offcpu`` and ``loop.unattributed`` beside the
+    nine loop-held stages, ``gc.pause`` while the gauge runs, and the
+    annotation flag, which follows an open profiler capture."""
+
+    def __init__(self, interval: float = 0.05):
         self.interval = interval
-        self.max_ms = 0.0
+        # the gauge's own: the recency-weighted reading and the last one.
+        # Count and maximum are the ``loop.lag`` accumulator's
         self.ema_ms = 0.0
         self.last_ms = 0.0
-        self.samples = 0
         self._task: Optional[asyncio.Task] = None
 
+    @property
+    def samples(self) -> int:
+        from . import spans
+
+        return spans.recorder().accum(spans.LOOP_LAG).count
+
+    @property
+    def max_ms(self) -> float:
+        from . import spans
+
+        return spans.recorder().accum(spans.LOOP_LAG).max * 1e3
+
     async def _run(self) -> None:
-        while True:
-            due = clock.now() + self.interval
-            await clock.sleep(self.interval)
-            lag_ms = max(0.0, (clock.now() - due)) * 1e3
-            self.last_ms = lag_ms
-            self.samples += 1
-            if lag_ms > self.max_ms:
-                self.max_ms = lag_ms
-            self.ema_ms = (
-                lag_ms if self.samples == 1
-                else 0.9 * self.ema_ms + 0.1 * lag_ms
-            )
+        from . import spans
+
+        beat = spans.LoopBeat()
+        first = True
+        spans.watch_gc(True)
+        try:
+            while True:
+                due = clock.now() + self.interval
+                await clock.sleep(self.interval)
+                lag = max(0.0, clock.now() - due)
+                lag_ms = lag * 1e3
+                self.ema_ms = (
+                    lag_ms if first
+                    else 0.9 * self.ema_ms + 0.1 * lag_ms
+                )
+                self.last_ms = lag_ms
+                first = False
+                beat.tick(lag)  # loop.lag: the count and the maximum
+        finally:
+            spans.watch_gc(False)
 
     def start(self) -> None:
         self._task = asyncio.get_running_loop().create_task(self._run())

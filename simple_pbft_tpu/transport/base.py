@@ -319,7 +319,13 @@ class Transport(Protocol):
     """One node's handle on the network. Sends are fire-and-forget (the
     reference's semantics: http.Post with the response ignored,
     node.go:101-129); reliability comes from the protocol layer (quorums,
-    retransmit-on-timeout), not the transport."""
+    retransmit-on-timeout), not the transport.
+
+    ``send`` and ``broadcast`` are coroutines that never suspend: they
+    deliver in place (local) or enqueue for a pump task that owns the
+    socket (tcp, grpc), and drop when the outbox is full. The loop-held
+    stage accounting (spans.py) times them as held time on that footing;
+    tests/test_loop_stages.py holds every transport in the tree to it."""
 
     node_id: str
 

@@ -5,6 +5,7 @@ by request id."""
 
 import asyncio
 import json
+import time
 
 import pytest
 
@@ -106,6 +107,18 @@ def test_snapshot_absorbs_all_four_surfaces_after_traffic():
 # ---------------------------------------------------------------------------
 
 
+async def _executed(com, seq: int = 1, timeout: float = 10.0) -> None:
+    """Wait until every replica has executed ``seq``. The client accepts
+    2f+1 SPECULATIVE replies before any replica has executed the block,
+    so a test that reads execution state right after ``submit`` returns
+    races the commit phase."""
+    deadline = time.monotonic() + timeout
+    while any(r.executed_seq < seq for r in com.replicas):
+        assert time.monotonic() < deadline, [
+            (r.id, r.executed_seq) for r in com.replicas]
+        await asyncio.sleep(0.01)
+
+
 def test_status_server_serves_metrics_mid_run():
     """Acceptance criterion: scraping a node's /metrics.json MID-RUN
     returns the unified snapshot — no shutdown required."""
@@ -117,6 +130,7 @@ def test_status_server_serves_metrics_mid_run():
         await srv.start()
         try:
             assert await com.clients[0].submit("put k v") == "ok"
+            await _executed(com)
             status, body = await _http_get(srv.bound_port, "/metrics.json")
             assert status == 200
             snap = json.loads(body)
@@ -231,6 +245,7 @@ def test_trace_joins_client_and_replica_phases():
         com.start()
         try:
             assert await com.clients[0].submit("put traced v") == "ok"
+            await _executed(com)
         finally:
             await com.stop()
 
@@ -284,6 +299,7 @@ def test_trace_jsonl_sink_and_trace_endpoint(tmp_path):
         await srv.start()
         try:
             assert await com.clients[0].submit("put k v") == "ok"
+            await _executed(com)
             status, body = await _http_get(srv.bound_port, "/trace.json")
             assert status == 200
             doc = json.loads(body)
@@ -338,10 +354,7 @@ def test_bench_committee_telemetry_aggregate():
             assert await com.clients[0].submit("put k v") == "ok"
             # settle past the speculative fast answer (ISSUE 15): the
             # aggregate must see every replica's commit applied
-            for _ in range(100):
-                if all(r.executed_seq >= 1 for r in com.replicas):
-                    break
-                await asyncio.sleep(0.05)
+            await _executed(com)
             agg = bench_consensus._committee_telemetry(com)
             assert agg["schema"] == SCHEMA_VERSION
             assert agg["replicas_running"] == 4

@@ -37,12 +37,40 @@ COLUMNS = (
     "NODE", "SRC", "VIEW", "ROLE", "EXEC", "STABLE", "CAGE", "BACKLOG",
     "VQ", "QCQ", "QCB", "PAIRms", "SHED", "DEG", "QUAR", "REJ", "WDOG",
     "AUD", "SPEC", "LOAD", "CTL", "NET", "NETIO", "DEV", "TRACE", "RTTms",
-    "LAGms", "REQ/s",
+    "LAGms", "LOOP", "REQ/s",
 )
 
 
 def _fmt_kib(b: float) -> str:
     return f"{b / 1024:.0f}K" if b < 10 * 1024 * 1024 else f"{b / (1024 * 1024):.1f}M"
+
+
+def loop_cell(snap: dict, prev: Optional[dict]) -> str:
+    """LOOP: where the event loop's thread went (ISSUE 26) — the
+    loop-held stage with the most self time and the off-CPU time, each
+    in percent of the time accounted (the nine ``loop.*`` stages,
+    ``loop.unattributed`` and ``loop.offcpu``, which together are the
+    wall time): ``ingest38 off12``. Between refreshes in the live loop,
+    cumulative post-mortem / on the first frame. Blank for a snapshot
+    without the accumulators. One loop runs every in-process node, so
+    every row of such a committee reads the same."""
+    def sums(doc: Optional[dict]) -> Dict[str, float]:
+        stages = ((doc or {}).get("spans") or {}).get("stages") or {}
+        return {k: v.get("sum", 0.0) for k, v in stages.items()
+                if k.startswith("loop.") and k != "loop.lag"}
+
+    now, before = sums(snap), sums(prev)
+    took = {k: v - before.get(k, 0.0) for k, v in now.items()}
+    if any(v < 0 for v in took.values()):
+        took = now  # spans.configure() reset the surface between frames
+    total = sum(took.values())
+    held = {k: v for k, v in took.items()
+            if k not in ("loop.offcpu", "loop.unattributed")}
+    if total <= 0 or not held:
+        return ""
+    top = max(held, key=held.get)
+    return (f"{top[5:]}{100 * held[top] / total:.0f} "
+            f"off{100 * took.get('loop.offcpu', 0.0) / total:.0f}")
 
 
 def netio_cell(snap: dict, prev: Optional[dict], dt: float) -> str:
@@ -374,6 +402,7 @@ def row_from_snapshot(snap: dict, src: str, prev: Optional[dict],
         trace_cell(snap),
         (f"{ver['rtt_ms_ema']:.0f}" if "rtt_ms_ema" in ver else ""),
         (f"{lag['ema_ms']:.1f}" if "ema_ms" in lag else ""),
+        loop_cell(snap, prev),
         rate,
     ]
 

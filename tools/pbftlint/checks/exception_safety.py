@@ -57,6 +57,13 @@ TELEMETRY_ROOTS = {
 # — the audited no-raise surface. Each target's existence is checked.
 AUDITED_NO_RAISE: Dict[Tuple[str, str], Tuple[str, Optional[str], str]] = {
     ("spans", "record"): ("simple_pbft_tpu/spans.py", None, "record"),
+    # loop-held stages (ISSUE 26): a section is two clock reads and a few
+    # adds on the loop's own state; charge() the same without the clock
+    ("spans", "held"): ("simple_pbft_tpu/spans.py", "held", "__init__"),
+    ("spans", "begin"): ("simple_pbft_tpu/spans.py", None, "begin"),
+    ("spans", "end"): ("simple_pbft_tpu/spans.py", None, "end"),
+    ("spans", "parked"): ("simple_pbft_tpu/spans.py", "parked", "__enter__"),
+    ("spans", "charge"): ("simple_pbft_tpu/spans.py", None, "charge"),
     ("tracer", "emit"): (
         "simple_pbft_tpu/telemetry.py", "RequestTracer", "emit"),
     ("tracer", "note_block"): (
