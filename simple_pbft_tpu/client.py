@@ -17,13 +17,20 @@ import asyncio
 import itertools
 import random
 from collections import OrderedDict, defaultdict, deque
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 from . import clock, spans
 from .config import CommitteeConfig, config_from_doc
 from .crypto.signer import Signer
 from .crypto.verifier import BatchItem, Verifier, best_cpu_verifier
-from .messages import ConfigFetch, ConfigReply, Message, Reply, Request
+from .messages import (
+    ConfigFetch,
+    ConfigReply,
+    Message,
+    Reply,
+    ReplyBatch,
+    Request,
+)
 from .transport.base import Transport
 
 
@@ -74,6 +81,11 @@ class Client:
         # completed after at least one retry (the "shed then recovered"
         # signature — distinguishes overload shedding from real loss)
         self.metrics: Dict[str, int] = defaultdict(int)
+        # reply frames taken off the wire (Reply or ReplyBatch), and the
+        # entries that came inside a ReplyBatch; present from the start so
+        # a counter reader sees 0 where nothing was batched
+        self.metrics["reply_frames"] = 0
+        self.metrics["reply_entries_batched"] = 0
         # Hedged first send: also deliver each request to `hedge` backups
         # (rotating), who relay it to the primary and arm their failover
         # timers on first receipt. Kills the worst-case failover tail
@@ -184,7 +196,12 @@ class Client:
         if isinstance(msg, ConfigReply):
             self._on_config_reply(msg)
             return
-        if not isinstance(msg, Reply) or msg.client_id != self.id:
+        if not isinstance(msg, Reply):
+            if isinstance(msg, ReplyBatch):
+                self._on_reply_batch(msg)
+            return
+        self.metrics["reply_frames"] += 1
+        if msg.client_id != self.id:
             return
         if msg.sender not in self.cfg.replica_ids:
             return  # only replicas may answer; f+1 matching assumes it
@@ -199,73 +216,112 @@ class Client:
             # confirmation still verifies: the f+1 final quorum the
             # confirmation trusts must be signature-checked.)
             return
-        if self.cfg.verify_signatures:
-            if msg.mac:
-                # point-to-point fast path: HMAC under the shared key
-                # with the claimed sender (crypto/mac.py)
-                from .crypto import mac as mac_mod
-
-                key = self._mac.key_for(msg.sender)
-                if key is None or not mac_mod.tag_valid(
-                    key, msg.signing_payload(), msg.mac
-                ):
-                    return
-            else:
-                pub = self.cfg.pubkey(msg.sender)
-                if pub is None or not msg.sig:
-                    return
-                try:
-                    sig = bytes.fromhex(msg.sig)
-                except ValueError:
-                    return
-                ok = self.verifier.verify_batch(
-                    [
-                        BatchItem(
-                            pubkey=pub, msg=msg.signing_payload(), sig=sig
-                        )
-                    ]
-                )
-                if not ok[0]:
-                    return
+        if not self._authentic(msg):
+            return
         if fut is None or fut.done():
             self._on_confirm(msg)
         else:
             self._on_reply(msg)
 
+    def _authentic(self, msg: Union[Reply, ReplyBatch]) -> bool:
+        """Is the frame's ONE authenticator (over its whole signing
+        payload) valid under the claimed sender's key?"""
+        if not self.cfg.verify_signatures:
+            return True
+        if msg.mac:
+            # point-to-point fast path: HMAC under the shared key
+            # with the claimed sender (crypto/mac.py)
+            from .crypto import mac as mac_mod
+
+            key = self._mac.key_for(msg.sender)
+            return key is not None and mac_mod.tag_valid(
+                key, msg.signing_payload(), msg.mac
+            )
+        pub = self.cfg.pubkey(msg.sender)
+        if pub is None or not msg.sig:
+            return False
+        try:
+            sig = bytes.fromhex(msg.sig)
+        except ValueError:
+            return False
+        return bool(self.verifier.verify_batch(
+            [BatchItem(pubkey=pub, msg=msg.signing_payload(), sig=sig)]
+        )[0])
+
+    def _on_reply_batch(self, msg: ReplyBatch) -> None:
+        """One replica's replies to several of OUR requests of one block
+        (messages.ReplyBatch): every entry somebody still waits for counts
+        exactly as a ``Reply`` from that sender would — after the frame's
+        one authenticator has been checked, and not at all if it fails."""
+        self.metrics["reply_frames"] += 1
+        if (
+            msg.client_id != self.id
+            or msg.sender not in self.cfg.replica_ids
+            or len(msg.timestamps) != len(msg.results)
+        ):
+            return
+        self.metrics["reply_entries_batched"] += len(msg.timestamps)
+        spec = bool(msg.spec)
+        waiters = self._waiters
+        wanted = []
+        for ts, result in zip(msg.timestamps, msg.results):
+            fut = waiters.get(ts)
+            if (fut is not None and not fut.done()) or (
+                # only a FINAL entry can confirm a speculative answer
+                not spec and ts in self._confirming
+            ):
+                wanted.append((ts, result))
+        if not wanted:
+            return  # a late frame: dropped unchecked, as a late Reply is
+        if not self._authentic(msg):
+            return
+        sender, view, seq, epoch = msg.sender, msg.view, msg.seq, msg.epoch
+        for ts, result in wanted:
+            fut = waiters.get(ts)
+            if fut is None or fut.done():
+                self._confirm(sender, spec, ts, result, False)
+            else:
+                self._count(sender, view, seq, spec, epoch, ts, result, False)
+
     def _on_reply(self, msg: Reply) -> None:
-        ts = msg.timestamp
+        self._count(
+            msg.sender, msg.view, msg.seq, bool(getattr(msg, "spec", 0)),
+            msg.epoch, msg.timestamp, msg.result, bool(msg.superseded),
+        )
+
+    def _count(self, sender: str, view: int, seq: int, spec: bool,
+               epoch: int, ts: int, result: str, superseded: bool) -> None:
+        """Count one authenticated reply (a ``Reply``, or an entry of a
+        ``ReplyBatch``) toward the quorums of timestamp ``ts``."""
         fut = self._waiters.get(ts)
         if fut is None or fut.done():
             return
-        self.view_hint = max(self.view_hint, msg.view)
-        if msg.epoch > self.epoch:
+        self.view_hint = max(self.view_hint, view)
+        if epoch > self.epoch:
             # authenticated reply from a later committee epoch: our
             # address book is stale — re-resolve instead of timing out
             # against removed replicas (the reply itself still counts
             # toward f+1 below; epoch is a hint, not part of matching)
-            self._maybe_refresh_config(msg.epoch)
+            self._maybe_refresh_config(epoch)
         # f+1 matching is on the RESULT only (Castro-Liskov §2.4): honest
         # replicas may execute the same request in different views when a
         # failover re-proposes it, and their replies still agree on the
         # outcome — matching on (result, view) would deadlock exactly
         # when a view change lands mid-request. The view rides along
         # purely as the primary hint above.
-        spec = bool(getattr(msg, "spec", 0))
-        prev = self._replies[ts].get(msg.sender)
+        prev = self._replies[ts].get(sender)
         if prev is not None and not prev[2] and spec:
             # reply accounting (ISSUE 15): this replica already answered
             # FINAL — a late speculative copy must neither double-count
             # nor downgrade the recorded mark
             return
-        self._replies[ts][msg.sender] = (
-            msg.result, bool(msg.superseded), spec, msg.seq, msg.view,
-        )
+        self._replies[ts][sender] = (result, superseded, spec, seq, view)
         counts_final: Dict[tuple, int] = defaultdict(int)
         counts_slot: Dict[tuple, int] = defaultdict(int)
-        for result, superseded, sp, seq, view in self._replies[ts].values():
-            counts_slot[(result, superseded, seq, view)] += 1
+        for res, sup, sp, at_seq, at_view in self._replies[ts].values():
+            counts_slot[(res, sup, at_seq, at_view)] += 1
             if not sp:
-                counts_final[(result, superseded)] += 1
+                counts_final[(res, sup)] += 1
         # final answer: f+1 matching non-speculative replies (classic —
         # matching ignores seq/view: honest replicas execute the same
         # request at the same agreed slot, and the result alone is what
@@ -286,9 +342,9 @@ class Client:
         # request speculated at different seqs — or at the same seq
         # under different views' re-proposals, each with <= f honest
         # preparers — must never pool into a fake quorum.
-        for (result, superseded, _seq, _view), cnt in counts_slot.items():
+        for (res, sup, _seq, _view), cnt in counts_slot.items():
             if cnt >= self.cfg.quorum:
-                self._resolve(ts, fut, (result, superseded), "spec")
+                self._resolve(ts, fut, (res, sup), "spec")
                 return
         # Mixed superseded/real split with no quorum: a checkpoint fold
         # raced our retransmission — replicas that folded answer
@@ -337,28 +393,35 @@ class Client:
             fut.set_result(result)
 
     def _on_confirm(self, msg: Reply) -> None:
-        """A signature-verified reply for a speculatively-accepted ts:
+        self._confirm(
+            msg.sender, bool(getattr(msg, "spec", 0)), msg.timestamp,
+            msg.result, bool(msg.superseded),
+        )
+
+    def _confirm(self, sender: str, spec: bool, ts: int, result: str,
+                 superseded: bool) -> None:
+        """An authenticated reply for a speculatively-accepted ts:
         count FINAL copies toward the f+1 confirmation quorum."""
-        ent = self._confirming.get(msg.timestamp)
-        if ent is None or getattr(msg, "spec", 0) or msg.superseded:
+        ent = self._confirming.get(ts)
+        if ent is None or spec or superseded:
             return
-        if msg.result != ent["result"]:
+        if result != ent["result"]:
             # A single contradicting final can be one byzantine replica
             # (well within f) — it must neither fire the alarm nor
             # destroy confirmation tracking. Only f+1 DISTINCT
             # contradictors prove the COMMITTEE contradicted the 2f+1
             # speculative quorum — impossible under quorum intersection
             # unless > f replicas are faulty; surface THAT loudly.
-            ent["contradicting"].add(msg.sender)
+            ent["contradicting"].add(sender)
             if len(ent["contradicting"]) >= self.cfg.weak_quorum:
                 self.metrics["spec_final_mismatch"] += 1
-                del self._confirming[msg.timestamp]
+                del self._confirming[ts]
             return
-        ent["senders"].add(msg.sender)
+        ent["senders"].add(sender)
         if len(ent["senders"]) >= self.cfg.weak_quorum:
             self.metrics["final_confirms"] += 1
             self.confirm_latencies.append(clock.now() - ent["t0"])
-            del self._confirming[msg.timestamp]
+            del self._confirming[ts]
 
     def _bg(self, coro) -> None:
         """Launch a fire-and-forget send: hold the task reference (GC can

@@ -33,7 +33,7 @@ import hashlib
 import logging
 import threading
 from collections import OrderedDict, defaultdict
-from typing import Any, Dict, List, Optional, Set, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple, Union
 
 from .. import clock, spans, trace
 from ..app import Application, KVStore
@@ -63,6 +63,7 @@ from ..messages import (
     Prepare,
     QuorumCert,
     Reply,
+    ReplyBatch,
     Request,
     SlotFetch,
     StateChunkReply,
@@ -334,11 +335,11 @@ class Replica:
         # actually leave the process and hit the honest peers' role gate
         self.refuse_retirement = False
 
-    def _auth_reply(self, reply: Reply) -> None:
-        """Authenticate a reply: per-client HMAC when BOTH ends publish kx
-        keys (~2 us) — the client derives the same key from OUR published
-        kx pubkey, so a replica absent from kx_pubkeys must sign instead
-        or its MAC'd replies are undecipherable. Ed25519 otherwise."""
+    def _auth_reply(self, reply: Union[Reply, ReplyBatch]) -> None:
+        """Authenticate a reply frame: per-client HMAC when BOTH ends
+        publish kx keys (~2 us) — the client derives the same key from OUR
+        published kx pubkey, so a replica absent from kx_pubkeys must sign
+        instead or its MAC'd replies are undecipherable. Ed25519 otherwise."""
         from ..crypto import mac as mac_mod
 
         key = (
@@ -351,6 +352,52 @@ class Replica:
             reply.mac = mac_mod.tag(key, reply.signing_payload())
         else:
             self.signer.sign_msg(reply)
+
+    def _batch(self, members: List[Reply]) -> ReplyBatch:
+        """One frame for the replies owed one client for one slot."""
+        first = members[0]
+        self.metrics["reply_entries_batched"] += len(members)
+        return ReplyBatch(
+            view=first.view,
+            seq=first.seq,
+            client_id=first.client_id,
+            spec=first.spec,
+            epoch=first.epoch,
+            timestamps=[m.timestamp for m in members],
+            results=[m.result for m in members],
+        )
+
+    def _reply_frames(
+        self, replies: List[Reply]
+    ) -> Sequence[Union[Reply, ReplyBatch]]:
+        """The authenticated frames for the replies this replica owes for
+        one block (or, after a re-speculation, several): one frame per
+        (slot, client). A client owed one reply gets that ``Reply``, as
+        ever; a client owed several (a pipelined client fills a block with
+        its own requests) gets one ``ReplyBatch`` under one authenticator.
+        Frames keep block order, and so does every frame's entries. The
+        ``replies`` themselves stay unauthenticated when batched: the ones
+        ``recent_replies`` holds are signed on demand if retransmitted."""
+        frames: Sequence[Union[Reply, ReplyBatch]]
+        if len({r.client_id for r in replies}) == len(replies):
+            frames = replies  # nobody is owed two: the list as it came
+        else:
+            groups: Dict[Tuple[int, str], List[Reply]] = {}
+            for r in replies:
+                key = (r.seq, r.client_id)
+                members = groups.get(key)
+                if members is None:
+                    groups[key] = [r]
+                else:
+                    members.append(r)
+            frames = [
+                members[0] if len(members) == 1 else self._batch(members)
+                for members in groups.values()
+            ]
+        for frame in frames:
+            self._auth_reply(frame)
+        self.metrics["reply_frames_sent"] += len(frames)
+        return frames
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -1627,10 +1674,23 @@ class Replica:
                     # digest losing to the committed one voids the fork
                     self.spec.before_finalize(act)
                 final_results: Dict[Tuple[str, int], str] = {}
-                # replies are signed and sent one by one between the
-                # applies; their time is summed here and charged once
-                # per block
-                sent, signing, sending = 0, 0.0, 0.0
+                # Designated repliers: cfg.repliers replicas (f+1 plus a
+                # few loss-tolerance spares, rotating by seq) sign and
+                # transmit — f+1 matching is all the client can use, so
+                # the remaining signatures and sends were pure waste (at
+                # n=100: ~58 signs + client-side decodes per request).
+                # Everyone still CACHES the reply: if the designated set
+                # is unlucky (drops, faults), the client's retransmission
+                # hits the _on_request duplicate branch, where every
+                # replica signs-on-demand and resends the cached reply
+                # (the liveness fallback).
+                designated = (
+                    not self.retired
+                    and (self._index - act.seq) % self.cfg.n
+                    < self.cfg.repliers
+                )
+                owed: List[Reply] = []  # in block order
+                traced: List[str] = []
                 for req in reqs:
                     self.relay_buffer.pop((req.client_id, req.timestamp), None)
                     if req.ack > self.client_ack.get(req.client_id, 0):
@@ -1692,38 +1752,31 @@ class Replica:
                     self.recent_replies.setdefault(req.client_id, {})[
                         req.timestamp
                     ] = reply
-                    # Designated repliers: cfg.repliers replicas (f+1 plus a
-                    # few loss-tolerance spares, rotating by seq) sign and
-                    # transmit — f+1 matching is all the client can use, so
-                    # the remaining signatures and sends were pure waste (at
-                    # n=100: ~58 signs + client-side decodes per request).
-                    # Everyone still CACHES the reply: if the designated set
-                    # is unlucky (drops, faults), the client's retransmission
-                    # hits the _on_request duplicate branch, where every
-                    # replica signs-on-demand and resends the cached reply
-                    # (the liveness fallback).
-                    if (
-                        not self.retired
-                        and (self._index - act.seq) % self.cfg.n
-                        < self.cfg.repliers
-                    ):
-                        t_sign = clock.now()
-                        self._auth_reply(reply)
-                        t_send = clock.now()
-                        self.metrics["replies_sent"] += 1
-                        await self.transport.send(
-                            req.client_id, reply.to_wire()
-                        )
-                        sent += 1
-                        signing += t_send - t_sign
-                        sending += clock.now() - t_send
+                    if designated:
+                        owed.append(reply)
                         if trace_rid:
-                            self.tracer.emit(
-                                "reply", trace_rid, view=act.view, seq=act.seq
-                            )
-                if sent:
-                    spans.charge(spans.LOOP_SIGN_REPLY, signing, sent)
-                    spans.charge(spans.LOOP_SEND, sending, sent)
+                            traced.append(trace_rid)
+                if owed:
+                    # the block's replies leave after its applies, one
+                    # frame per client; both stages are charged once
+                    t_sign = clock.now()
+                    frames = self._reply_frames(owed)
+                    t_send = clock.now()
+                    for frame in frames:
+                        await self.transport.send(
+                            frame.client_id, frame.to_wire()
+                        )
+                    spans.charge(
+                        spans.LOOP_SIGN_REPLY, t_send - t_sign, len(frames)
+                    )
+                    spans.charge(
+                        spans.LOOP_SEND, clock.now() - t_send, len(frames)
+                    )
+                    self.metrics["replies_sent"] += len(owed)
+                    for trace_rid in traced:
+                        self.tracer.emit(
+                            "reply", trace_rid, view=act.view, seq=act.seq
+                        )
                 if self.spec is not None:
                     # confirm (or roll back) the slot's speculation, and
                     # keep the fork in lockstep across unspeculated slots
@@ -1785,15 +1838,15 @@ class Replica:
         from the final reply once it lands."""
         if not replies:
             return
-        # all signed, then all sent, in the same order as before: one
-        # section of each stage per list, not one per reply
-        with spans.held(spans.LOOP_SIGN_REPLY, len(replies)):
-            for reply in replies:
-                self._auth_reply(reply)
-        with spans.held(spans.LOOP_SEND, len(replies)):
-            for reply in replies:
-                self.metrics["spec_replies_sent"] += 1
-                await self.transport.send(reply.client_id, reply.to_wire())
+        # all signed, then all sent, in block order: one section of each
+        # stage per list, not one per frame
+        with spans.held(spans.LOOP_SIGN_REPLY) as sec:
+            frames = self._reply_frames(replies)
+            sec.n = len(frames)
+        with spans.held(spans.LOOP_SEND, len(frames)):
+            for frame in frames:
+                await self.transport.send(frame.client_id, frame.to_wire())
+        self.metrics["spec_replies_sent"] += len(replies)
 
     # ------------------------------------------------------------------
     # live membership reconfiguration (ISSUE 7 tentpole, pillar 3)

@@ -306,8 +306,8 @@ class Message:
 
     #: authenticator fields blanked out of every signing payload (a tag
     #: cannot cover itself); subclasses with additional authenticators
-    #: extend this (Reply adds "mac") — __setattr__'s invalidation
-    #: exemptions must stay in sync with the union of these.
+    #: extend this (Reply and ReplyBatch add "mac") — __setattr__'s
+    #: invalidation exemptions must stay in sync with the union of these.
     _AUTH_FIELDS: ClassVar[Tuple[str, ...]] = ("sig",)
 
     def signing_payload(self) -> bytes:
@@ -419,6 +419,33 @@ class Reply(Message):
 
     #: both authenticators blank out of the payload so sig and mac attest
     #: the same bytes and either can authenticate interchangeably
+    _AUTH_FIELDS: ClassVar[Tuple[str, ...]] = ("sig", "mac")
+
+
+@dataclass
+class ReplyBatch(Message):
+    """Every reply one replica owes ONE client for ONE block, in one
+    frame under one authenticator: entry i says what a ``Reply`` with
+    this frame's sender, ``view``, ``seq``, ``spec`` and ``epoch`` and
+    ``timestamp=timestamps[i]``, ``result=results[i]`` says. A pipelined
+    client has many requests in one block, and their replies from one
+    replica agree in everything else. Sent only for two or more entries
+    (one entry is a ``Reply``, so a single-request client sees no new
+    kind), never for superseded answers, and never cached: the reply
+    cache and retransmissions stay single ``Reply`` messages."""
+
+    KIND: ClassVar[str] = "replybatch"
+
+    view: int = 0
+    seq: int = 0
+    client_id: str = ""
+    spec: int = 0
+    epoch: int = 0
+    timestamps: List[int] = field(default_factory=list)
+    results: List[str] = field(default_factory=list)
+    mac: str = ""
+
+    #: as Reply: either authenticator attests the same bytes
     _AUTH_FIELDS: ClassVar[Tuple[str, ...]] = ("sig", "mac")
 
 

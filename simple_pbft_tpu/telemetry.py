@@ -78,6 +78,7 @@ BENCH_SCHEMA_VERSION = 1
 WIRE_PHASE_OF_KIND = {
     "request": "request",
     "reply": "reply",
+    "replybatch": "reply",
     "preprepare": "preprepare",
     "prepare": "prepare",
     "commit": "commit",

@@ -424,3 +424,246 @@ def test_committee_over_tpu_verifier():
             assert r.metrics["sweep_errors"] == 0
 
     run(scenario(), timeout=240)
+
+
+# -- reply batching: one frame per (replica, client, block) ----------------
+
+
+def _tap(client):
+    """Record every frame the client takes off the wire, as
+    (kind, raw bytes), before the client sees it."""
+    from simple_pbft_tpu.transport.base import wire_kind
+
+    frames = []
+    seen = client._on_wire
+
+    def on_wire(raw):
+        frames.append((wire_kind(raw), raw))
+        seen(raw)
+
+    client._on_wire = on_wire
+    return frames
+
+
+async def _settled(com, timeout=10.0):
+    """Wait until every replica has executed what the quorum committed,
+    and the clients have read what that sent them."""
+    deadline = asyncio.get_running_loop().time() + timeout
+    while (len({r.executed_seq for r in com.replicas}) > 1
+           and asyncio.get_running_loop().time() < deadline):
+        await asyncio.sleep(0.02)
+    await asyncio.sleep(0.05)
+
+
+def test_pipelined_client_is_answered_in_reply_batches():
+    """16 puts of ONE client in flight at n=4: every put is acknowledged,
+    every replica's state equals the plain reference, and the replies
+    came as ``replybatch`` frames, one per replica and block."""
+    import json
+
+    async def scenario():
+        com = LocalCommittee.build(n=4, clients=1)
+        client = com.clients[0]
+        frames = _tap(client)
+        reference = {}
+        com.start()
+        try:
+            for wave in range(4):
+                puts = {f"k{i}": f"v{wave}.{i}" for i in range(16)}
+                results = await asyncio.gather(
+                    *(client.submit(f"put {k} {v}") for k, v in puts.items())
+                )
+                assert results == ["ok"] * 16
+                reference.update(puts)
+            await _settled(com)
+        finally:
+            await com.stop()
+        for r in com.replicas:
+            assert json.loads(r.app.snapshot()) == reference, r.id
+        assert len({r.app.state_digest() for r in com.replicas}) == 1
+        assert len({r.executed_seq for r in com.replicas}) == 1
+        sent = {
+            key: sum(r.metrics[key] for r in com.replicas)
+            for key in ("reply_entries_batched", "reply_frames_sent",
+                        "replies_sent", "spec_replies_sent")
+        }
+        assert sent["reply_entries_batched"] > 0
+        # fewer frames than replies, and every reply is in exactly one
+        assert sent["reply_frames_sent"] < (
+            sent["replies_sent"] + sent["spec_replies_sent"])
+        kinds = [kind for kind, _raw in frames]
+        assert set(kinds) <= {"reply", "replybatch"}
+        assert "replybatch" in kinds
+        assert client.metrics["reply_frames"] == len(kinds)
+        assert client.metrics["reply_entries_batched"] == (
+            sent["reply_entries_batched"])
+        assert sent["reply_frames_sent"] == len(kinds)
+        assert client.metrics.get("spec_final_mismatch", 0) == 0
+        # the reply cache is checkpoint state: one plain Reply a request,
+        # whatever frame carried it
+        from simple_pbft_tpu.messages import Reply
+
+        for r in com.replicas:
+            assert len(r.recent_replies["c0"]) == 64
+            assert all(type(rep) is Reply
+                       for rep in r.recent_replies["c0"].values())
+        assert len({r._checkpoint_snapshot() for r in com.replicas}) == 1
+
+    run(scenario())
+
+
+# sha256 of the frames the PARENT of the reply-batching change sent for
+# (view 0, seq 1, client c0, timestamp 7, result "ok", epoch 0) at n=4,
+# keyed (sender, spec): the MAC'd Reply of each replica
+GOLDEN_REPLY_SHA256 = {
+    ("r0", 1): "9997afe9eb088f041b7a22bef73c98a605608097395da2befe5159112e7d59dd",
+    ("r0", 0): "eaa0611fba79e8179d18b423069bf2e3f17ad32b2b22f84eb96e2db77e58c11b",
+    ("r1", 1): "ba7ccbb878ab7e26a454e03e66447609c8f497c9a0c535cc2a979976a5657954",
+    ("r1", 0): "c8e99c1a184894e00e20c6ff2bf96e8f20e98b619e2cb245d8b386354addc45b",
+    ("r2", 1): "9e08382c81a658ec656cfe508bf8feee13128683ca35bad07ff848613183e383",
+    ("r2", 0): "4619f133de9250a32f2ebb57bff12c548af232814aa97aed01e61108cadb4ff8",
+    ("r3", 1): "03077b800e18392edfb36dd8e24be778ea003e11b16bf6e82bfa65415d47ccff",
+    ("r3", 0): "bb636daffe63920e57c3f10c3677c9c49cb21acb4570263eb3d6e56e4acb6f97",
+}
+GOLDEN_R1_FINAL = (
+    b'{"client_id":"c0","epoch":0,"kind":"reply","mac":"da16c28521d1af26a6c'
+    b'bbb7e6847878ca4ca654379d6d9ab5225e07b7a76329b","result":"ok","sender"'
+    b':"r1","seq":1,"sig":"","spec":0,"superseded":0,"timestamp":7,"view":0}'
+)
+
+
+def test_one_request_in_flight_sends_the_parents_reply_bytes():
+    """A client with one request in flight pays nothing for the batching:
+    no ``replybatch`` frame exists, and each ``Reply`` is byte for byte
+    what the parent commit sent (golden frames, timestamps pinned)."""
+    import hashlib
+    import itertools
+    import json
+
+    from simple_pbft_tpu.crypto import mac as mac_mod
+
+    if not mac_mod.kx_available():
+        pytest.skip("no X25519 backend: replies are signed, not MAC'd")
+
+    async def scenario():
+        com = LocalCommittee.build(n=4, clients=1)
+        client = com.clients[0]
+        client._ts = itertools.count(7)
+        frames = _tap(client)
+        com.start()
+        try:
+            assert await client.submit("put k v") == "ok"
+            first = list(frames)
+            for i in range(5):
+                assert await client.submit(f"put k{i} v") == "ok"
+            await _settled(com)
+        finally:
+            await com.stop()
+        assert {kind for kind, _raw in frames} == {"reply"}
+        assert sum(r.metrics["reply_entries_batched"] for r in com.replicas) == 0
+        assert client.metrics["reply_entries_batched"] == 0
+        assert client.metrics["reply_frames"] == len(frames)
+        assert len(first) >= com.cfg.quorum
+        finals = 0
+        for _kind, raw in first:
+            doc = json.loads(raw)
+            assert doc["timestamp"] == 7
+            finals += not doc["spec"]
+            assert hashlib.sha256(raw).hexdigest() == GOLDEN_REPLY_SHA256[
+                (doc["sender"], doc["spec"])], raw
+        assert hashlib.sha256(GOLDEN_R1_FINAL).hexdigest() == (
+            GOLDEN_REPLY_SHA256[("r1", 0)])
+        every = [raw for _kind, raw in frames]
+        assert GOLDEN_R1_FINAL in every or finals == 0
+
+    run(scenario())
+
+
+def test_a_dropped_batch_is_repaired_by_single_cached_replies():
+    """Lose every ``replybatch`` frame: the pipelined client times out,
+    rebroadcasts each request, and every replica answers from
+    ``recent_replies`` with a single ``Reply`` (signed on demand: the
+    cached copies of batched replies carry no authenticator)."""
+
+    async def scenario():
+        com = LocalCommittee.build(n=4, clients=1)
+        client = com.clients[0]
+        client.request_timeout = 0.4
+        frames = _tap(client)
+        tapped = client._on_wire
+        dropped = []
+
+        def lossy(raw):
+            if b'"kind":"replybatch"' in raw:
+                dropped.append(raw)
+                return
+            tapped(raw)
+
+        client._on_wire = lossy
+        com.start()
+        try:
+            results = await asyncio.gather(
+                *(client.submit(f"put k{i} v{i}") for i in range(8))
+            )
+            assert results == ["ok"] * 8
+        finally:
+            await com.stop()
+        assert dropped, "eight puts in flight were never batched"
+        assert {kind for kind, _raw in frames} == {"reply"}
+        assert client.metrics["retransmissions"] > 0
+        assert client.metrics["recovered_after_retry"] > 0
+        assert client.metrics["reply_entries_batched"] == 0
+        for r in com.replicas:
+            assert r.metrics["committed_requests"] == 8  # at most once
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("auth", ["mac", "sig"])
+def test_reply_frames_are_one_per_slot_and_client_in_block_order(auth):
+    """The sender's grouping, on its own: replies of two slots (what a
+    re-speculation hands over) and three clients become one frame per
+    (slot, client), a lone reply stays a ``Reply``, block order is kept,
+    and a client accepts each frame under the replica's MAC or, where a
+    side publishes no kx key, its Ed25519 signature."""
+    from simple_pbft_tpu.messages import Message, Reply, ReplyBatch
+
+    overrides = {"kx_pubkeys": {}} if auth == "sig" else {}
+    com = LocalCommittee.build(n=4, clients=3, **overrides)
+    rep = com.replica("r2")
+    if auth == "mac" and not rep.cfg.kx_pubkeys:
+        pytest.skip("no X25519 backend: replies are always signed")
+
+    def owed(seq, client, ts):
+        return Reply(view=0, seq=seq, client_id=client, timestamp=ts,
+                     result=f"res{ts}", spec=1, epoch=0)
+
+    replies = [owed(5, "c0", 11), owed(5, "c1", 21), owed(5, "c0", 12),
+               owed(5, "c2", 31), owed(5, "c0", 13), owed(5, "c1", 22),
+               owed(6, "c0", 14), owed(6, "c0", 15), owed(6, "c1", 23)]
+    frames = rep._reply_frames(replies)
+    assert [(type(f), f.seq, f.client_id) for f in frames] == [
+        (ReplyBatch, 5, "c0"), (ReplyBatch, 5, "c1"), (Reply, 5, "c2"),
+        (ReplyBatch, 6, "c0"), (Reply, 6, "c1")]
+    assert frames[0].timestamps == [11, 12, 13]
+    assert frames[0].results == ["res11", "res12", "res13"]
+    assert frames[1].timestamps == [21, 22]
+    assert frames[3].timestamps == [14, 15]
+    assert frames[2] is replies[3] and frames[4] is replies[8]
+    assert rep.metrics["reply_frames_sent"] == 5
+    assert rep.metrics["reply_entries_batched"] == 7
+    for frame in frames:
+        assert frame.sender == "r2" and frame.spec == 1
+        assert bool(frame.mac) == (auth == "mac")
+        assert bool(frame.sig) == (auth == "sig")
+        client = com.clients[int(frame.client_id[1:])]
+        assert client._authentic(Message.from_wire(frame.to_wire()))
+        other = com.clients[(int(frame.client_id[1:]) + 1) % 3]
+        if auth == "mac":  # a MAC speaks to one client only
+            assert not other._authentic(Message.from_wire(frame.to_wire()))
+    # members of a batch stay unauthenticated: signed on demand if resent
+    assert not replies[0].mac and not replies[0].sig
+    # every client owed one reply: the list goes out as it came
+    singles = [owed(7, "c0", 16), owed(7, "c1", 24), owed(7, "c2", 32)]
+    assert rep._reply_frames(singles) == singles
+    assert rep.metrics["reply_entries_batched"] == 7

@@ -11,6 +11,8 @@ def test_roundtrip_all_kinds():
     samples = [
         m.Request(sender="c1", client_id="c1", timestamp=7, operation="put x 1"),
         m.Reply(sender="r0", view=1, seq=2, client_id="c1", timestamp=7, result="ok"),
+        m.ReplyBatch(sender="r0", view=1, seq=2, client_id="c1", spec=1, epoch=3,
+                     timestamps=[7, 8, 9], results=["ok", "", "v\"1"], mac="ab" * 32),
         m.PrePrepare(sender="r0", view=0, seq=1, digest="ab", block=[{"op": 1}]),
         m.Prepare(sender="r1", view=0, seq=1, digest="ab"),
         m.Commit(sender="r2", view=0, seq=1, digest="ab"),
@@ -51,6 +53,27 @@ def test_sign_and_verify_message():
     assert not ed.verify(pub, msg.signing_payload(), bytes.fromhex(msg.sig))
 
 
+def test_reply_batch_payload_blanks_both_authenticators():
+    """sig and mac attest the same bytes, as Reply's do; any entry moves
+    the payload, an authenticator never does."""
+    batch = m.ReplyBatch(sender="r0", view=1, seq=2, client_id="c1",
+                         timestamps=[7, 8], results=["a", "b"])
+    payload = batch.signing_payload()
+    batch.sig = "aa" * 64
+    batch.mac = "bb" * 32
+    assert batch.signing_payload() == payload
+    assert m.Message.from_wire(batch.to_wire()).signing_payload() == payload
+    batch.results = ["a", "c"]
+    assert batch.signing_payload() != payload
+    # one entry says what a Reply with the frame's fields says, under
+    # another kind: the two payloads can never be taken for each other
+    single = m.Reply(sender="r0", view=1, seq=2, client_id="c1", timestamp=7,
+                     result="a")
+    assert single.signing_payload() != m.ReplyBatch(
+        sender="r0", view=1, seq=2, client_id="c1", timestamps=[7],
+        results=["a"]).signing_payload()
+
+
 def test_block_digest_matches_content():
     block = [{"client_id": "c", "timestamp": 1, "operation": "x"}]
     d1 = m.PrePrepare.block_digest(block)
@@ -72,6 +95,14 @@ def test_from_wire_malformed_always_valueerror():
         b'{"kind":"prepare","view":"high"}',
         b'{"kind":"prepare","view":true}',
         b'{"kind":"preprepare","block":"notalist"}',
+        b'{"kind":"replybatch","timestamps":7,"results":["ok"]}',
+        b'{"kind":"replybatch","timestamps":["7"],"results":["ok"]}',
+        b'{"kind":"replybatch","timestamps":[true],"results":["ok"]}',
+        b'{"kind":"replybatch","timestamps":[7.5],"results":["ok"]}',
+        b'{"kind":"replybatch","timestamps":[7],"results":[1]}',
+        b'{"kind":"replybatch","timestamps":[7],"results":[["ok"]]}',
+        b'{"kind":"replybatch","timestamps":[7],"results":"ok"}',
+        b'{"kind":"replybatch","timestamps":[7],"results":["ok"],"spec":"1"}',
         b"\xff\xfe",
     ]
     for raw in bad:
@@ -117,6 +148,8 @@ def test_fuzz_mutated_wires_never_crash():
         m.Request(sender="c1", client_id="c1", timestamp=7, operation="x"),
         m.PrePrepare(sender="r0", view=0, seq=1, digest="ab", block=[{"o": 1}]),
         m.Prepare(sender="r1", view=0, seq=1, digest="ab"),
+        m.ReplyBatch(sender="r0", view=0, seq=1, client_id="c1",
+                     timestamps=[7, 8], results=["ok", "ok"]),
         m.ViewChange(sender="r3", new_view=2, stable_seq=100),
         m.NewView(sender="r2", new_view=2),
     ]
@@ -156,13 +189,14 @@ def test_fuzz_random_json_structures_never_crash():
         if k == 4:
             kind = rng.choice(
                 ["request", "preprepare", "prepare", "commit", "reply",
-                 "checkpoint", "viewchange", "newview", "zzz"]
+                 "replybatch", "checkpoint", "viewchange", "newview", "zzz"]
             )
             return {"kind": kind, "view": gen(depth + 1), "seq": gen(depth + 1)}
         if k == 5:
             return [gen(depth + 1) for _ in range(rng.randrange(4))]
         return {
-            rng.choice(["kind", "view", "block", "sig", "sender", "q"]):
+            rng.choice(["kind", "view", "block", "sig", "sender", "q",
+                        "timestamps", "results"]):
                 gen(depth + 1)
             for _ in range(rng.randrange(4))
         }

@@ -21,6 +21,7 @@ from simple_pbft_tpu.messages import (
     NewView,
     PrePrepare,
     Reply,
+    ReplyBatch,
     Request,
     ViewChange,
     canonical_json,
@@ -103,6 +104,9 @@ def test_real_message_traffic_byte_exact():
         Commit(view=0, seq=1, digest="00" * 32, bls_share="ff" * 48),
         Reply(view=2, seq=7, client_id="c9", timestamp=42, result="ok",
               superseded=1, mac="aa" * 16),
+        ReplyBatch(view=2, seq=7, client_id="c9", spec=1,
+                   timestamps=[1785448550156039, 1785448550156040],
+                   results=["ok", "v\u00e9 \"q\""], mac="aa" * 16),
         ViewChange(new_view=4, stable_seq=64,
                    checkpoint_proof=[{"kind": "checkpoint", "seq": 64,
                                       "state_digest": "ee" * 32}],

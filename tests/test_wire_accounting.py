@@ -271,6 +271,33 @@ class TestDerived:
         assert pc["total_msgs_per_slot"] == 27.0
         assert pc["total_msgs_per_req"] == pytest.approx(54 / 8)
 
+    def test_every_message_kind_has_a_phase_and_batches_are_replies(self):
+        """A kind added to messages.py without a phase would report under
+        "other"; a ``replybatch`` frame is reply-phase traffic, a kind of
+        its own on the wire."""
+        from simple_pbft_tpu.telemetry import WIRE_PHASE_OF_KIND
+        from simple_pbft_tpu.transport.base import wire_kind
+
+        assert set(messages.ALL_KINDS) - {"message"} <= set(WIRE_PHASE_OF_KIND)
+        raw = messages.ReplyBatch(
+            sender="r0", client_id="c0", timestamps=[1, 2],
+            results=['{"kind":"reply"}', "ok"], mac="ab" * 32,
+        ).to_wire()
+        assert wire_kind(raw) == "replybatch"
+        w = WireAccounting("r0")
+        w.account_send("c0", raw)
+        single = messages.Reply(sender="r0", client_id="c0", timestamp=3,
+                                result="ok", mac="ab" * 32).to_wire()
+        w.account_send("c0", single)
+        per_kind = w.per_kind()
+        assert per_kind["replybatch"]["sent_msgs"] == 1
+        assert per_kind["reply"]["sent_msgs"] == 1
+        pc = wire_per_commit(per_kind, slots=1, requests=3)
+        assert pc["per_kind"]["replybatch"]["phase"] == "reply"
+        assert pc["per_phase"]["reply"]["msgs_per_slot"] == 2.0
+        assert pc["per_phase"]["reply"]["bytes_per_slot"] == float(
+            len(raw) + len(single))
+
     def test_aggregate_and_delta(self):
         a = {"prepare": {"sent_msgs": 2, "sent_bytes": 100}}
         b = {"prepare": {"sent_msgs": 5, "sent_bytes": 300},
