@@ -276,9 +276,10 @@ async def run_config(
     # itself seconds a 3 s timer fires before ANY round can finish and
     # the committee view-changes perpetually from t=0, so the timer
     # scales with the verify backend. The 15 s was set on 2026-07-31
-    # against device round trips of 82-372 ms (chip_r05.jsonl); S1
-    # re-derives it from the round trip chip_smoke.py measures, and
-    # --view-timeout overrides it meanwhile.
+    # against device round trips of 82-372 ms (git
+    # 7f473af:bench_results/chip_r05.jsonl); S1 re-derives it from the
+    # round trip chip_smoke.py measures, and --view-timeout overrides it
+    # meanwhile.
     degraded_vt = 3.0 if verifier in ("cpu", "insecure") else 15.0
     com = LocalCommittee.build(
         n=n,
@@ -316,7 +317,7 @@ async def run_config(
     if verifier == "tpu":
         # Pre-pay every (bucket, table-shape) compile BEFORE the timed
         # window, with the committee's REAL key population so the warmed
-        # shapes are the ones live sweeps hit. _shared_jit makes the
+        # shapes are the ones live sweeps hit. The shared jit makes the
         # compiles process-wide, so one warmer covers all n replicas.
         # The warm budget must cover the COALESCED maximum, not one
         # replica's sweep: the service folds every replica's pending

@@ -1,11 +1,11 @@
 """Process-wide coalescing verify service: many replicas, one device pass.
 
-Round-4 evidence (bench_results/chip_r04.jsonl, builder-recorded
-2026-07-31) falsified the naive architecture: with every replica's drain
-sweep making its own blocking device call under the process-wide device
-lock, an n-replica committee pays n device round trips per round of
-votes — n=16 consensus committed 6.4 req/s with the chip in the loop vs
-422 req/s with the CPU verifier. The device batch is shape-padded
+Round-4 evidence (builder-recorded 2026-07-31; git
+7f473af:bench_results/chip_r04.jsonl) falsified the naive architecture:
+with every replica's drain sweep making its own blocking device call
+under the process-wide device lock, an n-replica committee pays n device
+round trips per round of votes — n=16 consensus committed 6.4 req/s with
+the chip in the loop vs 422 req/s with the CPU verifier. The device batch is shape-padded
 anyway, so one pass over EVERYONE's pending items costs the same wall
 clock as one replica's.
 

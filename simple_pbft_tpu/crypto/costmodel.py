@@ -1,34 +1,18 @@
-"""Static device cost model for the verify kernels (ISSUE 14).
+"""Static device cost model for the verify kernel (ISSUE 14).
 
-This codifies the analysis of the round-5 builder memo on the verify
-kernel (2026-07-31, not reproduced since): for each jit shape
-(mode, window, bucket) the kernel's dominant resource draws are an
-analytic function of the geometry —
+For each jit shape (mode, window, bucket) of the device ledger, what the
+kernel draws is an analytic function of the geometry:
 
-- **table-row gathers** (the measured bottleneck): the fused engine
-  gathers ONE packed Niels row per window position per item (the
-  (s_nibble, k_nibble) pair indexes a joint table), the split comb
-  engine gathers TWO (separate base- and A-tables), the ladder gathers
-  none. Row bytes come from ``ops/comb.ROW`` so ``use_row_packing``
-  (128 B rows) is honored automatically.
-- **madds**: one mixed Edwards add per gathered row — w=5 is 52/item,
-  exactly the ``fusion.33`` loop the on-chip profile attributed 39% of
-  a pass to.
-- **host->device wire bytes**: what the staging path actually ships
-  per item (the fused WIRE layout is ~101 B/item; comb re-ships
-  window-decomposed scalars).
+- **table-row gathers**: ONE Niels row per window position per item
+  (the (s_nibble, k_nibble) pair indexes a joint table) — 64 rows of
+  256 B, 16,384 B an item.
+- **madds**: one mixed Edwards add per gathered row.
+- **host->device wire bytes**: what the staging path ships per item
+  (S||k||R + key index + precheck, 101 B).
 
 ``tools/verify_observatory.py`` joins these per-shape constants with
-the device ledger's measured per-shape dispatch counts to print
-achieved-vs-peak gather bandwidth and a dominant-limiter verdict —
-the r05 hand decomposition, recomputed continuously.
-
-Reference peaks are MEASURED operating ceilings, not datasheet
-numbers: ``v5lite`` is the 12.1 GB/s effective gather rate implied by
-the r05 steady state (8192-item w=5 pass, 52 dense 256 B rows/item,
-9.0 ms device time) — the point the w=6 regression pinned as
-gather-bandwidth-bound. On a CPU backend no peak is meaningful and
-callers get ``None``.
+the device ledger's measured per-shape dispatch counts into the bytes
+the kernel gathered and the share of wall clock each stage took.
 """
 
 from __future__ import annotations
@@ -37,63 +21,36 @@ from typing import Any, Dict, Optional
 
 from ..ops import comb
 
-# measured effective gather-bandwidth ceilings by platform key (GB/s).
-# Derivation for v5lite: r05 on-chip profile, device-side 9.0 ms per
-# 8192-item w=5 pass = 8192 * 52 * 256 B / 9.0 ms ~= 12.1e9 B/s at the
-# operating point the window-geometry A/B proved bandwidth-bound.
-PEAK_GATHER_GBPS: Dict[str, float] = {"v5lite": 12.1}
-
 # rough int-op cost of one mixed Edwards add on 17-limb field elements
 # (~8 field muls of 17x17 limb products, mul+add each): used only for
 # arithmetic-intensity context, never for a pass/fail verdict.
 MADD_INT_OPS = 8 * 17 * 17 * 2
 
 
-def shape_cost(
-    mode: str, window: int, bucket: int, row_bytes: Optional[int] = None
-) -> Dict[str, Any]:
+def shape_cost(mode: str, window: int, bucket: int) -> Dict[str, Any]:
     """Per-item and per-pass analytic costs for one jit shape.
 
-    ``mode`` is the ledger's spelling (``fused``/``wire``/``comb``/
-    ``ladder``/arbitrary lane modes); unknown modes return a zero-gather
-    row (pairing lanes, shard wrappers) so callers can sum blindly.
-    ``row_bytes`` overrides the live ``comb.ROW`` width (post-hoc
-    analysis of a packed-row run from an unpacked process).
+    ``mode`` is the ledger's spelling: ``fused`` is the verify kernel;
+    any other lane mode (the QC lane's ``pairing``) returns a
+    zero-gather row so callers can sum blindly.
     """
-    rb = (comb.ROW * 4) if row_bytes is None else int(row_bytes)
-    m = mode.split("/")[0]
-    if m.startswith("wire") or m.startswith("fused"):
-        npos = comb.npos_for(window if window else 4)
-        gathers = npos  # joint (s, k) window: one fused-table row/pos
+    row_bytes = comb.ROW * 4
+    if mode == "fused":
+        gathers = comb.NPOS  # joint (s, k) window: one table row/pos
         wire = 96 + 4 + 1  # S||k||R + a_idx + precheck per item
-    elif m == "comb":
-        npos = comb.NPOS
-        gathers = 2 * npos  # separate base-table and A-table rows
-        wire = 2 * npos * 4 + 4 + 17 * 4 + 4 + 1  # s/k windows + idx + R
-    elif m == "ladder":
-        npos = 256
-        gathers = 0  # no key cache: the ladder recomputes, gathers nothing
-        wire = 2 * 256 * 4 + 4 * (17 * 2 + 2) + 1  # bit arrays + points
     else:
-        return {
-            "mode": mode, "window": window, "bucket": bucket,
-            "gathers_per_item": 0, "row_bytes": rb,
-            "gather_bytes_per_item": 0, "gather_bytes_per_pass": 0,
-            "madds_per_item": 0, "flops_per_item": 0,
-            "wire_bytes_per_item": 0,
-        }
-    gb_item = gathers * rb
-    madds = max(gathers, npos)
+        gathers = wire = 0
+    gb_item = gathers * row_bytes
     return {
         "mode": mode,
         "window": window,
         "bucket": bucket,
         "gathers_per_item": gathers,
-        "row_bytes": rb,
+        "row_bytes": row_bytes,
         "gather_bytes_per_item": gb_item,
         "gather_bytes_per_pass": gb_item * bucket,
-        "madds_per_item": madds,
-        "flops_per_item": madds * MADD_INT_OPS,
+        "madds_per_item": gathers,
+        "flops_per_item": gathers * MADD_INT_OPS,
         "wire_bytes_per_item": wire,
     }
 

@@ -8,15 +8,15 @@ those verifies would sit). This module fills that gap TPU-first:
 
 - The consensus plane drains every pending (pubkey, message, signature)
   tuple into one batch.
-- Host prep is fully vectorized: wire bytes are decoded with numpy (one
-  join + frombuffer per batch, no per-item Python), and the challenge
-  scalars k = SHA-512(R||A||M) mod L come from the native OpenMP batch
-  hasher (simple_pbft_tpu/native/) — sub-microsecond per item, so the
-  host keeps up with the device instead of capping it.
-- One jitted device pass per batch (comb engine by default — see
-  ops/comb.py; or the self-contained Straus ladder). Constant shapes, no
-  data-dependent control flow — every signature costs the same fixed
-  sequence, so XLA compiles one kernel per bucket size.
+- Host prep is vectorized: wire bytes are split with numpy (one join +
+  frombuffer per batch, no per-item Python), and the challenge scalars
+  k = SHA-512(R||A||M) mod L come from the native OpenMP batch hasher
+  (simple_pbft_tpu/native/). The raw (B, 96) bytes go to the device,
+  which unpacks windows and limbs itself.
+- One jitted device pass per batch (the fused comb kernel — see
+  ops/comb.py). Constant shapes, no data-dependent control flow — every
+  signature costs the same fixed sequence, so XLA compiles one kernel
+  per bucket size.
 - Device arrays are limb-major / batch-minor ((17, B) etc., see
   ops/field25519.py) so the batch fills the vector lanes.
 - Batches are padded to bucketed sizes (powers of two) so recompiles are
@@ -31,7 +31,6 @@ double-scalar multiplication and an equality — no second ladder.
 
 from __future__ import annotations
 
-import functools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence
@@ -42,8 +41,6 @@ import numpy as np
 
 from .. import native
 from ..ops import comb
-from ..ops import edwards as ed
-from ..ops import field25519 as fe
 from . import ed25519_cpu as ref
 from .verifier import BatchItem
 
@@ -87,13 +84,6 @@ def _ge_l_np(s_bytes: np.ndarray) -> np.ndarray:
     return gt | undecided  # equal counts as >= L
 
 
-def _bits_msb_first_np(le_bytes: np.ndarray) -> np.ndarray:
-    """(n, 32) uint8 little-endian scalar -> (n, 256) int32 bits MSB
-    first — the ladder consumes the scalar top bit down."""
-    bits = np.unpackbits(le_bytes, axis=-1, bitorder="little")  # LSB first
-    return bits[:, ::-1].astype(np.int32)
-
-
 def _split_items(items: Sequence[BatchItem]):
     """Items -> (pub (n,32), r (n,32), s (n,32), msgs list, wellformed
     (n,) bool) with malformed rows zeroed — one join per field, no
@@ -115,101 +105,6 @@ def _split_items(items: Sequence[BatchItem]):
     return pub, sig[:, :32], sig[:, 32:], msgs, ok
 
 
-def _pad_batch_arrays(arrays, n: int, size: int):
-    """Zero-pad each array's TRAILING (batch) dim from n to size."""
-    assert size >= n, f"pad target {size} < batch {n}"
-    pad = size - n
-
-    def pz(a):
-        widths = [(0, 0)] * (a.ndim - 1) + [(0, pad)]
-        return np.pad(a, widths)
-
-    return tuple(pz(a) for a in arrays)
-
-
-class PreparedBatch:
-    """Fixed-shape device-ready arrays for one verify batch of size n
-    (pre-padding). Field order matches verify_kernel's signature; the
-    batch axis is trailing on every array."""
-
-    __slots__ = ("n", "a_y", "a_sign", "r_y", "r_sign", "s_bits", "k_bits", "precheck")
-
-    def __init__(self, n, a_y, a_sign, r_y, r_sign, s_bits, k_bits, precheck):
-        self.n = n
-        self.a_y = a_y
-        self.a_sign = a_sign
-        self.r_y = r_y
-        self.r_sign = r_sign
-        self.s_bits = s_bits
-        self.k_bits = k_bits
-        self.precheck = precheck
-
-    def arrays(self):
-        return (
-            self.a_y,
-            self.a_sign,
-            self.r_y,
-            self.r_sign,
-            self.s_bits,
-            self.k_bits,
-            self.precheck,
-        )
-
-    def padded(self, size: int) -> "PreparedBatch":
-        """Zero-pad every array's batch dim up to `size`. Padding rows get
-        precheck=False, so their (garbage) device verdicts are masked out."""
-        if size == self.n:
-            return self
-        return PreparedBatch(self.n, *_pad_batch_arrays(self.arrays(), self.n, size))
-
-
-def prepare_batch(items: Sequence[BatchItem]) -> PreparedBatch:
-    """Wire bytes -> fixed-shape numpy arrays + host precheck mask.
-
-    Malformed items (wrong lengths) stay in the batch as dummy rows with
-    precheck=False — keeping shapes static is cheaper than compacting.
-    """
-    pub, r_raw, s_raw, msgs, ok = _split_items(items)
-    k_le = native.challenge_batch(r_raw, pub, msgs)
-
-    # host-detectable rejects: non-canonical S, non-canonical y encodings
-    ok &= ~_ge_l_np(s_raw)
-    ok &= ~_ge_p_np(pub)
-    ok &= ~_ge_p_np(r_raw)
-
-    return PreparedBatch(
-        len(items),
-        fe.bytes32_to_limbs_major_np(pub),
-        fe.sign_bits_np(pub),
-        fe.bytes32_to_limbs_major_np(r_raw),
-        fe.sign_bits_np(r_raw),
-        np.ascontiguousarray(_bits_msb_first_np(s_raw).T),
-        np.ascontiguousarray(_bits_msb_first_np(k_le).T),
-        ok,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Device kernel (ladder mode — self-contained, no key cache)
-# ---------------------------------------------------------------------------
-
-
-def verify_kernel(a_y, a_sign, r_y, r_sign, s_bits, k_bits, precheck):
-    """The jittable batched verify: limb/bit-major arrays in, (B,) bool out.
-
-    Every row runs the identical fixed ladder; invalid decompressions
-    produce garbage points whose verdicts are ANDed away — no branches.
-    """
-    a_pt, ok_a = ed.decompress(a_y, a_sign)
-    r_pt, ok_r = ed.decompress(r_y, r_sign)
-    acc = ed.double_scalar_mul_base(s_bits, k_bits, ed.point_neg(a_pt))
-    # acc == R, projectively (R has Z = 1): X*1 == x_R * Z, Y*1 == y_R * Z
-    x, y, z = acc[0], acc[1], acc[2]
-    x_r, y_r = r_pt[0], r_pt[1]
-    eq = fe.eq(x, fe.mul(x_r, z)) & fe.eq(y, fe.mul(y_r, z))
-    return eq & ok_a & ok_r & precheck
-
-
 def _bucket_size(n: int) -> int:
     for b in BUCKETS:
         if n <= b:
@@ -218,41 +113,17 @@ def _bucket_size(n: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Comb-path host prep: committee pubkey table bank + per-batch scalars
+# Host prep: committee pubkey table bank + per-batch wire staging
 # ---------------------------------------------------------------------------
 
 
-class CombBatch:
-    """Device-ready arrays for the comb kernels (pre-padding); batch axis
-    trailing on every array."""
-
-    __slots__ = ("n", "s_nib", "k_nib", "a_idx", "r_y", "r_sign", "precheck")
-
-    def __init__(self, n, s_nib, k_nib, a_idx, r_y, r_sign, precheck):
-        self.n = n
-        self.s_nib = s_nib
-        self.k_nib = k_nib
-        self.a_idx = a_idx
-        self.r_y = r_y
-        self.r_sign = r_sign
-        self.precheck = precheck
-
-    def arrays(self):
-        return (self.s_nib, self.k_nib, self.a_idx, self.r_y, self.r_sign, self.precheck)
-
-    def padded(self, size: int) -> "CombBatch":
-        if size == self.n:
-            return self
-        return CombBatch(self.n, *_pad_batch_arrays(self.arrays(), self.n, size))
-
-
 class KeyBank:
-    """Cache of per-pubkey comb tables (the committee's key set).
+    """Cache of per-pubkey fused comb tables (the committee's key set).
 
     PBFT pubkeys are few and endlessly reused, so each is decompressed and
-    expanded into packed Niels rows once on the host (exact bigints) and
-    kept on device. The bank's capacity grows in powers of two so kernel
-    shapes (and thus compiles) change only on committee growth.
+    expanded into Niels rows once on the host (exact bigints) and kept on
+    device. The bank's capacity grows in powers of two so kernel shapes
+    (and thus compiles) change only on committee growth.
 
     `max_keys` bounds the bank: a Byzantine sender must not be able to
     grow device memory and force recompiles by spraying fresh valid curve
@@ -262,48 +133,22 @@ class KeyBank:
 
     UNCACHED = -2
 
-    def __init__(
-        self,
-        initial_capacity: int = 8,
-        max_keys: Optional[int] = None,
-        mode: str = "comb",
-        window: int = 4,
-    ):
-        if mode not in ("comb", "fused"):
-            raise ValueError(f"mode must be comb|fused, got {mode!r}")
-        if window not in (4, 5, 6):
-            raise ValueError(f"window must be 4|5|6, got {window!r}")
-        self._mode = mode
-        self.window = window
-        if mode == "comb":
-            if window != 4:
-                raise ValueError("comb mode is fixed at 4-bit windows")
-            self._builder = comb.comb_table_np
-            self._rows_per_key = comb.NPOS * comb.WINDOW
-            default_max = 1024  # ~260 KB/key
-        else:
-            self._builder = lambda pt: comb.fused_table_np(pt, window)
-            self._rows_per_key = comb.npos_for(window) * (1 << (2 * window))
-            # cap device table memory at ~2 GB whatever the window
-            # (w=4: ~4.2 MB/key -> 512 keys; w=5: ~13.6 MB -> 157;
-            # w=6: ~45 MB -> 46); over-cap keys fall back to the CPU
-            # path. 2 GB was chosen against the v5e-lite chip: an n=256
-            # committee + clients is 264 keys = 1.11 GB at w=4, and the
-            # old 1 GB budget pushed exactly the CLIENT keys (registered
-            # after the replicas, signing every request — the bulk of
-            # the verify load) over the cap (chip_r05.jsonl
-            # consensus_qc256_tpu attempt 1: one 8127-item pile stalled
-            # ~75 s on the scalar fallback, committee committed zero).
-            default_max = max(8, (2 << 30) // (self._rows_per_key * comb.ROW * 4))
+    # 2 GiB of device table memory at 4 MiB a key (comb.ROWS_PER_KEY
+    # rows of 64 int32 words). Chosen against the v5e chip: an n=256 committee +
+    # clients is 264 keys = 1.11 GB, and a 1 GB budget pushed exactly
+    # the CLIENT keys (registered after the replicas, signing every
+    # request — the bulk of the verify load) over the cap, onto the CPU
+    # fallback.
+    MAX_KEYS = 512
+
+    def __init__(self, initial_capacity: int = 8, max_keys: int = MAX_KEYS):
         self._index: Dict[bytes, int] = {}
         self._invalid_cache: set = set()
-        self._max_keys = default_max if max_keys is None else max_keys
+        self._max_keys = max_keys
         # clamp: capacity beyond max_keys would allocate (and upload)
-        # table memory the lookup path refuses to ever use — at w=6 a
-        # 64-slot bank is ~2.9 GB against the ~2 GB budget max_keys
-        # enforces (46 keys)
+        # table memory the lookup path refuses to ever use
         self._cap = max(1, min(initial_capacity, self._max_keys))
-        self._np = np.zeros((self._cap, self._rows_per_key, comb.ROW), np.int32)
+        self._np = np.zeros((self._cap, comb.ROWS_PER_KEY, comb.ROW), np.int32)
         self._dev = None
         self._dirty = True
         # the replica pipeline verifies sweep k+1 in a second worker thread
@@ -323,16 +168,15 @@ class KeyBank:
             if len(pubkey) != 32 or pubkey in self._invalid_cache:
                 return -1
         # table construction runs outside the lock, re-checking on
-        # re-entry (fused mode builds in native C++ at ~11 ms/key — a
-        # cold n=64 bank is ~0.7 s; the pure-Python bigint fallback is
-        # ~0.2 s/key at w=4)
+        # re-entry (native C++ builds ~11 ms/key — a cold n=64 bank is
+        # ~0.7 s; the pure-Python bigint fallback is ~0.2 s/key)
         pt = ref.point_decompress(pubkey)
         if pt is None:
             with self._lock:
                 if len(self._invalid_cache) < 4096:  # bounded negative cache
                     self._invalid_cache.add(pubkey)
             return -1
-        table = self._builder(pt)
+        table = comb.fused_table_np(pt)
         with self._lock:
             idx = self._index.get(pubkey)
             if idx is not None:  # raced: another thread built it first
@@ -379,52 +223,21 @@ class KeyBank:
         return a_idx, hit, fallback
 
     def device_tables(self) -> jnp.ndarray:
-        """Flat (cap * rows_per_key, ROW) packed-row table on device."""
+        """Flat (cap * comb.ROWS_PER_KEY, ROW) table on device."""
         with self._lock:
             if self._dirty or self._dev is None:
                 self._dev = jnp.asarray(
-                    self._np.reshape(self._cap * self._rows_per_key, comb.ROW)
+                    self._np.reshape(self._cap * comb.ROWS_PER_KEY, comb.ROW)
                 )
                 self._dirty = False
             return self._dev
 
 
-def prepare_comb_batch(
-    items: Sequence[BatchItem], bank: KeyBank
-) -> "tuple[CombBatch, List[int]]":
-    """Wire bytes -> comb-kernel arrays, registering pubkeys in `bank`.
-
-    Returns (batch, fallback): `fallback` lists item positions whose
-    pubkey is valid but over the bank's cap — the caller must verify
-    those on the CPU path (their device rows are masked out).
-
-    Vectorized end to end: the only per-item Python is the bank's dict
-    lookup; decoding is one join + frombuffer per field and the challenge
-    scalars come from the native batch hasher.
-    """
-    n = len(items)
-    s_raw, k_raw, r_raw, a_idx, ok, fallback = _decode_and_precheck(items, bank)
-    wbits = getattr(bank, "window", 4)
-    batch = CombBatch(
-        n,
-        comb.windows_major_np(s_raw, wbits),
-        comb.windows_major_np(k_raw, wbits),
-        a_idx,
-        fe.bytes32_to_limbs_major_np(r_raw),
-        fe.sign_bits_np(r_raw),
-        ok,
-    )
-    return batch, fallback
-
-
 class WireBatch:
-    """Raw-bytes staging for the fused WIRE kernel: one packed (n, 96)
-    uint8 array (S ‖ k ‖ R per row) plus key rows and the precheck mask.
-
-    Window extraction, limb decomposition and the sign bit move onto the
-    device (ops/comb.fused_verify_wire_kernel), so this is ~100 bytes on
-    the host->device link per signature instead of ~290, and the host
-    sheds the unpack work."""
+    """Raw-bytes staging for the kernel: one (n, 96) uint8 array
+    (S ‖ k ‖ R per row) plus key rows and the precheck mask. Window
+    extraction, limb decomposition and the sign bit happen on the
+    device (ops/comb.fused_verify_wire_kernel)."""
 
     def __init__(self, n: int, wire: np.ndarray, a_idx: np.ndarray,
                  precheck: np.ndarray):
@@ -450,12 +263,17 @@ class WireBatch:
         )
 
 
-def _decode_and_precheck(items: Sequence[BatchItem], bank: KeyBank):
-    """Shared prologue of both staging paths: wire-byte split, bank
-    lookup, native challenge scalars, and the canonicality reject
-    policy (S >= L malleability, non-canonical R.y). Single-sourced so
-    the comb and wire device paths can never diverge in what they
-    reject. -> (s_raw, k_raw, r_raw, a_idx, ok, fallback)."""
+def prepare_wire_batch(
+    items: Sequence[BatchItem], bank: KeyBank
+) -> "tuple[WireBatch, List[int]]":
+    """Wire bytes -> WireBatch, registering pubkeys in `bank`.
+
+    Returns (batch, fallback): `fallback` lists item positions whose
+    pubkey is valid but over the bank's cap — the caller must verify
+    those on the CPU path (their device rows are masked out). Host work
+    is only the byte joins, the bank's dict lookup, the native challenge
+    hash and the canonicality reject policy (S >= L malleability,
+    non-canonical R.y) — no window/limb unpacking."""
     pub, r_raw, s_raw, msgs, ok = _split_items(items)
     a_idx, hit, fallback = bank.lookup_many(items)
     ok &= hit
@@ -464,23 +282,9 @@ def _decode_and_precheck(items: Sequence[BatchItem], bank: KeyBank):
 
     ok &= ~_ge_l_np(s_raw)
     ok &= ~_ge_p_np(r_raw)
-    return s_raw, k_raw, r_raw, a_idx, ok, fallback
-
-
-def prepare_wire_batch(
-    items: Sequence[BatchItem], bank: KeyBank
-) -> "tuple[WireBatch, List[int]]":
-    """Wire bytes -> WireBatch for the fused wire kernel (same contract
-    as prepare_comb_batch: returns (batch, fallback positions)). Host
-    work is only the byte joins, the bank lookup, the native challenge
-    hash and the canonicality prechecks — no window/limb unpacking."""
-    n = len(items)
-    s_raw, k_raw, r_raw, a_idx, ok, fallback = _decode_and_precheck(items, bank)
     wire = np.concatenate([s_raw, k_raw, r_raw], axis=1)  # (n, 96) uint8
-    return WireBatch(n, wire, a_idx.astype(np.int32), ok), fallback
+    return WireBatch(len(items), wire, a_idx.astype(np.int32), ok), fallback
 
-
-_JIT_CACHE: Dict[str, object] = {}
 
 # One device pass at a time, process-wide. The replica runtime calls
 # verify_batch from worker threads (asyncio.to_thread) so the event loop
@@ -515,42 +319,20 @@ class _CompileWatch:
         jax.monitoring.unregister_event_listener(self._on_event)
 
 
-def _shared_jit(mode: str):
-    """One jitted callable per mode, shared by every unmeshed TpuVerifier.
-
-    A per-instance `jax.jit` wrapper would give each verifier its own
-    compile cache — an N-replica committee would then compile the same
-    kernel N times per bucket size (minutes of wasted wall clock, and a
-    practical deadlock on single-core CI hosts)."""
-    fn = _JIT_CACHE.get(mode)
-    if fn is None:
-        if mode.startswith("wire"):
-            window = 1 << int(mode[4:] or "4")  # "wire" / "wire5" / "wire6"
-            kernel = functools.partial(
-                comb.fused_verify_wire_kernel, window=window
-            )
-        elif mode.startswith("fused"):
-            window = 1 << int(mode[5:] or "4")  # "fused" / "fused5" / "fused6"
-            kernel = functools.partial(comb.fused_verify_kernel, window=window)
-        else:
-            kernel = {
-                "comb": comb.comb_verify_kernel,
-                "ladder": verify_kernel,
-            }[mode]
-        fn = jax.jit(kernel)
-        _JIT_CACHE[mode] = fn
-    return fn
+# The one jitted kernel, shared by every unmeshed TpuVerifier. A
+# per-instance `jax.jit` wrapper would give each verifier its own compile
+# cache — an N-replica committee would then compile the same kernel N
+# times per bucket size (minutes of wasted wall clock, and a practical
+# deadlock on single-core CI hosts).
+_SHARED_JIT = jax.jit(comb.fused_verify_wire_kernel)
 
 
 class TpuVerifier:
     """The `tpu` backend behind the crypto.Verifier seam.
 
-    Default mode is the fused comb engine (ops/comb.py): cached per-pubkey
+    One kernel, the fused comb (ops/comb.py): cached per-pubkey
     dual-scalar tables, zero doublings, no on-device decompression, one
-    madd per nibble position, batch-amortized inversion. `mode="comb"`
-    halves table memory for twice the madds; `mode="ladder"` selects the
-    self-contained Straus ladder (no key cache — useful when pubkeys are
-    unbounded).
+    madd per nibble position, batch-amortized inversion.
 
     Pads drained batches to bucketed sizes, runs one jitted device pass per
     chunk, and returns the per-item bitmap. Pass a `jax.sharding.Mesh` via
@@ -560,20 +342,17 @@ class TpuVerifier:
 
     name = "tpu"
 
+    # what the device ledger's rows and the harnesses' warm lines call
+    # the kernel and its scalar window
+    _mode = "fused"
+    _window = comb.WBITS
+
     def __init__(
         self,
         mesh: Optional[jax.sharding.Mesh] = None,
-        mode: str = "fused",
-        window: int = 4,
         initial_keys: Optional[int] = None,
     ):
-        if mode not in ("comb", "fused", "ladder"):
-            raise ValueError(f"mode must be comb|fused|ladder, got {mode!r}")
-        if window != 4 and mode != "fused":
-            raise ValueError("window is a fused-mode knob")
         self._mesh = mesh
-        self._mode = mode
-        self._window = window
         # initial_keys sizes the bank for the EXPECTED key population
         # (committee + clients). This is not an optimization nicety: the
         # jit signature includes the table shape, which is a function of
@@ -587,74 +366,49 @@ class TpuVerifier:
         cap = 8
         if initial_keys is not None:
             cap = 1 << max(3, int(initial_keys - 1).bit_length())
-        self._bank = (
-            KeyBank(initial_capacity=cap, mode=mode, window=window)
-            if mode in ("comb", "fused")
-            else None
-        )
+        self._bank = KeyBank(initial_capacity=cap)
         self._cpu_fb = None  # lazy batched native verifier (over-cap keys)
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            # shard_map, not a GSPMD-sharded jit: each device runs the
+            # kernel on its LOCAL batch shard, so the Pallas Mosaic
+            # accumulator needs no GSPMD partitioning rule and stays
+            # active on TPU meshes (accum resolves per backend: Pallas
+            # on TPU, XLA fori_loop on the CPU mesh). Per-shard batches
+            # stay powers of two (bucket sizes / power-of-two mesh),
+            # which the kernel's batch inversion requires.
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as PS
 
             axis = mesh.axis_names[0]
-            vec = NamedSharding(mesh, P(axis))  # (B,)
-            mat = NamedSharding(mesh, P(None, axis))  # (limb/pos, B)
-            repl = NamedSharding(mesh, P())
-            if mode == "comb":
-                self._fn = jax.jit(
-                    comb.comb_verify_kernel,
-                    in_shardings=(mat, mat, vec, repl, repl, mat, vec, vec),
-                    out_shardings=vec,
+            # args are (wire (B,96), a_idx (B,), f_table (replicated),
+            # precheck (B,)) — batch axis LEADS the wire array, so
+            # shards split rows.
+            #
+            # check_vma=False: under jax 0.9.0's varying-axes check a
+            # pallas_call fails to trace here — compiled, because its
+            # out_shape carries no vma ("vma on jax.ShapeDtypeStruct
+            # must not be None"); interpreted (how tier-1 runs this
+            # path), inside the interpreter's own grid scan even with
+            # the vma given ("Scan carry input and output got
+            # mismatched varying manual axes"). The body has no
+            # collectives and every output is per-shard, so the
+            # check has nothing to protect.
+            self._fn = jax.jit(
+                shard_map(
+                    comb.fused_verify_wire_kernel,
+                    mesh=mesh,
+                    in_specs=(
+                        PS(axis, None), PS(axis), PS(None, None),
+                        PS(axis),
+                    ),
+                    out_specs=PS(axis),
+                    check_vma=False,
                 )
-            elif mode == "fused":
-                # shard_map, not a GSPMD-sharded jit: each device runs
-                # the kernel on its LOCAL batch shard, so the Pallas
-                # Mosaic accumulator needs no GSPMD partitioning rule
-                # and stays active on TPU meshes (accum resolves per
-                # backend: Pallas on TPU, XLA fori_loop on the CPU mesh).
-                # Per-shard batches stay powers of two (bucket sizes /
-                # power-of-two mesh), which the kernel's batch inversion
-                # requires.
-                from jax import shard_map
-                from jax.sharding import PartitionSpec as PS
-
-                # wire kernel: args are (wire (B,96), a_idx (B,),
-                # f_table (replicated), precheck (B,)) — batch axis
-                # LEADS the wire array, so shards split rows.
-                #
-                # check_vma=False: under jax 0.9.0's varying-axes check a
-                # pallas_call fails to trace here — compiled, because its
-                # out_shape carries no vma ("vma on jax.ShapeDtypeStruct
-                # must not be None"); interpreted (how tier-1 runs this
-                # path), inside the interpreter's own grid scan even with
-                # the vma given ("Scan carry input and output got
-                # mismatched varying manual axes"). The body has no
-                # collectives and every output is per-shard, so the
-                # check has nothing to protect.
-                self._fn = jax.jit(
-                    shard_map(
-                        functools.partial(
-                            comb.fused_verify_wire_kernel, window=1 << window
-                        ),
-                        mesh=mesh,
-                        in_specs=(
-                            PS(axis, None), PS(axis), PS(None, None),
-                            PS(axis),
-                        ),
-                        out_specs=PS(axis),
-                        check_vma=False,
-                    )
-                )
-            else:
-                self._fn = jax.jit(
-                    verify_kernel,
-                    in_shardings=(mat, vec, mat, vec, mat, mat, vec),
-                    out_shardings=vec,
-                )
+            )
             self._align = int(np.prod(mesh.devices.shape))
             if self._align & (self._align - 1):
-                # batches pad to power-of-two BUCKETS (and the comb
-                # kernel's batch inversion needs a power of two); a
+                # batches pad to power-of-two BUCKETS (and the kernel's
+                # batch inversion needs a power of two); a
                 # non-power-of-two mesh cannot divide them evenly and the
                 # sharded jit would fail at runtime instead of here
                 raise ValueError(
@@ -662,11 +416,7 @@ class TpuVerifier:
                     f"{self._align} devices"
                 )
         else:
-            if mode == "fused":  # fused staging is the wire path
-                key = "wire" if window == 4 else f"wire{window}"
-            else:
-                key = mode
-            self._fn = _shared_jit(key)
+            self._fn = _SHARED_JIT
             self._align = 1
         # Device-side accounting, owned by the verifier: seconds are
         # measured INSIDE the device lock by the holder, so they are
@@ -728,13 +478,13 @@ class TpuVerifier:
         population exceeds the bank budget — over-cap keys fall back to
         the per-batch CPU path forever, which is safe but silently
         forfeits the device for those signers."""
-        if self._bank is not None and len(pubkeys) > self._bank._max_keys:
+        if len(pubkeys) > self._bank._max_keys:
             import logging
 
             logging.warning(
-                "TpuVerifier bank clamped: %d published keys > max_keys=%d "
-                "(window=%d); over-cap keys verify on the CPU fallback path",
-                len(pubkeys), self._bank._max_keys, self._window,
+                "TpuVerifier bank clamped: %d published keys > max_keys=%d; "
+                "over-cap keys verify on the CPU fallback path",
+                len(pubkeys), self._bank._max_keys,
             )
         top = _bucket_size(max(1, min(max_sweep, BUCKETS[-1])))
         self.warm(pubkeys=pubkeys, buckets=[b for b in BUCKETS if b <= top])
@@ -753,12 +503,11 @@ class TpuVerifier:
         enrolled clients — a PBFT deployment publishes these up front),
         then run one throwaway device pass per batch bucket at the
         resulting table shape. Because the jitted kernels are shared
-        process-wide (_shared_jit), warming ONE verifier warms every
+        process-wide (_SHARED_JIT), warming ONE verifier warms every
         replica in a simulated committee — provided they were built with
         the same initial_keys, so their table shapes match."""
-        if self._bank is not None:
-            for pk in pubkeys:
-                self._bank.lookup(pk)
+        for pk in pubkeys:
+            self._bank.lookup(pk)
         # wrong-length pubkey: _split_items masks the row and the bank
         # rejects it without registering — an all-zero 32-byte key would
         # decompress to a valid (order-4) point and permanently occupy a
@@ -783,8 +532,7 @@ class TpuVerifier:
         counters are observability, not control flow. Returns whether
         the signature is FRESH (this dispatch traces and compiles) —
         the device ledger's compile-vs-cache column."""
-        cap = self._bank._cap if self._bank is not None else 0
-        sig = (self._mode, self._window, size, cap)
+        sig = (self._mode, self._window, size, self._bank._cap)
         self.bucket_hits[size] = self.bucket_hits.get(size, 0) + 1
         fresh = sig not in self.shape_signatures
         if fresh:
@@ -818,10 +566,8 @@ class TpuVerifier:
         `size` and the bank's current table shape, as text. chip_smoke.py
         reads it to show the Pallas accumulator went through Mosaic (a
         ``tpu_custom_call``) and was not interpreted."""
-        if self._mode != "fused":
-            raise ValueError("lowered_text covers the fused wire kernel only")
         struct = jax.ShapeDtypeStruct
-        rows = self._bank._cap * self._bank._rows_per_key
+        rows = self._bank._cap * comb.ROWS_PER_KEY
         return self._fn.lower(
             struct((size, 96), jnp.uint8),
             struct((size,), jnp.int32),
@@ -877,27 +623,13 @@ class TpuVerifier:
         # planes show the prep beside the device's modules
         with spans.annotation(spans.VERIFY_HOST_PREP):
             size = _bucket_size(max(len(items), self._align))
-            fallback: List[int] = []
-            if self._mode in ("comb", "fused"):
-                if self._mode == "fused":
-                    prep, fallback = prepare_wire_batch(items, self._bank)
-                    prep = prep.padded(size)
-                    wire, a_idx, precheck = prep.arrays()
-                    tables = self._bank.device_tables()
-                    args = (wire, a_idx, tables, precheck)
-                else:
-                    prep, fallback = prepare_comb_batch(items, self._bank)
-                    prep = prep.padded(size)
-                    s_nib, k_nib, a_idx, r_y, r_sign, precheck = prep.arrays()
-                    tables = self._bank.device_tables()
-                    b_table = comb.base_table_device()
-                    args = (s_nib, k_nib, a_idx, tables, b_table, r_y, r_sign, precheck)
-            else:
-                prep = prepare_batch(items).padded(size)
-                args = prep.arrays()
+            prep, fallback = prepare_wire_batch(items, self._bank)
+            prep = prep.padded(size)
+            wire, a_idx, precheck = prep.arrays()
+            args = (wire, a_idx, self._bank.device_tables(), precheck)
             compile_fresh = self._record_shape(size)
-        # host-side prep (nibble decomposition, padding, array builds)
-        # is CPU work on the dispatcher's thread — if it rivals the
+        # host-side prep (byte joins, challenge hashes, padding) is CPU
+        # work on the dispatcher's thread — if it rivals the
         # device RTT the pipeline is host-bound, and only a span can say
         # so (spans.py; the r5 "where do the other 96% go" question)
         prep_s = time.perf_counter() - t_prep
@@ -956,7 +688,7 @@ class TpuVerifier:
                 # not a scalar loop — at n=256 the over-cap keys were
                 # the clients', i.e. most of the pile, and the
                 # pure-Python per-item path turned one coalesced batch
-                # into a ~75 s stall (chip_r05.jsonl qc256 attempt 1)
+                # into a ~75 s stall (round-5 chip record, qc256 attempt 1)
                 fb_out = self._cpu_fb.verify_batch(
                     [items[i] for i in fallback]
                 )

@@ -1,11 +1,8 @@
 """Device-plane event ledger (ISSUE 14 tentpole).
 
-The verify plane's cost claim — bandwidth-bound at 777k verifies/s/chip
-with a route to ~1.05M (a round-5 builder memo, 2026-07-31, not
-reproduced since) — was produced by hand, once. Every
-other plane got continuous instrumentation (spans in PR 4, wire
+Every other plane got continuous instrumentation (spans in PR 4, wire
 accounting in PR 9); the device plane, where per-role crypto cost
-dominates, stayed a markdown memo. This module is the continuously-
+dominates, had a hand-made memo. This module is the continuously-
 measured replacement: every jit dispatch on the verify path records one
 event — (lane, mode, window, bucket, batch size, pad waste, queue wait,
 host prep, device RTT, compile-vs-cache, host<->device bytes) — into a
@@ -13,13 +10,12 @@ bounded lock-free ring, and the aggregates ride
 ``VerifyService.snapshot()["device"]`` -> telemetry -> every flight
 frame and bench record. ``tools/verify_observatory.py`` joins the
 ledger with the span layer and the static cost model
-(``crypto/costmodel.py``) into a measured roofline verdict per run.
+(``crypto/costmodel.py``) into a per-run decomposition.
 
-Lanes share one schema so the 8-mesh shard-out inherits it day one:
+Lanes share one schema:
 
   ``ed25519``  TpuVerifier jit dispatches (the coalesced verify path)
   ``bls``      QcVerifyLane RLC multi-pairing batches
-  ``shard``    parallel/sharded_verify per-device SPMD step events
 
 Discipline (PBL004): every public entry point here is audited
 never-raise — recording wraps its body in a broad except because a
@@ -45,7 +41,6 @@ log = logging.getLogger(__name__)
 
 LANE_ED25519 = "ed25519"
 LANE_BLS = "bls"
-LANE_SHARD = "shard"
 
 
 # the raw (summable) lane counters; consumers that merge blocks across
@@ -270,9 +265,8 @@ class DeviceLedger:
                     continue
                 ln, m, w, b = skey
                 # lane-qualified keys: "ed25519:fused/w4/b8192" — the
-                # lane prefix keeps e.g. an ed25519 ladder shape and
-                # the shard wrapper's identical (mode, window, bucket)
-                # from overwriting each other in the export
+                # lane prefix keeps two lanes' identical (mode, window,
+                # bucket) from overwriting each other in the export
                 shapes[f"{ln}:{m}/w{w}/b{b}"] = dict(row)
             top_src = lanes.get(LANE_ED25519)
             if top_src is None and lanes:

@@ -1,16 +1,13 @@
-"""Comb-table Ed25519 verification engine — the fast TPU path.
+"""Fused-comb Ed25519 verification — the one verify kernel.
 
-The generic ladder (ops/edwards.py) spends its time on 256 doublings, 256
-unified adds, and two on-device square-root chains (point decompression of
-A and R). PBFT gives us structure the TPU can exploit:
+PBFT gives the verifier structure the TPU can exploit:
 
 - **Pubkeys are a small committee set**, reused across every vote. So the
   host decompresses each pubkey once (exact bigint math) and uploads a
-  per-key *comb table*: T_A[i][w] = (w * 16^i) A for i in 0..63, w in
-  0..15, in Niels form (y+x, y−x, 2dxy). [k]A is then 64 table lookups +
-  64 mixed adds — **zero doublings**.
-- **The base point is fixed**, so [S]B uses a constant comb table the same
-  way.
+  per-key *fused dual-scalar table*: T[i][ws][wk] = (ws * 16^i) B +
+  (wk * 16^i) (−A) for i in 0..63 and ws, wk in 0..15, in Niels form
+  (y+x, y−x, 2dxy). [S]B + [k](−A) is then 64 table lookups + 64 mixed
+  adds — **zero doublings**.
 - **R never needs decompressing**: instead of comparing points in
   extended coordinates ([S]B − [k]A == R), compute P = [S]B + [k](−A),
   normalize to affine with ONE inversion amortized over the whole batch
@@ -21,18 +18,17 @@ A and R). PBFT gives us structure the TPU can exploit:
 
 TPU-native data layout (what makes this fast, not just op-lean):
 
-- Tables live in HBM as PACKED ROWS: one (64,) int32 row per Niels entry
+- Tables live in HBM as rows: one (64,) int32 row per Niels entry
   = [y+x limbs | y−x limbs | 2dxy limbs | pad] — so fetching an entry is
   one dense 256-byte row read. All 64 positions' rows for the whole batch
-  are fetched in ONE flat `jnp.take` (measured ~230M rows/s on a v5e,
-  vs ~11M rows/s for 64 per-position gathers in a loop).
+  are fetched in ONE flat `jnp.take`, not 64 per-position gathers in a
+  loop.
 - Compute arrays are limb-major / batch-minor ((17, B), see
   ops/field25519.py): the batch fills the 128-wide vector lanes, making
   the 64-iteration madd loop VPU-dense.
 
-Per-signature device cost (fused mode): 64 mixed adds (7 field muls each)
-+ ~3 muls of batch inversion ≈ 450 field muls, vs ≈ 4300 + two 250-square
-chains for the ladder.
+Per-signature device cost: 64 mixed adds (7 field muls each) + ~3 muls
+of batch inversion ≈ 450 field muls.
 
 Everything stays constant-shape: 64 nibble positions whatever the scalar,
 identity entries for zero nibbles, verdicts masked by host prechecks.
@@ -49,64 +45,28 @@ from jax import lax
 from . import field25519 as fe
 from ..crypto import ed25519_cpu as ref
 
-NPOS = 64  # 4-bit comb positions covering 256-bit scalars
-WINDOW = 16
-FWINDOW = WINDOW * WINDOW  # fused (s_nibble, k_nibble) window: 256 entries
-ROW_DENSE = 64  # Niels row: 3*17 int32 limbs + 13 pad to a 256B row
-ROW_PACKED = 32  # two 15-bit limbs per int32: 3*9 words + 5 pad, 128B
-ROW = ROW_DENSE  # active row width — module global, see use_row_packing
-PACKED = False
-
-
-def use_row_packing(on: bool) -> None:
-    """Select the table-row layout BEFORE any table is built or kernel
-    jitted (jit traces and KeyBank allocations capture ROW). Packed rows
-    halve the madd loop's gather bandwidth — the kernel's dominant HBM
-    stream — for two extra shift/mask ops per element at unpack; the
-    A/B lives in the chip ledger as verify_w5_pack. Layouts cannot mix:
-    tables built in one mode are garbage to a kernel traced in the
-    other, which is why this is a process-wide switch and not a
-    per-call flag."""
-    global ROW, PACKED
-    PACKED = bool(on)
-    ROW = ROW_PACKED if on else ROW_DENSE
-
-
-def npos_for(wbits: int) -> int:
-    """Positions covering a 256-bit scalar with wbits-bit windows."""
-    return -(-256 // wbits)
+WBITS = 4  # scalar window width
+NPOS = 256 // WBITS  # comb positions covering 256-bit scalars: 64
+WINDOW = 1 << WBITS  # entries per scalar per position: 16
+ROWS_PER_KEY = NPOS * WINDOW * WINDOW  # one key's table: 16,384 rows
+ROW = 64  # Niels row: 3*17 int32 limbs + 13 pad to a 256B row
 
 # ---------------------------------------------------------------------------
-# Host-side table construction (exact Python bigints -> packed limb rows)
+# Host-side table construction (exact Python bigints -> limb rows)
 # ---------------------------------------------------------------------------
 
 
 def _pack_rows_np(vals: np.ndarray) -> np.ndarray:
-    """(n, 3, 17) int32 Niels limbs -> (n, ROW) packed rows.
-
-    Dense mode (ROW=64): one int32 per limb, 13 pad words — a 256-byte
-    row of which only 204 bytes are payload. Packed mode (ROW=32, see
-    `use_row_packing`): limbs are 15-bit nonnegative values, so pairs
-    share an int32 (lo | hi << 15) — 9 words per element (the 17th limb
-    rides alone), 27 + 5 pad = a 128-byte row. The madd loop's gather is
-    the kernel's dominant HBM stream (r4 profile: staging copies +
-    gather ~45% of the pass with the madds), so halving row bytes buys
-    bandwidth at the cost of two shift/mask ops per element at unpack."""
+    """(n, 3, 17) int32 Niels limbs -> (n, ROW) rows: one int32 per
+    limb, 13 pad words — a 256-byte row of which 204 bytes are payload."""
     n = vals.shape[0]
     out = np.zeros((n, ROW), dtype=np.int32)
-    if PACKED:
-        v = vals.reshape(n, 3, fe.NLIMB)
-        packed = np.zeros((n, 3, 9), dtype=np.int32)
-        packed[:, :, :8] = v[:, :, 0:16:2] | (v[:, :, 1:16:2] << 15)
-        packed[:, :, 8] = v[:, :, 16]
-        out[:, : 3 * 9] = packed.reshape(n, 27)
-    else:
-        out[:, : 3 * fe.NLIMB] = vals.reshape(n, 3 * fe.NLIMB)
+    out[:, : 3 * fe.NLIMB] = vals.reshape(n, 3 * fe.NLIMB)
     return out
 
 
 def _batch_affine_niels_np(points) -> np.ndarray:
-    """Extended bigint points -> (n, ROW) packed Niels rows, with ONE
+    """Extended bigint points -> (n, ROW) Niels rows, with ONE
     modular inversion for the whole list (host Montgomery batch trick) and
     vectorized int->limb conversion. comb_table-scale builds do tens of
     thousands of entries per key; per-entry Fermat inversions would cost
@@ -134,50 +94,32 @@ def _batch_affine_niels_np(points) -> np.ndarray:
     return _pack_rows_np(limbs)
 
 
-def comb_table_np(point: ref.Point) -> np.ndarray:
-    """(NPOS * WINDOW, ROW) packed rows: row[i*W + w] = (w * 16^i) * point."""
-    pts = []
-    base = point
-    for i in range(NPOS):
-        acc = ref.IDENTITY
-        for w in range(WINDOW):
-            pts.append(acc)
-            acc = ref.point_add(acc, base)
-        for _ in range(4):  # base <- 16 * base
-            base = ref.point_double(base)
-    return _batch_affine_niels_np(pts)
-
-
 def _point_neg(p: ref.Point) -> ref.Point:
     x, y, z, t = p
     return ((-x) % ref.P, y, z, (-t) % ref.P)
 
 
-def fused_table_np(point: ref.Point, wbits: int = 4) -> np.ndarray:
-    """(npos * 4^wbits, ROW) packed rows for wbits-bit windows:
-    row[i*FW + ws*2^w + wk] = (ws * 2^(w*i)) B + (wk * 2^(w*i)) (−A),
-    FW = 4^wbits, npos = ceil(256/wbits).
+def fused_table_np(point: ref.Point) -> np.ndarray:
+    """(ROWS_PER_KEY, ROW) rows of one key's fused table:
+    row[i*256 + ws*16 + wk] = (ws * 16^i) B + (wk * 16^i) (−A).
 
     One row fetch + ONE mixed add per window position evaluates
-    [S]B + [k](−A) — half the madds of the separate-table comb. Wider
-    windows cut positions (and device madds) at the cost of a bigger
-    per-key table: w=4 -> 64 positions / ~4.2 MB per key, w=5 -> 52 /
-    ~13.6 MB, w=6 -> 43 / ~45 MB. Keys are few (a committee) and
+    [S]B + [k](−A). ~4.2 MB per key; keys are few (a committee) and
     endlessly reused, so the build amortizes; KeyBank caps total memory.
     """
     # Native fast path (native/ed25519.cpp): the same build in C++ group
     # arithmetic, ~80x the Python bigint loop — the difference between a
-    # sub-second and a half-minute cold KeyBank at n=64 (and w=6 tables
-    # are 10x bigger still). Output is affine-Niels field-element BYTES;
-    # the vectorized bytes->limb conversion below is shared with the
-    # Python path, so both produce bit-identical packed rows.
+    # sub-second and a half-minute cold KeyBank at n=64. Output is
+    # affine-Niels field-element BYTES; the vectorized bytes->limb
+    # conversion below is shared with the Python path, so both produce
+    # bit-identical rows.
     from .. import native
 
     x, y = ref.point_to_affine(point)
     a_xy = np.frombuffer(
         x.to_bytes(32, "little") + y.to_bytes(32, "little"), dtype=np.uint8
     )
-    nb = native.ed25519_fused_table(a_xy, wbits)
+    nb = native.ed25519_fused_table(a_xy, WBITS)
     if nb is not None:
         n = nb.shape[0]
         limbs = fe.bytes32_to_limbs_np(
@@ -185,67 +127,21 @@ def fused_table_np(point: ref.Point, wbits: int = 4) -> np.ndarray:
         ).reshape(n, 3, fe.NLIMB)
         return _pack_rows_np(limbs)
 
-    window = 1 << wbits
     pts = []
     base_b = ref.B
     base_a = _point_neg(point)
-    for i in range(npos_for(wbits)):
+    for i in range(NPOS):
         row_b = ref.IDENTITY
-        for ws in range(window):
+        for ws in range(WINDOW):
             acc = row_b
-            for wk in range(window):
+            for wk in range(WINDOW):
                 pts.append(acc)
                 acc = ref.point_add(acc, base_a)
             row_b = ref.point_add(row_b, base_b)
-        for _ in range(wbits):  # bases <- 2^wbits * bases
+        for _ in range(WBITS):  # bases <- 16 * bases
             base_b = ref.point_double(base_b)
             base_a = ref.point_double(base_a)
     return _batch_affine_niels_np(pts)
-
-
-_BASE_TABLE: Optional[np.ndarray] = None
-_BASE_TABLE_DEV = None
-
-
-def base_table() -> np.ndarray:
-    """Constant comb table of the Ed25519 base point (built once)."""
-    global _BASE_TABLE
-    if _BASE_TABLE is None:
-        _BASE_TABLE = comb_table_np(ref.B)
-    return _BASE_TABLE
-
-
-def base_table_device() -> jnp.ndarray:
-    """Device-resident copy of base_table() (uploaded once — the verify
-    hot path must not re-transfer 256 KB per batch)."""
-    global _BASE_TABLE_DEV
-    if _BASE_TABLE_DEV is None:
-        _BASE_TABLE_DEV = jnp.asarray(base_table())
-    return _BASE_TABLE_DEV
-
-
-def nibbles_major_np(le_bytes: np.ndarray) -> np.ndarray:
-    """(n, 32) uint8 little-endian scalar -> (NPOS, n) int32 nibbles,
-    least significant first (position i carries weight 16^i — matching
-    comb_table_np, order-free since the comb has no doublings).
-    POSITION-MAJOR — the device layout, written directly (interleaved row
-    assignment) so the hot prep path never transposes."""
-    cols = le_bytes.T  # (32, n) strided view
-    out = np.empty((NPOS, le_bytes.shape[0]), dtype=np.int32)
-    out[0::2] = cols & 0x0F
-    out[1::2] = cols >> 4
-    return out
-
-
-def windows_major_np(le_bytes: np.ndarray, wbits: int) -> np.ndarray:
-    """(n, 32) uint8 little-endian scalar -> (npos, n) int32 wbits-bit
-    windows, least significant first, position-major (the shared
-    fe.extract_windows_np decoder; w=4 keeps the cheaper nibble
-    interleave). The top position's window is naturally truncated to the
-    scalar's top bits."""
-    if wbits == 4:
-        return nibbles_major_np(le_bytes)
-    return fe.extract_windows_np(le_bytes, wbits, npos_for(wbits))
 
 
 # ---------------------------------------------------------------------------
@@ -253,50 +149,14 @@ def windows_major_np(le_bytes: np.ndarray, wbits: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _unpack_element(words: jnp.ndarray) -> jnp.ndarray:
-    """(9, ...) packed words -> (17, ...) limbs: lo | hi << 15 pairs for
-    limbs 0..15, the 17th limb rides alone in word 8."""
-    lo = words[:8] & 0x7FFF
-    hi = (words[:8] >> 15) & 0x7FFF
-    pairs = jnp.stack([lo, hi], axis=1).reshape((16,) + words.shape[1:])
-    return jnp.concatenate([pairs, words[8:9]], axis=0)
-
-
 def _row_niels(rows: jnp.ndarray):
-    """Table rows (ROW, ...) -> (ypx, ymx, xy2d) limb arrays (17, ...).
-    Layout (dense int32-per-limb vs 15-bit pair-packed) is captured at
-    trace time from the module switch (use_row_packing)."""
-    if PACKED:
-        return (
-            _unpack_element(rows[0:9]),
-            _unpack_element(rows[9:18]),
-            _unpack_element(rows[18:27]),
-        )
+    """Table rows (ROW, ...) -> (ypx, ymx, xy2d) limb arrays (17, ...)."""
     n = fe.NLIMB
     return rows[:n], rows[n : 2 * n], rows[2 * n : 3 * n]
 
 
-def negate_rows(rows: jnp.ndarray) -> jnp.ndarray:
-    """Niels negation on packed rows: swap (y+x, y−x), negate 2dxy.
-    Dense layout only — the separate-table comb path that needs it never
-    runs packed (use_row_packing gates the fused path's tables)."""
-    if PACKED:
-        # unconditional (NOT an assert): under `python -O` a packed
-        # table silently negated with dense-layout arithmetic would
-        # produce wrong group elements — and wrong verify verdicts —
-        # instead of failing loudly (ADVICE r5)
-        raise RuntimeError(
-            "negate_rows is a dense-layout (comb-mode) helper; "
-            "packed rows (use_row_packing) only feed the fused path"
-        )
-    ypx, ymx, xy2d = _row_niels(rows)
-    return jnp.concatenate(
-        [ymx, ypx, fe.neg(xy2d), rows[3 * fe.NLIMB :]], axis=0
-    )
-
-
 def _madd_tuple(x1, y1, z1, t1, rows):
-    """Mixed add on coordinate tuples: extended (17, ...) x4 + packed
+    """Mixed add on coordinate tuples: extended (17, ...) x4 +
     Niels rows (ROW, ...). ref10-style ge_madd — 7 field muls. Same group
     law as edwards.point_add with Z2 = 1 and the Niels components
     precomputed. Tuple form so the Pallas loop carries register-resident
@@ -314,7 +174,7 @@ def _madd_tuple(x1, y1, z1, t1, rows):
 
 
 def madd(p: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
-    """Mixed add: extended (4, 17, ...) + packed Niels rows (ROW, ...)."""
+    """Mixed add: extended (4, 17, ...) + Niels rows (ROW, ...)."""
     x, y, z, t = _madd_tuple(p[0], p[1], p[2], p[3], rows)
     return jnp.stack([x, y, z, t], axis=0)
 
@@ -340,7 +200,7 @@ def _ident_like(batch_ref: jnp.ndarray) -> jnp.ndarray:
 
 
 def _gather_rows(flat_table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """One flat fetch of every position's packed row, staged position-major.
+    """One flat fetch of every position's row, staged position-major.
 
     flat_table: (M, ROW). idx: (NPOS, B) row indices. -> (NPOS, ROW, B).
     A single big `take` keeps the gather dense (the per-position-in-loop
@@ -352,58 +212,27 @@ def _gather_rows(flat_table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return rows.reshape(npos, b, ROW).transpose(0, 2, 1)
 
 
-def comb_accumulate(
-    s_nibbles: jnp.ndarray,
-    k_nibbles: jnp.ndarray,
-    a_row_base: jnp.ndarray,
-    a_flat: jnp.ndarray,
-    b_flat: jnp.ndarray,
-) -> jnp.ndarray:
-    """[S]B + [k](−A) via separate comb tables: two row fetches + two
-    mixed adds per nibble position (128 madds total).
-
-    s_nibbles, k_nibbles: (NPOS, B) int32. a_row_base: (B,) int32 =
-    key_index * NPOS * WINDOW. a_flat: (n_keys*NPOS*WINDOW, ROW).
-    b_flat: (NPOS*WINDOW, ROW).
-    """
-    pos = jnp.arange(NPOS, dtype=jnp.int32)[:, None]
-    b_rows = _gather_rows(b_flat, pos * WINDOW + s_nibbles)
-    a_rows = _gather_rows(a_flat, a_row_base[None, :] + pos * WINDOW + k_nibbles)
-    acc0 = _ident_like(s_nibbles[0])
-
-    def body(i, acc):
-        acc = madd(acc, b_rows[i])
-        return madd(acc, negate_rows(a_rows[i]))
-
-    return lax.fori_loop(0, NPOS, body, acc0)
-
-
 def fused_accumulate(
     s_windows: jnp.ndarray,
     k_windows: jnp.ndarray,
     row_base: jnp.ndarray,
     f_flat: jnp.ndarray,
-    window: int = WINDOW,
-    accum: Optional[str] = None,
 ) -> jnp.ndarray:
     """[S]B + [k](−A) via the fused dual-scalar table: one row fetch + one
-    mixed add per window position (npos total; 64 for 4-bit windows).
+    mixed add per window position (NPOS = 64 in all).
 
-    s_windows, k_windows: (npos, B) int32. row_base: (B,) int32 =
-    key_index * npos * window^2. f_flat: (n_keys*npos*window^2, ROW).
-    `window` = 2^wbits is static (captured at trace time).
+    s_windows, k_windows: (NPOS, B) int32. row_base: (B,) int32 =
+    key_index * ROWS_PER_KEY. f_flat: (n_keys*ROWS_PER_KEY, ROW).
 
     The madd loop runs either as plain XLA (fori_loop) or as a Pallas
     kernel that keeps the accumulator and every field-mul intermediate in
-    VMEM across all positions (`use_accum_impl`). `accum` overrides the
-    global choice — the GSPMD-sharded mesh path must force "xla" (a
-    Mosaic custom call has no partitioning rule inside a sharded jit).
+    VMEM across all positions; `_resolve_accum_impl` says which.
     """
     npos = s_windows.shape[0]
     pos = jnp.arange(npos, dtype=jnp.int32)[:, None]
-    idx = row_base[None, :] + pos * (window * window) + s_windows * window + k_windows
+    idx = row_base[None, :] + pos * (WINDOW * WINDOW) + s_windows * WINDOW + k_windows
     rows_all = _gather_rows(f_flat, idx)  # (npos, ROW, B)
-    impl = accum or _resolve_accum_impl()
+    impl = _resolve_accum_impl()
     if impl in ("pallas", "pallas_interpret"):
         return _madd_loop_pallas(rows_all, interpret=impl == "pallas_interpret")
     acc0 = _ident_like(s_windows[0])
@@ -430,11 +259,11 @@ PALLAS_TILE = 256  # batch lanes per kernel program (rows block = 4 MiB)
 
 
 def use_accum_impl(name: str) -> None:
-    """Select the fused-accumulate implementation ('auto', 'xla',
-    'pallas' or 'pallas_interpret') BEFORE any kernel is jitted — jit
-    traces capture the choice. 'auto' resolves at trace time: the Pallas
-    kernel on a TPU (builder-recorded 2026-07-31: ~28% faster at batch
-    8k), the XLA fori_loop elsewhere. 'pallas' means the Mosaic-compiled
+    """The test seam over the accumulator ('auto', 'xla', 'pallas' or
+    'pallas_interpret'), to be set BEFORE the kernel is traced — jit
+    traces capture the choice. 'auto' is what the program runs and
+    resolves at trace time from the platform: the Pallas kernel on a
+    TPU, the XLA fori_loop elsewhere. 'pallas' means the Mosaic-compiled
     kernel and is an error where Mosaic cannot run; the interpreter is
     only ever what a test asks for by name ('pallas_interpret'), never
     what a backend silently gets."""
@@ -511,10 +340,9 @@ def _interleave(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 CHAIN_WIDTH = 128  # one full VREG of lanes: the Fermat chain is as cheap
-# on (17, 128) as on (17, 1), so the tree stops here — the levels below
-# ran 64..1-wide on 128-wide vector lanes, pure sequential-dependency
-# waste (the r4 chip profile charged ~23% of the verify pass to this
-# tail for ~1.3% of its field muls).
+# on (17, 128) as on (17, 1), so the tree stops here — levels below it
+# would run 64..1-wide on 128-wide vector lanes, a sequential tail that
+# does ~1.3% of the pass's field muls.
 
 
 def batch_invert(z: jnp.ndarray) -> jnp.ndarray:
@@ -553,71 +381,37 @@ def _encode_and_compare(
 
 
 def fused_verify_kernel(
-    s_windows: jnp.ndarray,  # (npos, B) int32 — S scalar windows
-    k_windows: jnp.ndarray,  # (npos, B) int32 — challenge scalar windows
+    s_windows: jnp.ndarray,  # (NPOS, B) int32 — S scalar windows
+    k_windows: jnp.ndarray,  # (NPOS, B) int32 — challenge scalar windows
     a_index: jnp.ndarray,  # (B,) int32 — key row into the fused table bank
-    f_table: jnp.ndarray,  # (n_keys*npos*window^2, ROW) packed Niels rows
+    f_table: jnp.ndarray,  # (n_keys*ROWS_PER_KEY, ROW) Niels rows
     r_y: jnp.ndarray,  # (17, B) int32 — R's canonical y limbs
     r_sign: jnp.ndarray,  # (B,) int32 — R's x sign bit
     precheck: jnp.ndarray,  # (B,) bool — host-side validity mask
-    window: int = WINDOW,  # static: 2^wbits entries per scalar per position
-    accum: Optional[str] = None,  # static accumulate-impl override
 ) -> jnp.ndarray:
     """Batched verify via the fused comb: one row fetch + one madd per
-    window position (64 at w=4, 52 at w=5, 43 at w=6)."""
-    npos = s_windows.shape[0]
-    p = fused_accumulate(
-        s_windows,
-        k_windows,
-        a_index * (npos * window * window),
-        f_table,
-        window=window,
-        accum=accum,
-    )
+    window position. The body fused_verify_wire_kernel calls once the
+    wire bytes are unpacked."""
+    p = fused_accumulate(s_windows, k_windows, a_index * ROWS_PER_KEY, f_table)
     return _encode_and_compare(p, r_y, r_sign, precheck)
 
 
 def fused_verify_wire_kernel(
     wire: jnp.ndarray,  # (B, 96) uint8 — S (32) ‖ k (32) ‖ R (32) raw bytes
     a_index: jnp.ndarray,  # (B,) int32 — key row into the fused table bank
-    f_table: jnp.ndarray,  # (n_keys*npos*window^2, ROW) packed Niels rows
+    f_table: jnp.ndarray,  # (n_keys*ROWS_PER_KEY, ROW) Niels rows
     precheck: jnp.ndarray,  # (B,) bool — host-side validity mask
-    window: int = WINDOW,
-    accum: Optional[str] = None,
 ) -> jnp.ndarray:
-    """fused_verify_kernel taking RAW wire bytes, one packed (B, 96)
-    uint8 array per batch: scalar-window extraction, R limb decomposition
-    and the sign bit all happen on device (fe.extract_windows_dev).
+    """The verify kernel: RAW wire bytes in, one (B, 96) uint8 array per
+    batch; scalar-window extraction, R limb decomposition and the sign
+    bit all happen on device (fe.extract_windows_dev), then
+    fused_verify_kernel.
 
-    This is the transfer-lean staging path: ~100 bytes/item cross the
-    host->device link instead of ~290 (int32 windows + limbs), and the
-    host sheds the unpack work. XLA fuses the byte shuffling into the
-    kernel prologue — the device rate is unchanged; the end-to-end rate
-    is what improves (it is transfer- and host-bound)."""
-    wbits = window.bit_length() - 1
-    npos = npos_for(wbits)
-    s_w = fe.extract_windows_dev(wire[:, 0:32], wbits, npos)
-    k_w = fe.extract_windows_dev(wire[:, 32:64], wbits, npos)
+    ~100 bytes/item cross the host->device link (int32 windows + limbs
+    would be ~290) and the host does no unpack work; XLA fuses the byte
+    shuffling into the kernel prologue."""
+    s_w = fe.extract_windows_dev(wire[:, 0:32], WBITS, NPOS)
+    k_w = fe.extract_windows_dev(wire[:, 32:64], WBITS, NPOS)
     r_y = fe.extract_windows_dev(wire[:, 64:96], fe.RADIX, fe.NLIMB)
     r_sign = wire[:, 95].astype(jnp.int32) >> 7
-    return fused_verify_kernel(
-        s_w, k_w, a_index, f_table, r_y, r_sign, precheck,
-        window=window, accum=accum,
-    )
-
-
-def comb_verify_kernel(
-    s_nibbles: jnp.ndarray,  # (NPOS, B) int32 — S scalar nibbles
-    k_nibbles: jnp.ndarray,  # (NPOS, B) int32 — challenge scalar nibbles
-    a_index: jnp.ndarray,  # (B,) int32 — key row into the pubkey table bank
-    a_table: jnp.ndarray,  # (n_keys*NPOS*WINDOW, ROW) packed Niels rows
-    b_table: jnp.ndarray,  # (NPOS*WINDOW, ROW) packed rows (base point)
-    r_y: jnp.ndarray,  # (17, B) int32 — R's canonical y limbs
-    r_sign: jnp.ndarray,  # (B,) int32 — R's x sign bit
-    precheck: jnp.ndarray,  # (B,) bool — host-side validity mask
-) -> jnp.ndarray:
-    """Batched verify via combs: [S]B + [k](−A) must encode to R's bytes."""
-    p = comb_accumulate(
-        s_nibbles, k_nibbles, a_index * (NPOS * WINDOW), a_table, b_table
-    )
-    return _encode_and_compare(p, r_y, r_sign, precheck)
+    return fused_verify_kernel(s_w, k_w, a_index, f_table, r_y, r_sign, precheck)

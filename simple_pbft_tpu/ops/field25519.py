@@ -16,8 +16,7 @@ leads and batch axes trail. This is the TPU-native choice: XLA maps the
 minor-most axis to the 128-wide vector lanes, so with batch minor a
 (17, B) element wastes nothing (B is a lane multiple), while the previous
 batch-major (B, 17) form padded 17 -> 128 lanes and made every hot-path
-intermediate ~7.5x larger in HBM. Measured on a v5e chip this layout is
-~2.8x faster for the madd chain that dominates verification.
+intermediate ~7.5x larger in HBM.
 
 All functions are shape-polymorphic over TRAILING batch dimensions and
 pure jnp — jittable, vmappable, shardable. Carry ripples are expressed as
@@ -203,8 +202,7 @@ def mul_padacc(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     (35, ...) accumulator. With the limb axis MAJOR the pads are extent
     changes on the slowest-varying axis — no lane relayout — and all
     elementwise ops fuse in XLA; the batch stays resident in the vector
-    lanes. This is the production hot-path multiply (~3 ns/item/mul for
-    the madd chain on a v5e at batch 8192, ~2.8x the batch-major form).
+    lanes. This is the hot-path multiply.
     """
     nb = a.ndim - 1
     acc = jnp.zeros((2 * NLIMB + 1,) + a.shape[1:], dtype=a.dtype)
@@ -226,9 +224,8 @@ def mul_skew(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     Materializes a (17, 17, ...) product tensor; the antidiagonal sums use
     the skew trick (pad rows to 35 and reshape, so element (i, j) lands in
     column i + j). Compact in HLO (~25 ops/mul vs ~135 for padacc) so the
-    ~300-multiply exponentiation chains always use it to keep compile
-    times bounded; kept selectable for the hot path via `use_mul_impl`
-    for A/B benchmarking.
+    ~300-multiply exponentiation chains use it (`_chain_mul`) to keep
+    compile times bounded.
     """
     prod = a[:, None] * b[None, :]  # (17, 17, ...)
     nb = prod.ndim - 2
@@ -248,23 +245,14 @@ def mul_skew(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return normalize(out)
 
 
-# The production field multiply (see mul_padacc docstring). `use_mul_impl`
-# selects the skew form for A/B benchmarking on real hardware.
+# The hot-path field multiply (see mul_padacc docstring).
 mul = mul_padacc
 
 # The exponentiation chains unroll ~300 sequential multiplies on tiny
 # (often (17, 1)) operands — runtime-negligible but compile-dominating.
-# They always use the compact skew form (~25 HLO ops/mul vs ~135) so the
-# hot-path mul choice doesn't balloon compile times 5-10x.
+# They use the compact skew form (~25 HLO ops/mul vs ~135) so compile
+# times do not balloon 5-10x.
 _chain_mul = mul_skew
-
-
-def use_mul_impl(name: str) -> None:
-    """Select the hot-path field-multiply formulation ('padacc' or 'skew')
-    BEFORE any kernel is jitted — jit traces capture whatever `mul` is
-    bound to at trace time."""
-    global mul
-    mul = {"padacc": mul_padacc, "skew": mul_skew}[name]
 
 
 def sq(a: jnp.ndarray) -> jnp.ndarray:
@@ -353,10 +341,9 @@ def select(cond: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Host-side byte <-> limb conversion (vectorized numpy; used by the
-# verifier's batch-preparation path). Host arrays are batch-major (n, 17)
-# — natural for row-wise wire decoding — and transposed to the device's
-# limb-major layout at staging time (see tpu_verifier.prepare_*).
+# Byte -> window/limb conversion: vectorized numpy for the host's table
+# build (ops/comb.py), and its device twin, which the verify kernel runs
+# on the raw wire bytes.
 # ---------------------------------------------------------------------------
 
 
@@ -369,8 +356,8 @@ def extract_windows_np(data: np.ndarray, wbits: int, count: int) -> np.ndarray:
     window with two shifts — `count` vectorized ops total vs an
     unpackbits expansion to 256 int32 lanes per item (~10x faster at
     batch 8k). Windows extending past bit 255 are naturally truncated.
-    Shared by the field-limb (wbits=15) and comb-window (wbits=4/5/6)
-    decoders so the word-straddle logic lives in exactly one place."""
+    Generic in `wbits` like its device twin extract_windows_dev, which
+    the kernel calls with 4 (scalar windows) and 15 (R's limbs)."""
     words = np.ascontiguousarray(data).view("<u8")  # (n, 4)
     mask = np.uint64((1 << wbits) - 1)
     out = np.empty((count, data.shape[0]), dtype=np.int32)
@@ -420,8 +407,3 @@ def bytes32_to_limbs_np(data: np.ndarray) -> np.ndarray:
     """(n, 32) uint8 little-endian -> (n, 17) int32 limbs (batch-major
     form for host-side table building; see bytes32_to_limbs_major_np)."""
     return bytes32_to_limbs_major_np(data).T
-
-
-def sign_bits_np(data: np.ndarray) -> np.ndarray:
-    """(n, 32) uint8 -> (n,) int32 top bit (Edwards x sign)."""
-    return (data[..., 31] >> 7).astype(np.int32)

@@ -3,8 +3,6 @@
 - OpenSSLVerifier's parsed-key cache stops inserting at MAX_KEYS instead
   of clearing: committee keys stay resident under adversarial fresh-key
   churn (mirrors NativeEdVerifier._row_for's policy).
-- ops/comb.negate_rows fails loudly with RuntimeError (not a stripped
-  assert) when called on packed-layout tables.
 """
 
 import numpy as np
@@ -72,26 +70,3 @@ def test_openssl_cache_churn_costs_attacker_not_committee():
         v.verify_batch([BatchItem(evil, b"m", b"m"), BatchItem(a, b"m", b"m")])
     assert loads.count(evil) == 3
     assert loads.count(a) == 1
-
-
-# ---------------------------------------------------------------------------
-# comb.negate_rows packed-layout guard
-# ---------------------------------------------------------------------------
-
-
-def test_negate_rows_raises_runtime_error_on_packed_layout():
-    """Must be an unconditional RuntimeError: under `python -O` a bare
-    assert would vanish and packed tables would be dense-negated into
-    wrong group elements (wrong verify verdicts) silently."""
-    from simple_pbft_tpu.ops import comb
-
-    comb.use_row_packing(True)
-    try:
-        with pytest.raises(RuntimeError, match="dense-layout"):
-            comb.negate_rows(np.zeros((comb.ROW, 2), dtype=np.int32))
-    finally:
-        comb.use_row_packing(False)
-    # dense layout still works (shape sanity only; numeric behavior is
-    # covered by the kernel-vs-oracle suites)
-    rows = np.asarray(comb.base_table())
-    assert comb.negate_rows(rows).shape == rows.shape

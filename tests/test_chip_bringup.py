@@ -3,8 +3,8 @@ process, and nothing quietly runs a ``tpu`` path on the CPU.
 
 - chip_smoke.py: the CPU dry run passes; doctored, it fails; without the
   flag on a host with no chip it exits nonzero and prints no result.
-- bench.py (without --smoke), bench_consensus.py --verifier tpu and
-  launch.py -n 4 --verifier tpu refuse, nonzero, before doing anything.
+- bench_consensus.py --verifier tpu and launch.py -n 4 --verifier tpu
+  refuse, nonzero, before doing anything.
 - the compile cache is placed from outside or at one fixed path.
 - the meshed fused verifier traces with the Pallas accumulator.
 - the native loader decides freshness from the source's content.
@@ -104,13 +104,6 @@ def test_chip_smoke_without_the_flag_refuses_a_cpu_host():
 # ---------------------------------------------------------------------------
 
 
-def test_bench_without_smoke_refuses_a_cpu_host():
-    r = _run("bench.py")
-    assert r.returncode != 0
-    assert "needs a TPU" in r.stderr
-    assert r.stdout == ""  # no measurement line, not even a zero one
-
-
 def test_bench_consensus_verifier_tpu_refuses_a_cpu_host():
     r = _run("bench_consensus.py", "--verifier", "tpu", "--seconds", "1")
     assert r.returncode != 0
@@ -178,12 +171,12 @@ def test_meshed_fused_verifier_traces_with_the_pallas_accumulator():
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("dp",))
     try:
         comb.use_accum_impl("pallas_interpret")
-        got = TpuVerifier(mesh=mesh, mode="fused").verify_batch(items)
+        got = TpuVerifier(mesh=mesh).verify_batch(items)
         # "pallas" proper is Mosaic, and Mosaic cannot run here: an error,
         # never a silent drop to the interpreter
         comb.use_accum_impl("pallas")
         with pytest.raises(RuntimeError, match="needs a TPU"):
-            TpuVerifier(mesh=mesh, mode="fused").verify_batch(items)
+            TpuVerifier(mesh=mesh).verify_batch(items)
     finally:
         comb.use_accum_impl("auto")
     assert got == oracle == [True] * 12 + [False]

@@ -1,7 +1,7 @@
 """VerifyService: the process-wide coalescing verify front.
 
 Round-4 chip evidence showed n replicas each paying a full device round
-trip per sweep, serialized (bench_results/chip_r04.jsonl: n=16 TPU at
+trip per sweep, serialized (git 7f473af:bench_results/chip_r04.jsonl: n=16 TPU at
 6.4 req/s vs CPU 422). The service folds every pending sweep into one
 async device pass; these tests pin the coalescing, routing, ordering,
 failure, and end-to-end consensus behavior with controllable fakes (the

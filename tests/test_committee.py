@@ -331,7 +331,7 @@ def test_committee_over_meshed_tpu_verifier():
         from simple_pbft_tpu.crypto.tpu_verifier import TpuVerifier
 
         mesh = Mesh(np.asarray(jax.devices()[:8]), ("dp",))
-        shared = TpuVerifier(mesh=mesh, mode="fused", initial_keys=16)
+        shared = TpuVerifier(mesh=mesh, initial_keys=16)
         com = LocalCommittee.build(
             n=4,
             clients=1,

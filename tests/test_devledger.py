@@ -1,6 +1,6 @@
 """Device-plane observatory (ISSUE 14): per-dispatch ledger schema and
-aggregates, the zero-overhead-when-disabled A/B, the BLS and shard
-lanes sharing the schema, the static cost model's r05 anchor points,
+aggregates, the zero-overhead-when-disabled A/B, the BLS and per-device
+events sharing the schema, the static cost model's anchor points,
 verify_observatory's decomposition/reconciliation/limiter logic, the
 pbft_top DEV cell, and the dead-target view-change evidence rule."""
 
@@ -87,7 +87,7 @@ def test_dispatch_event_schema(fresh_ledger, warm_verifier, signed_items):
     snap = devledger.snapshot()
     assert snap["dispatches"] == 1 and snap["items"] == 5
     assert snap["pad_waste_pct"] == pytest.approx(100 * 3 / 8, abs=0.1)
-    # lane-qualified shape key: an ed25519 and a shard lane sharing a
+    # lane-qualified shape key: two lanes sharing a
     # (mode, window, bucket) must never overwrite each other
     assert "ed25519:fused/w4/b8" in snap["shapes"]
     assert 0 < snap["occupancy"] <= 1.0
@@ -194,58 +194,43 @@ def test_bls_lane_shares_schema(fresh_ledger):
     assert devledger.snapshot()["lanes"]["bls"]["dispatches"] == 1
 
 
-def test_shard_lane_per_device_events(fresh_ledger):
-    """instrument_step fans one SPMD pass into per-device events (the
-    8-mesh shard-out's schema, exercised without a mesh compile)."""
-    from simple_pbft_tpu.parallel.sharded_verify import instrument_step
-
-    mesh = SimpleNamespace(devices=np.zeros(2))  # 2-"device" stand-in
-    calls = []
-
-    def step(*args):
-        calls.append(args)
-        return np.ones(8, dtype=bool)
-
-    run = instrument_step(step, mesh, mode="comb", window=4)
-    out = run(np.zeros((17, 8), np.int32), np.zeros(8, np.int32),
-              n_valid=6)
-    assert out.shape == (8,) and len(calls) == 1
-    evs = [e for e in devledger.recent() if e["lane"] == "shard"]
+def test_per_device_events_normalize_occupancy(fresh_ledger):
+    """Events that name their device (one per chip of an SPMD pass) keep
+    the lane's device count, and occupancy is normalized by it."""
+    for d in ("d0", "d1"):
+        devledger.record(
+            "ed25519", "fused", 4, 4, 3, rtt_s=0.01, compile_fresh=d == "d0",
+            bytes_up=400, bytes_down=4, device=d,
+        )
+    evs = [e for e in devledger.recent() if e["lane"] == "ed25519"]
     assert len(evs) == 2
     assert {e["device"] for e in evs} == {"d0", "d1"}
-    assert all(e["bucket"] == 4 for e in evs)
-    assert sum(e["n"] for e in evs) == 6  # pre-pad items split across
-    lane = devledger.snapshot()["lanes"]["shard"]
+    assert all(e["bucket"] == 4 and e["pad"] == 1 for e in evs)
+    lane = devledger.snapshot()["lanes"]["ed25519"]
     assert lane["devices"] == 2 and lane["dispatches"] == 2
-    # one SPMD trace = ONE compile, stamped on one device row only
-    assert sum(1 for e in evs if e["compile"]) == 1
     assert lane["compiles"] == 1
     # occupancy normalizes by device count: one pass != 2x busy window
     assert lane["occupancy"] <= 1.0
 
 
 # ---------------------------------------------------------------------------
-# cost model (the r05 anchors)
+# cost model
 # ---------------------------------------------------------------------------
 
 
 def test_costmodel_r05_anchor_points():
-    # fused w=5: 52 joint-window gathers x 256 B dense rows — the
-    # 13,312 B/item stream the r05 memo priced the 8192-pass at
-    c5 = costmodel.shape_cost("fused", 5, 8192)
-    assert c5["gathers_per_item"] == 52
-    assert c5["gather_bytes_per_item"] == 13312
-    assert c5["gather_bytes_per_pass"] == 13312 * 8192
-    assert c5["madds_per_item"] == 52
-    # w=6 cuts madds 52 -> 43 (the A/B that pinned bandwidth-bound)
-    assert costmodel.shape_cost("fused", 6, 8192)["madds_per_item"] == 43
-    # split comb gathers two rows per position; ladder gathers nothing
-    assert costmodel.shape_cost("comb", 4, 8)["gathers_per_item"] == 128
-    assert costmodel.shape_cost("ladder", 4, 8)["gather_bytes_per_item"] == 0
-    # wire staging ships ~101 B/item on the fused path
-    assert c5["wire_bytes_per_item"] == 101
-    # unknown lane modes sum as zero instead of raising
-    assert costmodel.shape_cost("pairing", 0, 4)["gather_bytes_per_item"] == 0
+    # the kernel: 64 joint-window gathers x 256 B rows = 16,384 B an item
+    c4 = costmodel.shape_cost("fused", 4, 8192)
+    assert c4["gathers_per_item"] == 64
+    assert c4["gather_bytes_per_item"] == 16384
+    assert c4["gather_bytes_per_pass"] == 16384 * 8192
+    assert c4["madds_per_item"] == 64
+    # wire staging ships 101 B/item
+    assert c4["wire_bytes_per_item"] == 101
+    # other lanes' modes sum as zero instead of raising
+    pairing = costmodel.shape_cost("pairing", 0, 4)
+    assert pairing["gather_bytes_per_item"] == 0
+    assert pairing["madds_per_item"] == pairing["wire_bytes_per_item"] == 0
 
 
 def test_costmodel_shapes_rollup():
@@ -295,8 +280,8 @@ def test_analyze_shares_sum_and_reconciliation():
     assert rec["ledger_device_ms"] == pytest.approx(1010.0)
     assert rec["ok"] and rec["delta_pct"] <= 15.0
     assert v["limiter"] == "bandwidth"
-    assert v["roofline"]["per_shape"][0]["shape"] == "ed25519:fused/w4/b32"
-    assert v["roofline"]["gather_bytes"] > 0
+    assert v["gather"]["per_shape"][0]["shape"] == "ed25519:fused/w4/b32"
+    assert v["gather"]["gather_bytes"] > 0
 
 
 def test_analyze_reconciliation_flags_disagreement():

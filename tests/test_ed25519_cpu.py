@@ -118,7 +118,9 @@ def test_openssl_verifier_key_cache_is_bounded():
     """The OpenSSL backend's parsed-key cache must not grow without
     bound under an adversarial fresh-key spray (it serves as the
     TpuVerifier's over-bank-cap fallback, which sees exactly that
-    traffic shape); verdicts stay correct across the reset."""
+    traffic shape): it stops inserting at MAX_KEYS, the keys cached
+    before the cap stay, and verdicts stay correct for keys that never
+    got in."""
     pytest.importorskip("cryptography")
     from simple_pbft_tpu.crypto.verifier import BatchItem, OpenSSLVerifier
 
@@ -132,9 +134,12 @@ def test_openssl_verifier_key_cache_is_bounded():
     bad = BatchItem(items[0].pubkey, b"other", items[0].sig)
     out = v.verify_batch(items + [bad])
     assert out == [True] * 20 + [False]
-    assert len(v._cache) <= 8
-    # a key evicted by a reset and untouched since (key 1: loaded before
-    # the first clear, never re-seen) must still verify on re-sight —
-    # the reload-after-clear path, not a cache hit
-    assert items[1].pubkey not in v._cache
-    assert v.verify_batch([items[1]]) == [True]
+    assert len(v._cache) == 8
+    # the first eight keys got in before the cap and stay resident
+    assert set(v._cache) == {it.pubkey for it in items[:8]}
+    # a key that never got in still verifies (parsed per batch, never
+    # cached), and a forgery under it is still rejected
+    assert items[15].pubkey not in v._cache
+    forged = BatchItem(items[15].pubkey, b"other", items[15].sig)
+    assert v.verify_batch([items[15], forged]) == [True, False]
+    assert set(v._cache) == {it.pubkey for it in items[:8]}

@@ -64,13 +64,14 @@ class TestFieldOps:
             assert fe._limbs_to_int_np(out[:, j : j + 1]) == v
 
     def test_nibbles_major_layout(self):
+        # the 4-bit windows the comb tables are indexed by, position-major
         import numpy as np
 
         from simple_pbft_tpu.ops import comb
 
         rng = np.random.default_rng(8)
         data = rng.integers(0, 256, (20, 32), dtype=np.uint8)
-        out = comb.nibbles_major_np(data)
+        out = fe.extract_windows_np(data, comb.WBITS, comb.NPOS)
         assert out.shape == (comb.NPOS, 20)
         for j in range(20):
             v = int.from_bytes(bytes(data[j]), "little")
@@ -202,7 +203,7 @@ class TestPointOps:
             [np.frombuffer(ref.point_compress(p), dtype=np.uint8) for p in pts]
         )
         y_limbs = jnp.asarray(fe.bytes32_to_limbs_np(wires).T)  # (17, n)
-        sign = jnp.asarray(fe.sign_bits_np(wires))
+        sign = jnp.asarray((wires[:, 31] >> 7).astype(np.int32))
         point, ok = jax.jit(ed.decompress)(y_limbs, sign)
         y_out, x_par = jax.jit(ed.compress)(point)
         for i, p_ref in enumerate(pts):

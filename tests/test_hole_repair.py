@@ -70,7 +70,18 @@ def test_partitioned_replica_catches_up_without_view_change():
         c.request_timeout = 2.0
         await _pump_n(c, 3, "pre")
         victim = com.replica("r3")
+        # the client accepts on 2f+1 speculative replies, so the last
+        # "pre" request can return before the victim has executed it:
+        # let the victim draw level before cutting it off, or base_exec
+        # is read one slot early
+        deadline = asyncio.get_event_loop().time() + 10.0
+        while (
+            victim.executed_seq < max(r.executed_seq for r in com.replicas)
+            and asyncio.get_event_loop().time() < deadline
+        ):
+            await asyncio.sleep(0.05)
         base_exec = victim.executed_seq
+        assert base_exec == max(r.executed_seq for r in com.replicas)
         _cut_all(plan, com, "r3")
         await _pump_n(c, 6, "cut")
         assert victim.executed_seq == base_exec  # truly isolated

@@ -2,8 +2,9 @@
 
 Covers SURVEY.md §4's crypto-plane test strategy: RFC 8032 known-answer
 vectors, adversarial inputs (corrupted bits, non-canonical encodings,
-wrong lengths), per-position verdict bitmaps under batching, and the
-shard_map quorum step on the virtual 8-device mesh.
+wrong lengths), per-position verdict bitmaps under batching, the
+benchmark's planted failures by name, and the meshed kernel on the
+virtual 8-device mesh.
 """
 
 import numpy as np
@@ -11,11 +12,7 @@ import pytest
 
 from simple_pbft_tpu.crypto import ed25519_cpu as ref
 from simple_pbft_tpu.crypto.verifier import BatchItem
-from simple_pbft_tpu.crypto.tpu_verifier import (
-    TpuVerifier,
-    prepare_batch,
-    verify_kernel,
-)
+from simple_pbft_tpu.crypto.tpu_verifier import TpuVerifier
 
 # RFC 8032 §7.1 test vectors 1-3 (seed, pubkey, msg, sig)
 RFC8032_VECTORS = [
@@ -46,6 +43,14 @@ RFC8032_VECTORS = [
 @pytest.fixture(scope="module")
 def verifier():
     return TpuVerifier()
+
+
+@pytest.fixture(scope="module")
+def meshed_verifier():
+    import jax
+    from jax.sharding import Mesh
+
+    return TpuVerifier(mesh=Mesh(np.asarray(jax.devices()[:8]), ("dp",)))
 
 
 def _signed(i: int, msg: bytes):
@@ -102,69 +107,29 @@ def test_empty_batch(verifier):
 
 
 def test_windows_major_extraction():
-    """wbits-bit window extraction must reassemble to the scalar for
-    every supported width (the w>4 comb geometries depend on it)."""
+    """The device-side window extraction the kernel runs on raw wire
+    bytes must reassemble to the scalar and agree with its numpy twin,
+    at both widths the kernel uses: 4-bit scalar windows and R's 15-bit
+    limbs."""
+    import jax
+
     from simple_pbft_tpu.ops import comb
+    from simple_pbft_tpu.ops import field25519 as fe
 
     rng = np.random.default_rng(11)
     data = rng.integers(0, 256, (16, 32), dtype=np.uint8)
     data[0, :] = 0xFF
-    for w in (4, 5, 6):
-        out = comb.windows_major_np(data, w)
-        assert out.shape == (comb.npos_for(w), 16)
+    for w, count in ((comb.WBITS, comb.NPOS), (fe.RADIX, fe.NLIMB)):
+        out = np.asarray(
+            jax.jit(fe.extract_windows_dev, static_argnums=(1, 2))(data, w, count)
+        )
+        assert out.shape == (count, 16)
         assert (out < (1 << w)).all() and (out >= 0).all()
+        assert (out == fe.extract_windows_np(data, w, count)).all()
         for j in range(16):
-            v = sum(int(out[i, j]) << (w * i) for i in range(out.shape[0]))
-            assert v == int.from_bytes(bytes(data[j]), "little")
-
-
-def test_fused_window5_matches_oracle():
-    """The wide-window comb (fewer positions, bigger tables) must stay
-    bit-exact: w=5 TpuVerifier vs the RFC 8032 oracle on a mixed batch."""
-    v5 = TpuVerifier(mode="fused", window=5)
-    good = [_signed(i, b"w5 %d" % i) for i in range(3)]
-    tampered = BatchItem(good[0].pubkey, b"tampered", good[0].sig)
-    items = good + [tampered]
-    oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
-    assert v5.verify_batch(items) == oracle == [True, True, True, False]
-
-
-def test_wire_kernel_matches_host_prep():
-    """The wire kernel (raw (B, 96) bytes, on-device unpack) must be
-    bit-identical to the host-prepped fused kernel for every window
-    width — same verdicts on valid, tampered and padding rows."""
-    import jax
-
-    from simple_pbft_tpu.crypto.tpu_verifier import (
-        KeyBank,
-        prepare_comb_batch,
-        prepare_wire_batch,
-    )
-    from simple_pbft_tpu.ops import comb
-
-    good = [_signed(i, b"wire %d" % i) for i in range(5)]
-    bad = BatchItem(good[0].pubkey, b"altered", good[0].sig)
-    items = good + [bad]
-    for w in (4, 5, 6):
-        bank = KeyBank(mode="fused", window=w)
-        hp, _ = prepare_comb_batch(items, bank)
-        hp = hp.padded(8)
-        s_nib, k_nib, a_idx, r_y, r_sign, pre = hp.arrays()
-        tables = bank.device_tables()
-        want = np.asarray(
-            jax.jit(comb.fused_verify_kernel, static_argnames=("window",))(
-                s_nib, k_nib, a_idx, tables, r_y, r_sign, pre, window=1 << w
-            )
-        )
-        wp, _ = prepare_wire_batch(items, bank)
-        wire, wa_idx, wpre = wp.padded(8).arrays()
-        got = np.asarray(
-            jax.jit(
-                comb.fused_verify_wire_kernel, static_argnames=("window",)
-            )(wire, wa_idx, tables, wpre, window=1 << w)
-        )
-        assert (got == want).all(), (w, got, want)
-        assert got[: len(items)].tolist() == [True] * 5 + [False]
+            v = sum(int(out[i, j]) << (w * i) for i in range(count))
+            want = int.from_bytes(bytes(data[j]), "little")
+            assert v == want & ((1 << (w * count)) - 1)
 
 
 def test_initial_keys_pins_table_shape_and_warm_is_inert():
@@ -187,7 +152,7 @@ def test_keybank_cap_falls_back_to_cpu():
     from simple_pbft_tpu.crypto.tpu_verifier import KeyBank
 
     v = TpuVerifier()
-    v._bank = KeyBank(initial_capacity=2, max_keys=2, mode=v._mode)
+    v._bank = KeyBank(initial_capacity=2, max_keys=2)
     items = [_signed(i, b"cap %d" % i) for i in range(4)]  # 4 distinct keys
     bad = bytearray(items[3].sig)
     bad[2] ^= 4
@@ -236,7 +201,7 @@ def test_overbank_fallback_agrees_with_kernel():
     # fallback verdicts: bank capacity 1, pre-occupied by an unrelated
     # key, so EVERY edge item routes to the over-cap fallback path
     v = TpuVerifier()
-    v._bank = KeyBank(initial_capacity=1, max_keys=1, mode=v._mode)
+    v._bank = KeyBank(initial_capacity=1, max_keys=1)
     occupier = _signed(99, b"occupier")
     assert v.verify_batch([occupier]) == [True]
     assert len(v._bank._index) == 1
@@ -249,139 +214,104 @@ def test_overbank_fallback_agrees_with_kernel():
     assert type(kernel_equivalent_cpu_verifier()) is type(v._cpu_fb)
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
-def test_meshed_tpu_verifier_fused(packed):
-    """TpuVerifier(mesh=...) fused mode: the GSPMD-sharded jit path (with
-    its forced XLA accumulator — a Pallas call has no partitioning rule)
-    must agree with the oracle over the 8-device mesh, in both table-row
-    layouts (the table is replicated whatever its row width — this
-    pre-validates the default flip if the on-chip A/B favors packing)."""
-    import jax
-    from jax.sharding import Mesh
-
-    from simple_pbft_tpu.ops import comb
-
-    comb.use_row_packing(packed)
-    try:
-        mesh = Mesh(np.asarray(jax.devices()[:8]), ("dp",))
-        v = TpuVerifier(mesh=mesh, mode="fused")
-        items = [_signed(i % 4, b"meshed %d" % i) for i in range(12)]
-        forged = BatchItem(items[0].pubkey, b"not the msg", items[0].sig)
-        items.append(forged)
-        oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
-        assert v.verify_batch(items) == oracle == [True] * 12 + [False]
-    finally:
-        comb.use_row_packing(False)
+def test_meshed_tpu_verifier_fused(meshed_verifier):
+    """TpuVerifier(mesh=...): the shard_map form of the kernel must agree
+    with the oracle over the 8-device mesh."""
+    items = [_signed(i % 4, b"meshed %d" % i) for i in range(12)]
+    forged = BatchItem(items[0].pubkey, b"not the msg", items[0].sig)
+    items.append(forged)
+    oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
+    assert meshed_verifier.verify_batch(items) == oracle == [True] * 12 + [False]
 
 
-def test_sharded_comb_quorum_step():
-    """Comb-engine shard_map verify + psum tally over the 8-device mesh."""
-    import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from simple_pbft_tpu.ops import comb
-    from simple_pbft_tpu.crypto.tpu_verifier import KeyBank, prepare_comb_batch
-    from simple_pbft_tpu.parallel import make_comb_quorum_step
-
-    mesh = Mesh(np.asarray(jax.devices()[:8]), ("dp",))
-    n_inst = 2
-    items = [_signed(i % 8, b"inst vote %d" % i) for i in range(16)]
-    broken = bytearray(items[0].sig)
-    broken[3] ^= 1
-    items[0] = BatchItem(items[0].pubkey, items[0].msg, bytes(broken))
-
-    bank = KeyBank()
-    prep, _fallback = prepare_comb_batch(items, bank)
-    inst = np.arange(16, dtype=np.int32) % n_inst
-    onehot = np.eye(n_inst, dtype=np.int32)[inst]
-    vec = NamedSharding(mesh, P("dp"))  # (B,)
-    mat = NamedSharding(mesh, P(None, "dp"))  # batch axis trailing
-    repl = NamedSharding(mesh, P())
-    s_nib, k_nib, a_idx, r_y, r_sign, precheck = prep.arrays()
-    args = [
-        jax.device_put(s_nib, mat),
-        jax.device_put(k_nib, mat),
-        jax.device_put(a_idx, vec),
-        jax.device_put(np.asarray(bank.device_tables()), repl),
-        jax.device_put(comb.base_table(), repl),
-        jax.device_put(r_y, mat),
-        jax.device_put(r_sign, vec),
-        jax.device_put(precheck, vec),
-        jax.device_put(onehot, NamedSharding(mesh, P("dp", None))),
-    ]
-    verdict, counts = make_comb_quorum_step(mesh)(*args)
-    verdict, counts = np.asarray(verdict), np.asarray(counts)
-    assert not verdict[0] and verdict[1:].all()
-    assert counts.tolist() == [7, 8]
+def _not_a_point() -> bytes:
+    for last in range(256):
+        cand = bytes([7] * 31 + [last & 0x7F])
+        if ref.point_decompress(cand) is None:
+            return cand
+    raise AssertionError("no off-curve candidate found")
 
 
-def test_sharded_quorum_step():
-    """Ladder-engine shard_map verify + psum tally (fallback path)."""
-    import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+def _plant_flipped(it):
+    sig = bytearray(it.sig)
+    sig[37] ^= 0x10
+    return BatchItem(it.pubkey, it.msg, bytes(sig))
 
-    from simple_pbft_tpu.parallel import make_quorum_step
 
-    mesh = Mesh(np.asarray(jax.devices()[:8]), ("dp",))
-    n_inst = 2
-    items = [_signed(i % 8, b"inst vote %d" % i) for i in range(16)]
-    # corrupt one vote of instance 0
-    broken = bytearray(items[0].sig)
-    broken[3] ^= 1
-    items[0] = BatchItem(items[0].pubkey, items[0].msg, bytes(broken))
+def _plant_other_key(it):
+    other = bytes([2]) * 32  # _signed(2, ...)'s seed: in the batch, not item 9's
+    assert ref.public_key(other) != it.pubkey
+    return BatchItem(it.pubkey, it.msg, ref.sign(other, it.msg))
 
-    prep = prepare_batch(items)
-    inst = np.arange(16, dtype=np.int32) % n_inst
-    onehot = np.eye(n_inst, dtype=np.int32)[inst]
-    vec = NamedSharding(mesh, P("dp"))
-    mat = NamedSharding(mesh, P(None, "dp"))  # batch axis trailing
-    # arg order: a_y, a_sign, r_y, r_sign, s_bits, k_bits, precheck
-    specs = [mat, vec, mat, vec, mat, mat, vec]
-    args = [jax.device_put(a, s) for a, s in zip(prep.arrays(), specs)]
-    args.append(jax.device_put(onehot, NamedSharding(mesh, P("dp", None))))
 
-    verdict, counts = make_quorum_step(mesh)(*args)
-    verdict, counts = np.asarray(verdict), np.asarray(counts)
-    assert not verdict[0] and verdict[1:].all()
-    assert counts.tolist() == [7, 8]  # one invalid vote lost from instance 0
+def _plant_s_ge_l(it):
+    s_big = int.from_bytes(it.sig[32:], "little") + ref.L
+    return BatchItem(it.pubkey, it.msg, it.sig[:32] + s_big.to_bytes(32, "little"))
+
+
+def _plant_noncanonical_r(it):
+    return BatchItem(
+        it.pubkey, it.msg, (ref.P + 1).to_bytes(32, "little") + it.sig[32:]
+    )
+
+
+# the seven failures benchmark/stages.py: kernel_stage plants, by its names
+PLANTED = {
+    "flipped signature byte": _plant_flipped,
+    "signed by another committee key": _plant_other_key,
+    "S >= L": _plant_s_ge_l,
+    "non-canonical R.y": _plant_noncanonical_r,
+    "wrong-length key": lambda it: BatchItem(it.pubkey[:31], it.msg, it.sig),
+    "wrong-length signature": lambda it: BatchItem(it.pubkey, it.msg, it.sig[:63]),
+    "key not a curve point": lambda it: BatchItem(_not_a_point(), it.msg, it.sig),
+}
+
+
+@pytest.mark.parametrize("which", ["unmeshed", "meshed"])
+@pytest.mark.parametrize("kind", list(PLANTED))
+def test_planted_failure_agrees_with_oracle(kind, which, verifier, meshed_verifier):
+    """Each failure the benchmark plants, inside a batch of good
+    signatures: the device's verdicts equal the RFC 8032 oracle's item
+    for item, and the one False sits at the planted position."""
+    v = verifier if which == "unmeshed" else meshed_verifier
+    items = [_signed(i % 4, b"planted %d" % (i % 4)) for i in range(13)]
+    pos = 9
+    items[pos] = PLANTED[kind](items[pos])
+    oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
+    assert oracle == [i != pos for i in range(13)]
+    assert v.verify_batch(items) == oracle
 
 
 def test_pallas_accumulate_matches_xla():
     """The Pallas madd-loop kernel (interpret mode on CPU) must agree
     bit-for-bit with the XLA fori_loop path on the same batch."""
-    import jax.numpy as jnp
-
     from simple_pbft_tpu.ops import comb
-    from simple_pbft_tpu.crypto.tpu_verifier import KeyBank, prepare_comb_batch
+    from simple_pbft_tpu.crypto.tpu_verifier import KeyBank, prepare_wire_batch
 
     items = [_signed(i % 3, b"pallas %d" % i) for i in range(8)]
     broken = bytearray(items[5].sig)
     broken[9] ^= 2
     items[5] = BatchItem(items[5].pubkey, items[5].msg, bytes(broken))
 
-    bank = KeyBank(mode="fused")
-    prep, _ = prepare_comb_batch(items, bank)
-    s_nib, k_nib, a_idx, r_y, r_sign, precheck = prep.arrays()
-    tables = bank.device_tables()
-    args = (jnp.asarray(s_nib), jnp.asarray(k_nib), jnp.asarray(a_idx),
-            tables, jnp.asarray(r_y), jnp.asarray(r_sign), jnp.asarray(precheck))
+    bank = KeyBank()
+    prep, _ = prepare_wire_batch(items, bank)
+    wire, a_idx, precheck = prep.arrays()
+    args = (wire, a_idx, bank.device_tables(), precheck)
     try:
         comb.use_accum_impl("xla")
-        want = np.asarray(comb.fused_verify_kernel(*args))
+        want = np.asarray(comb.fused_verify_wire_kernel(*args))
         comb.use_accum_impl("pallas_interpret")
-        got = np.asarray(comb.fused_verify_kernel(*args))
+        got = np.asarray(comb.fused_verify_wire_kernel(*args))
     finally:
         comb.use_accum_impl("auto")  # restore the shipped default
     assert want.tolist() == [True] * 5 + [False] + [True] * 2
     assert got.tolist() == want.tolist()
 
 
-def test_row_packing_matches_oracle_and_dense():
-    """Packed table rows (two 15-bit limbs per int32, 128-byte rows —
-    the gather-bandwidth A/B, ops/comb.use_row_packing) must be
-    bit-exact against both the RFC 8032 oracle and the dense layout,
-    including invalid rows; kernels and banks built after the switch
-    capture the packed shapes."""
+def test_pallas_interpret_verifier_matches_oracle_and_xla():
+    """The whole verifier with the Pallas accumulator (interpret mode
+    here; Mosaic on the chip) must be bit-exact against both the RFC 8032
+    oracle and the XLA accumulator, including invalid rows."""
     from simple_pbft_tpu.ops import comb
 
     good = [_signed(40 + i, b"pack %d" % i) for i in range(5)]
@@ -393,23 +323,19 @@ def test_row_packing_matches_oracle_and_dense():
     ]
     oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
     assert oracle == [True] * 5 + [False, False]
-    dense = TpuVerifier(mode="fused", window=5).verify_batch(items)
-    comb.use_row_packing(True)
+    xla = TpuVerifier().verify_batch(items)
+    comb.use_accum_impl("pallas_interpret")
     try:
-        assert comb.ROW == comb.ROW_PACKED
-        packed = TpuVerifier(mode="fused", window=5).verify_batch(items)
-        # the unpack must also hold INSIDE the Pallas accumulate kernel
-        # (interpret mode here; the on-chip A/B runs it under Mosaic) —
-        # exercised directly at a small packed batch
-        comb.use_accum_impl("pallas_interpret")
-        try:
-            pal = TpuVerifier(mode="fused", window=4).verify_batch(items)
-        finally:
-            comb.use_accum_impl("auto")
+        # the shared jit traced with the XLA accumulator above; a jit of
+        # its own captures the Pallas one
+        import jax
+
+        v = TpuVerifier()
+        v._fn = jax.jit(comb.fused_verify_wire_kernel)
+        pal = v.verify_batch(items)
     finally:
-        comb.use_row_packing(False)
-    assert packed == dense == oracle
-    assert pal == oracle
+        comb.use_accum_impl("auto")
+    assert pal == xla == oracle
 
 
 def test_shape_stability_hook_post_warm(monkeypatch):

@@ -39,7 +39,7 @@ def test_keybank_races_never_alias_rows():
     from simple_pbft_tpu.ops import comb
     from simple_pbft_tpu.crypto.tpu_verifier import KeyBank
 
-    bank = KeyBank(initial_capacity=4, max_keys=24, mode="fused", window=4)
+    bank = KeyBank(initial_capacity=4, max_keys=24)
     committee = _keys(16, tag=1)
     spray = _keys(40, tag=2)  # 8 more fit under the cap; the rest UNCACHED
     bad = [bytes([i]) * 32 for i in range(8)]  # mostly non-points
@@ -91,7 +91,7 @@ def test_keybank_races_never_alias_rows():
     assert len(bank._index) <= 24
     for pk, idx in list(bank._index.items())[:8]:
         pt = ref.point_decompress(pk)
-        fresh = comb.fused_table_np(pt, 4)
+        fresh = comb.fused_table_np(pt)
         assert np.array_equal(bank._np[idx], fresh), "aliased table row"
     # spray keys beyond the cap must be UNCACHED, consistently
     over = [pk for _, pk in spray if pk not in bank._index]

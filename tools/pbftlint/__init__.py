@@ -17,7 +17,8 @@ ROADMAP queues next cannot re-introduce them:
                              consensus path ("telemetry never raises into
                              consensus")
   PBL005  assert-ban         ``assert`` in production control flow (the
-                             comb.negate_rows packed-guard precedent)
+                             PR 1 precedent: a layout guard that was an
+                             assert)
   PBL006  shape-stability    jit construction/dispatch outside the
                              recorded-signature warm path (the r5 qc256
                              mid-run-compile wedge)

@@ -19,7 +19,8 @@ actually audited to be no-raise. The checker holds the audited list:
 
 PBL005: ``assert`` compiles away under ``python -O`` — a production
 control-flow assert is a check that vanishes exactly when the system
-runs optimized (the ``comb.negate_rows`` packed-guard precedent, PR 1).
+runs optimized (the PR 1 precedent: a table-layout guard in ``ops/comb``
+that was an ``assert``).
 Flagged in every product module; validation belongs to ``raise``.
 """
 

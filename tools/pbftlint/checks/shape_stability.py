@@ -10,16 +10,14 @@ enforceable invariant. This checker makes the *static* half hold:
 
 - **no stray jit construction**: ``jax.jit(...)`` / ``shard_map`` may
   only be constructed in the registered engine modules (the kernels in
-  ``ops/``, the verifier/bank in ``crypto/tpu_verifier.py``, the
-  sharded-mesh experiments in ``parallel/``). A ``jax.jit`` in
-  consensus/transport/telemetry code is a new unwarmed dispatch surface
-  by definition.
+  ``ops/``, the verifier/bank in ``crypto/tpu_verifier.py``). A
+  ``jax.jit`` in consensus/transport/telemetry code is a new unwarmed
+  dispatch surface by definition.
 
 - **dispatch implies recording**: inside the shape-tracked modules
   (``crypto/tpu_verifier.py``, ``crypto/coalesce.py``,
   ``consensus/qc.py``), any function that CALLS a jitted handle
-  (``self._fn(...)``, a ``_JIT_CACHE[...]`` subscript call) must also
-  call ``_record_shape`` in the same body — otherwise its dispatches
+  (``self._fn(...)``) must also call ``_record_shape`` in the same body — otherwise its dispatches
   escape the warm-set accounting and ``post_warm_compiles`` lies.
 """
 
@@ -36,7 +34,6 @@ CODE = "PBL006"
 # modules allowed to construct jitted callables
 JIT_CONSTRUCTION_ALLOWED = (
     "simple_pbft_tpu/ops/",
-    "simple_pbft_tpu/parallel/",
     "simple_pbft_tpu/crypto/tpu_verifier.py",
     "simple_pbft_tpu/native/",
 )
@@ -48,7 +45,6 @@ SHAPE_TRACKED = (
 )
 # attribute names that hold jitted callables in the tracked modules
 JIT_HANDLES = {"_fn"}
-JIT_CACHES = {"_JIT_CACHE"}
 RECORDERS = {"_record_shape"}
 
 
@@ -98,7 +94,7 @@ def check(mods: List[Module], graph: callgraph.CallGraph) -> List[Finding]:
                                     f"{d}() constructed outside the "
                                     "registered engine modules — a new "
                                     "unwarmed dispatch surface; put the "
-                                    "kernel behind TpuVerifier/_shared_jit "
+                                    "kernel behind TpuVerifier/_SHARED_JIT "
                                     "so warmup and shape recording see it"
                                 ),
                             )
@@ -113,12 +109,6 @@ def check(mods: List[Module], graph: callgraph.CallGraph) -> List[Finding]:
             for c in calls:
                 d = callgraph.dotted(c.func)
                 if d is None:
-                    # _JIT_CACHE[mode](...) — subscript call
-                    f = c.func
-                    if isinstance(f, ast.Subscript) and isinstance(
-                        f.value, ast.Name
-                    ) and f.value.id in JIT_CACHES:
-                        dispatches.append((c, f.value.id + "[...]"))
                     continue
                 parts = d.split(".")
                 if parts[-1] in JIT_HANDLES:

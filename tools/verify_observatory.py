@@ -1,10 +1,8 @@
 #!/usr/bin/env python3
-"""verify_observatory: measured roofline attribution for the verify path.
+"""verify_observatory: measured attribution for the verify path.
 
-The r05 verify-plane verdict — bandwidth-bound on table-row gathers at
-777k verifies/s/chip with a route to ~1.05M — lived in a hand-written
-builder memo (2026-07-31, not reproduced since). This tool
-recomputes that decomposition from live artifacts, per run:
+Where a verify pass's time goes, recomputed from live artifacts, per
+run:
 
 - the **device ledger** (``simple_pbft_tpu/devledger.py``): per-dispatch
   (mode, window, bucket, pad, queue wait, host prep, RTT, compile,
@@ -14,10 +12,10 @@ recomputes that decomposition from live artifacts, per run:
   the acceptance bar; a bigger gap means one of the two surfaces lies);
 - the **static cost model** (``crypto/costmodel.py``): analytic
   table-gather bytes per (mode, window, bucket), turning measured
-  dispatch counts into achieved gather bandwidth.
+  dispatch counts into the bytes the kernel gathered.
 
-Output: a per-run verdict — achieved vs peak gather bandwidth, device
-occupancy, host-overhead share, and the dominant limiter (``bandwidth``
+Output: a per-run verdict — device occupancy, the share of each stage,
+gathered bytes per shape, and the dominant limiter (``bandwidth``
 / ``dispatch_gap`` / ``host_prep`` / ``queue_starvation`` /
 ``host_cpu_path``) — with ``--json`` for CI (the tier-1 device-smoke
 job gates on shares summing to 1 and the reconciliation bound).
@@ -27,11 +25,8 @@ Sources (combine freely):
                                *.spans.jsonl (stage table)
   --bench-record F [--cell C]  a bench/campaign ledger line carrying
                                ``device`` + ``spans`` blocks
-  --platform v5lite | --peak-gather-gbps X   roofline denominator
-                               (omit on CPU backends: utilization null)
 
-Triage workflow and a worked r05 re-derivation:
-docs/OBSERVABILITY.md §device observatory.
+Triage workflow: docs/OBSERVABILITY.md §device observatory.
 """
 
 from __future__ import annotations
@@ -145,8 +140,8 @@ def dominant_limiter(
     Ordered by what the biggest latency share means, with occupancy
     disambiguating the two device-flavored cases: a device-busy-
     dominated path on a SATURATED device is resource-bound (bandwidth
-    for the table engines — the r05 window-geometry A/B settled that —
-    compute for the gather-free ladder); the same share on an idle
+    for the table kernel, compute for a lane that gathers nothing);
+    the same share on an idle
     device means the pipeline isn't feeding it (queue starvation). A
     queue-wait-dominated path splits the same way: saturated device =
     backpressure (still bandwidth), idle device = the dispatcher is
@@ -171,13 +166,9 @@ def dominant_limiter(
     return "unknown"
 
 
-def analyze(
-    device: Dict[str, Any],
-    stages: Dict[str, Any],
-    peak_gather_gbps: Optional[float] = None,
-) -> Dict[str, Any]:
+def analyze(device: Dict[str, Any], stages: Dict[str, Any]) -> Dict[str, Any]:
     """Join one merged device block with one stage table into the
-    roofline verdict document."""
+    verdict document."""
     busy_ms = float(device.get("busy_s", 0.0)) * 1e3
     prep_ms = float(device.get("host_prep_s", 0.0)) * 1e3
     queue_ms = float(device.get("queue_wait_s", 0.0)) * 1e3
@@ -230,8 +221,6 @@ def analyze(
 
     shapes = device.get("shapes") or {}
     gather_bytes = costmodel.gather_bytes_for_shapes(shapes)
-    busy_s = float(device.get("busy_s", 0.0))
-    achieved = gather_bytes / busy_s / 1e9 if busy_s > 0 else 0.0
     per_shape = []
     for key, row in sorted(shapes.items()):
         parsed = costmodel.parse_shape_key(key)
@@ -252,23 +241,14 @@ def analyze(
                 cost["gather_bytes_per_pass"] * row.get("dispatches", 0)
             ),
         })
-    roofline = {
-        "gather_bytes": gather_bytes,
-        "achieved_gather_gbps": round(achieved, 3),
-        "peak_gather_gbps": peak_gather_gbps,
-        "utilization": (
-            round(achieved / peak_gather_gbps, 3)
-            if peak_gather_gbps else None
-        ),
-        "per_shape": per_shape,
-    }
+    gather = {"gather_bytes": gather_bytes, "per_shape": per_shape}
     return {
         "schema_version": 1,
         "window_s": device.get("window_s", 0.0),
         "device": device,
         "decomposition": {"totals_ms": totals, "shares": shares},
         "reconciliation": reconciliation,
-        "roofline": roofline,
+        "gather": gather,
         "limiter": dominant_limiter(shares, device, gather_bytes),
     }
 
@@ -318,7 +298,7 @@ def from_bench_record(path: str, cell: Optional[str]) -> Optional[Dict[str, Any]
 
 def main() -> None:
     ap = argparse.ArgumentParser(
-        description="measured roofline attribution for the TPU verify path"
+        description="measured attribution for the TPU verify path"
     )
     ap.add_argument("files", nargs="*", help="span JSONL files to join")
     ap.add_argument("--log-dir", default=None,
@@ -331,20 +311,9 @@ def main() -> None:
     ap.add_argument("--cell", default=None,
                     help="cell/config key inside --bench-record (default: "
                     "last line with a device block)")
-    ap.add_argument("--platform", default=None,
-                    choices=sorted(costmodel.PEAK_GATHER_GBPS),
-                    help="named measured gather-bandwidth ceiling "
-                    "(crypto/costmodel.py)")
-    ap.add_argument("--peak-gather-gbps", type=float, default=None,
-                    help="explicit roofline denominator, GB/s (overrides "
-                    "--platform; omit on CPU backends)")
     ap.add_argument("--json", action="store_true",
                     help="emit the verdict as one JSON document")
     args = ap.parse_args()
-
-    peak = args.peak_gather_gbps
-    if peak is None and args.platform:
-        peak = costmodel.PEAK_GATHER_GBPS[args.platform]
 
     device: Optional[Dict[str, Any]] = None
     stages: Dict[str, Any] = {}
@@ -374,7 +343,7 @@ def main() -> None:
                 critical_path.load_spans(span_paths)
             )
 
-    verdict = analyze(device, stages, peak_gather_gbps=peak)
+    verdict = analyze(device, stages)
     if args.json:
         print(json.dumps(verdict, sort_keys=True))
     else:
@@ -384,7 +353,7 @@ def main() -> None:
 
 def render(v: Dict[str, Any]) -> str:
     d = v["device"]
-    r = v["roofline"]
+    r = v["gather"]
     rec = v["reconciliation"]
     lines = [
         f"verify_observatory: {d.get('dispatches', 0)} dispatches / "
@@ -404,12 +373,7 @@ def render(v: Dict[str, Any]) -> str:
             f"   {k:<12} {frac * 100:5.1f}%  "
             f"({v['decomposition']['totals_ms'][k]:.1f} ms)"
         )
-    util = (f"{r['utilization'] * 100:.0f}% of {r['peak_gather_gbps']} GB/s"
-            if r["utilization"] is not None else "peak unknown")
-    lines.append(
-        f"-- roofline: {r['achieved_gather_gbps']} GB/s achieved table "
-        f"gather ({util})"
-    )
+    lines.append(f"-- table gather: {r['gather_bytes']} B in all")
     for row in r["per_shape"]:
         lines.append(
             f"   {row['shape']:<16} {row['dispatches']:>6} passes  "
