@@ -8,11 +8,14 @@ those verifies would sit). This module fills that gap TPU-first:
 
 - The consensus plane drains every pending (pubkey, message, signature)
   tuple into one batch.
-- Host prep is vectorized: wire bytes are split with numpy (one join +
-  frombuffer per batch, no per-item Python), and the challenge scalars
-  k = SHA-512(R||A||M) mod L come from the native OpenMP batch hasher
-  (simple_pbft_tpu/native/). The raw (B, 96) bytes go to the device,
-  which unpacks windows and limbs itself.
+- Host prep is one Python pass over the pile (byte joins, the key
+  bank's dict lookup) and ONE call into the native library
+  (simple_pbft_tpu/native/: prepare_wire), which hashes the challenge
+  scalars k = SHA-512(R||A||M) mod L, applies the canonicality reject
+  policy and writes the padded (B, 96) rows: the dispatcher's thread
+  shares the interpreter lock with the event loop, and every numpy step
+  that gave the lock up cost a wait to get it back. The raw (B, 96)
+  bytes go to the device, which unpacks windows and limbs itself.
 - One jitted device pass per batch (the fused comb kernel — see
   ops/comb.py). Constant shapes, no data-dependent control flow — every
   signature costs the same fixed sequence, so XLA compiles one kernel
@@ -33,7 +36,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +51,14 @@ from .verifier import BatchItem
 # XLA compiles at most len(BUCKETS) kernels, never one per batch size.
 BUCKETS = (8, 32, 128, 512, 2048, 8192)
 
+# The largest bucket whose pile is hashed on the calling thread with the
+# interpreter lock HELD. 512 items are under 0.3 ms of hashing; giving the
+# lock up costs up to the interpreter's 5 ms switch interval to get it
+# back from a busy event loop, and waking the OpenMP pool 0.35-0.65 ms when
+# passes come 20 ms apart (a 130-item pile: 0.08 ms hot, the sandbox's
+# CPU, ISSUE 33). A larger pile releases the lock and fans out.
+LOCK_HELD_BUCKET = 512
+
 _L_BYTES = ref.L.to_bytes(32, "little")
 
 _ZERO32 = bytes(32)
@@ -55,8 +66,8 @@ _ZERO64 = bytes(64)
 
 
 # ---------------------------------------------------------------------------
-# Host-side batch preparation (numpy + native hashing; no per-item Python
-# beyond dict lookups and byte-string joins)
+# The numpy staging's canonicality checks (the native call has its own;
+# these run where the library is absent, and the tests compare the two)
 # ---------------------------------------------------------------------------
 
 
@@ -82,27 +93,6 @@ def _ge_l_np(s_bytes: np.ndarray) -> np.ndarray:
         gt |= undecided & (b > l_arr[i])
         undecided &= b == l_arr[i]
     return gt | undecided  # equal counts as >= L
-
-
-def _split_items(items: Sequence[BatchItem]):
-    """Items -> (pub (n,32), r (n,32), s (n,32), msgs list, wellformed
-    (n,) bool) with malformed rows zeroed — one join per field, no
-    per-item numpy."""
-    n = len(items)
-    ok = np.ones(n, dtype=bool)
-    pubs: List[bytes] = []
-    sigs: List[bytes] = []
-    msgs: List[bytes] = []
-    for i, it in enumerate(items):
-        good = len(it.pubkey) == 32 and len(it.sig) == 64
-        if not good:
-            ok[i] = False
-        pubs.append(it.pubkey if good else _ZERO32)
-        sigs.append(it.sig if good else _ZERO64)
-        msgs.append(it.msg)
-    pub = np.frombuffer(b"".join(pubs), dtype=np.uint8).reshape(n, 32)
-    sig = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-    return pub, sig[:, :32], sig[:, 32:], msgs, ok
 
 
 def _bucket_size(n: int) -> int:
@@ -194,33 +184,51 @@ class KeyBank:
             self._dirty = True
             return idx
 
-    def lookup_many(self, items: Sequence[BatchItem]) -> "tuple[np.ndarray, np.ndarray, List[int]]":
-        """Resolve every item's pubkey row in one pass: -> (a_idx (n,)
-        int32, hit (n,) bool, fallback positions). One lock acquisition
-        covers the hit path (a per-item `lookup()` call pays lock+method
-        overhead ~4 ms at batch 8k); misses take the slow build path."""
+    def lookup_pile(self, items: Sequence[BatchItem], size: int):
+        """One pass over a pile: -> (pub, sig, msgs, ok, a_idx, fallback).
+
+        `pub` and `sig` are the items' keys (32 bytes a row) and
+        signatures (64) joined, `msgs` their messages, `ok` one byte a row
+        (0 = malformed lengths, or a key that is no curve point or is over
+        the cap; such a row's key and signature are zeroed), `a_idx` the
+        (size,) int32 table rows padded to the bucket in its one
+        allocation, `fallback` the positions whose key is valid but over
+        the cap. Plain Python under one lock acquisition, so the
+        interpreter lock is never given up: no numpy call here loops over
+        the rows. Misses take the slow build path."""
         n = len(items)
-        a_idx = np.zeros(n, dtype=np.int32)
-        hit = np.ones(n, dtype=bool)
-        fallback: List[int] = []
-        misses: List[int] = []
+        pubs: List[bytes] = []
+        sigs: List[bytes] = []
+        msgs: List[bytes] = []
+        rows = [0] * size
+        bad: List[int] = []  # rows that miss the bank or carry a wrong length
         with self._lock:
-            index = self._index
+            row_of = self._index.get
             for i, it in enumerate(items):
-                idx = index.get(it.pubkey)
-                if idx is not None:
-                    a_idx[i] = idx
+                pk, sg = it.pubkey, it.sig
+                idx = row_of(pk)
+                if idx is None or len(sg) != 64:
+                    bad.append(i)
                 else:
-                    misses.append(i)
-        for i in misses:
-            idx = self.lookup(items[i].pubkey)
+                    rows[i] = idx
+                pubs.append(pk)
+                sigs.append(sg)
+                msgs.append(it.msg)
+        ok = bytearray(b"\x01") * n
+        fallback: List[int] = []
+        for i in bad:
+            idx = self.lookup(pubs[i])
             if idx >= 0:
-                a_idx[i] = idx
-            else:
-                hit[i] = False
-                if idx == KeyBank.UNCACHED:
-                    fallback.append(i)
-        return a_idx, hit, fallback
+                rows[i] = idx
+            elif idx == KeyBank.UNCACHED:
+                fallback.append(i)
+            if len(pubs[i]) != 32 or len(sigs[i]) != 64:
+                pubs[i], sigs[i] = _ZERO32, _ZERO64
+                ok[i] = 0
+            elif idx < 0:
+                ok[i] = 0
+        a_idx = np.array(rows, dtype=np.int32)
+        return b"".join(pubs), b"".join(sigs), msgs, ok, a_idx, fallback
 
     def device_tables(self) -> jnp.ndarray:
         """Flat (cap * comb.ROWS_PER_KEY, ROW) table on device."""
@@ -233,57 +241,60 @@ class KeyBank:
             return self._dev
 
 
-class WireBatch:
-    """Raw-bytes staging for the kernel: one (n, 96) uint8 array
-    (S ‖ k ‖ R per row) plus key rows and the precheck mask. Window
-    extraction, limb decomposition and the sign bit happen on the
-    device (ops/comb.fused_verify_wire_kernel)."""
+class WireBatch(NamedTuple):
+    """Raw-bytes staging for the kernel, padded to its bucket: one
+    (size, 96) uint8 array (S ‖ k ‖ R per row) plus key rows and the
+    precheck mask (pad rows carry precheck=False). Window extraction,
+    limb decomposition and the sign bit happen on the device
+    (ops/comb.fused_verify_wire_kernel)."""
 
-    def __init__(self, n: int, wire: np.ndarray, a_idx: np.ndarray,
-                 precheck: np.ndarray):
-        self.n = n
-        self._arrays = (wire, a_idx, precheck)
+    wire: np.ndarray  # (size, 96) uint8
+    a_idx: np.ndarray  # (size,) int32
+    precheck: np.ndarray  # (size,) bool
+    fallback: List[int]  # positions the caller verifies on the CPU path
+    native: bool  # staged by native.prepare_wire, not by numpy
 
-    def arrays(self):
-        return self._arrays
 
-    def padded(self, size: int) -> "WireBatch":
-        """Zero-pad the batch (leading) dim up to `size`; keeps n = the
-        pre-pad item count (pad rows carry precheck=False)."""
-        if size == self.n:
-            return self
-        wire, a_idx, precheck = self._arrays
-        pad = size - self.n
-        assert pad > 0, (size, self.n)
-        return WireBatch(
-            self.n,
-            np.pad(wire, ((0, pad), (0, 0))),
-            np.pad(a_idx, (0, pad)),
-            np.pad(precheck, (0, pad)),
-        )
+def _stage_numpy(pub: bytes, sig: bytes, msgs: Sequence[bytes],
+                 ok: bytearray, size: int):
+    """The staging native.prepare_wire does, in numpy: what runs where the
+    native library is absent, and the reference the tests hold it to."""
+    n = len(msgs)
+    pub_np = np.frombuffer(pub, dtype=np.uint8).reshape(n, 32)
+    sig_np = np.frombuffer(sig, dtype=np.uint8).reshape(n, 64)
+    r_raw, s_raw = sig_np[:, :32], sig_np[:, 32:]
+    k_raw = native.challenge_batch(r_raw, pub_np, msgs)
+    precheck = np.frombuffer(ok, dtype=np.uint8).astype(bool)
+    precheck &= ~_ge_l_np(s_raw)
+    precheck &= ~_ge_p_np(r_raw)
+    wire = np.concatenate([s_raw, k_raw, r_raw], axis=1)  # (n, 96) uint8
+    pad = size - n
+    return np.pad(wire, ((0, pad), (0, 0))), np.pad(precheck, (0, pad))
 
 
 def prepare_wire_batch(
-    items: Sequence[BatchItem], bank: KeyBank
-) -> "tuple[WireBatch, List[int]]":
-    """Wire bytes -> WireBatch, registering pubkeys in `bank`.
+    items: Sequence[BatchItem], bank: KeyBank, size: int
+) -> WireBatch:
+    """Wire bytes -> WireBatch padded to the bucket `size`, registering
+    pubkeys in `bank`.
 
-    Returns (batch, fallback): `fallback` lists item positions whose
-    pubkey is valid but over the bank's cap — the caller must verify
-    those on the CPU path (their device rows are masked out). Host work
-    is only the byte joins, the bank's dict lookup, the native challenge
-    hash and the canonicality reject policy (S >= L malleability,
-    non-canonical R.y) — no window/limb unpacking."""
-    pub, r_raw, s_raw, msgs, ok = _split_items(items)
-    a_idx, hit, fallback = bank.lookup_many(items)
-    ok &= hit
-
-    k_raw = native.challenge_batch(r_raw, pub, msgs)
-
-    ok &= ~_ge_l_np(s_raw)
-    ok &= ~_ge_p_np(r_raw)
-    wire = np.concatenate([s_raw, k_raw, r_raw], axis=1)  # (n, 96) uint8
-    return WireBatch(len(items), wire, a_idx.astype(np.int32), ok), fallback
+    `fallback` lists item positions whose pubkey is valid but over the
+    bank's cap — the caller must verify those on the CPU path (their
+    device rows are masked out). Host work is one Python pass over the
+    items (the byte joins and the bank's dict lookup) and one native call
+    for the challenge hash, the canonicality reject policy (S >= L
+    malleability, non-canonical R.y) and the padding — no window/limb
+    unpacking. The pile's size alone decides how that call is made
+    (LOCK_HELD_BUCKET)."""
+    pub, sig, msgs, ok, a_idx, fallback = bank.lookup_pile(items, size)
+    staged = native.prepare_wire(
+        pub, sig, msgs, ok, size, hold_lock=size <= LOCK_HELD_BUCKET
+    )
+    wire, precheck = (
+        staged if staged is not None
+        else _stage_numpy(pub, sig, msgs, ok, size)
+    )
+    return WireBatch(wire, a_idx, precheck, fallback, staged is not None)
 
 
 # One device pass at a time, process-wide. The replica runtime calls
@@ -450,6 +461,11 @@ class TpuVerifier:
         # items answered by the over-cap CPU fallback instead of the
         # device (keys beyond the bank's max_keys)
         self.overcap_fallback_items = 0
+        # items of finished passes by who staged them: the native library's
+        # one call (prepare_wire) or the numpy staging that stands in for
+        # it where the library is absent
+        self.native_prep_items = 0
+        self.fallback_prep_items = 0
 
     @classmethod
     def for_population(
@@ -508,7 +524,7 @@ class TpuVerifier:
         the same initial_keys, so their table shapes match."""
         for pk in pubkeys:
             self._bank.lookup(pk)
-        # wrong-length pubkey: _split_items masks the row and the bank
+        # wrong-length pubkey: KeyBank.lookup_pile masks the row and the bank
         # rejects it without registering — an all-zero 32-byte key would
         # decompress to a valid (order-4) point and permanently occupy a
         # bank slot, skewing the very capacity this warmup pins
@@ -559,6 +575,8 @@ class TpuVerifier:
             "post_warm_compiles": self.post_warm_compiles,
             "bucket_hits": {str(k): v for k, v in sorted(self.bucket_hits.items())},
             "overcap_fallback_items": self.overcap_fallback_items,
+            "native_prep_items": self.native_prep_items,
+            "fallback_prep_items": self.fallback_prep_items,
         }
 
     def lowered_text(self, size: int) -> str:
@@ -623,10 +641,12 @@ class TpuVerifier:
         # planes show the prep beside the device's modules
         with spans.annotation(spans.VERIFY_HOST_PREP):
             size = _bucket_size(max(len(items), self._align))
-            prep, fallback = prepare_wire_batch(items, self._bank)
-            prep = prep.padded(size)
-            wire, a_idx, precheck = prep.arrays()
-            args = (wire, a_idx, self._bank.device_tables(), precheck)
+            prep = prepare_wire_batch(items, self._bank, size)
+            fallback = prep.fallback
+            args = (
+                prep.wire, prep.a_idx, self._bank.device_tables(),
+                prep.precheck,
+            )
             compile_fresh = self._record_shape(size)
         # host-side prep (byte joins, challenge hashes, padding) is CPU
         # work on the dispatcher's thread — if it rivals the
@@ -661,6 +681,12 @@ class TpuVerifier:
             # LOWER bound on the device rate when calls overlap).
             with _DEVICE_LOCK:
                 self.device_seconds += rtt
+                # counted where the pass ends, as the service counts its
+                # device_pass_items, so the two cover the same passes
+                if prep.native:
+                    self.native_prep_items += len(items)
+                else:
+                    self.fallback_prep_items += len(items)
             # per-dispatch device ledger event (ISSUE 14): one row per
             # jit dispatch with the full cost tuple — the continuously-
             # measured form of the r05 hand decomposition
@@ -695,6 +721,6 @@ class TpuVerifier:
                 self.overcap_fallback_items += len(fallback)
                 for i, ok_i in zip(fallback, fb_out):
                     verdict[i] = ok_i
-            return verdict[: prep.n].tolist()
+            return verdict[: len(items)].tolist()
 
         return finish

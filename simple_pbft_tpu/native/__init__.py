@@ -12,6 +12,10 @@ and chip_smoke.py refuses to call the chip proven on the fallback.
 API (numpy in, numpy out, zero per-item Python work):
 - challenge_batch(r, a, msgs) -> (n, 32) uint8 little-endian scalars
   k_i = SHA-512(R_i || A_i || M_i) mod L   (the Ed25519 challenge)
+- prepare_wire(pub, sig, msgs, ok, size, hold_lock) -> the verifier's
+  staged (size, 96) uint8 rows S || k || R and (size,) bool precheck, or
+  None without the library (the one entry point with no fallback of its
+  own: the numpy staging in crypto/tpu_verifier.py is it)
 - sha512_batch(msgs) -> (n, 64) uint8 digests
 """
 
@@ -20,6 +24,7 @@ from __future__ import annotations
 import ctypes
 import glob
 import hashlib
+import itertools
 import logging
 import os
 import subprocess
@@ -36,6 +41,9 @@ _SRC_ED = os.path.join(os.path.dirname(__file__), "ed25519.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+# the same library through a handle that keeps the interpreter lock across
+# a call (ctypes.CDLL gives it up); prepare_wire's small piles go this way
+_lib_held: Optional[ctypes.PyDLL] = None
 _tried = False
 # own lock: a first-use BLS build (g++, up to ~2 min) must not stall
 # Ed25519 host-prep calls on the unrelated library
@@ -139,6 +147,14 @@ def _configure_hostprep(lib):
     lib.sc_reduce_batch.restype = None
     lib.native_num_threads.argtypes = []
     lib.native_num_threads.restype = ctypes.c_int
+    global _lib_held
+    _lib_held = ctypes.PyDLL(lib._name)
+    for handle in (lib, _lib_held):
+        handle.prepare_wire.argtypes = [
+            _u8p, _u8p, _u8p, _i64p, _u8p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int, _u8p, _u8p,
+        ]
+        handle.prepare_wire.restype = None
     return lib
 
 
@@ -382,8 +398,12 @@ def num_threads() -> int:
 
 
 def _concat_offsets(msgs: Sequence[bytes]):
-    offs = np.zeros(len(msgs) + 1, dtype=np.int64)
-    np.cumsum([len(m) for m in msgs], out=offs[1:])
+    # summed in Python: numpy gives the interpreter lock up around a loop
+    # over 500 elements, and prepare_wire's caller must not queue for it
+    offs = np.fromiter(
+        itertools.accumulate(map(len, msgs), initial=0), np.int64,
+        len(msgs) + 1,
+    )
     cat = b"".join(msgs)
     buf = np.frombuffer(cat, dtype=np.uint8) if cat else np.zeros(1, np.uint8)
     return np.ascontiguousarray(buf), offs
@@ -413,6 +433,43 @@ def challenge_batch(
         k = ref.challenge_scalar(r[i].tobytes(), a[i].tobytes(), m)
         out[i] = np.frombuffer(k.to_bytes(32, "little"), np.uint8)
     return out
+
+
+def prepare_wire(
+    pub: bytes, sig: bytes, msgs: Sequence[bytes], ok: bytearray,
+    size: int, hold_lock: bool,
+) -> "Optional[tuple[np.ndarray, np.ndarray]]":
+    """A pile's joined bytes -> what the verify kernel takes, in one call.
+
+    `pub` is the n public keys joined (32 bytes each), `sig` the n
+    signatures R || S joined (64 each), `ok` one byte a row (0 = already
+    rejected). Returns (wire (size, 96) uint8 of S || k || R rows,
+    precheck (size,) bool = ok and S < L and R.y < p), rows n..size zeroed
+    and false; None when the library is absent.
+
+    `hold_lock` keeps the interpreter lock and hashes on the calling
+    thread; otherwise the call gives the lock up and fans out over the
+    OpenMP pool. The bytes are the same either way. Nothing here but the
+    lock-released call itself lets go of the lock (no numpy loop over 500
+    elements), so a pass queues for it at most once."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(msgs)
+    if (len(pub), len(sig), len(ok)) != (32 * n, 64 * n, n) or size < n:
+        raise ValueError(
+            f"prepare_wire: {n} messages with {len(pub)} key bytes, "
+            f"{len(sig)} signature bytes, {len(ok)} ok bytes, size {size}"
+        )
+    cat, offs = _concat_offsets(msgs)
+    wire = np.empty((size, 96), dtype=np.uint8)
+    precheck = np.empty(size, dtype=np.bool_)
+    (_lib_held if hold_lock else lib).prepare_wire(
+        np.frombuffer(pub, np.uint8), np.frombuffer(sig, np.uint8), cat, offs,
+        np.frombuffer(ok, np.uint8), n, size, 0 if hold_lock else 1,
+        wire, precheck.view(np.uint8),
+    )
+    return wire, precheck
 
 
 def sc_reduce_batch(digests: np.ndarray) -> np.ndarray:

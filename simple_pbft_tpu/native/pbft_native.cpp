@@ -19,6 +19,8 @@
 //     bits, so magnitudes shrink ~127 bits per fold)
 //   - challenge_batch / sha512_batch: OpenMP-parallel batch drivers over
 //     flat numpy buffers (no per-item allocation).
+//   - prepare_wire: a pile's joined bytes -> the staged (size, 96) rows and
+//     the precheck mask the kernel takes, padding included.
 //
 // The reference implements none of this (it has no signatures at all —
 // /root/reference/utils/utils.go:13-17 is its entire crypto surface); this
@@ -281,6 +283,20 @@ void sc_reduce(const uint8_t in[64], uint8_t out[32]) {
   }
 }
 
+// out = SHA-512(r || a || msg) mod L, little-endian 32 bytes: the Ed25519
+// challenge scalar of one signature (r, a: 32 bytes each).
+void challenge(const uint8_t* r, const uint8_t* a, const uint8_t* msg,
+               uint64_t len, uint8_t* out) {
+  Sha512Ctx c;
+  sha512_init(&c);
+  sha512_update(&c, r, 32);
+  sha512_update(&c, a, 32);
+  sha512_update(&c, msg, len);
+  uint8_t digest[64];
+  sha512_final(&c, digest);
+  sc_reduce(digest, out);
+}
+
 }  // namespace
 
 // ---------------------------------------------------------------------------
@@ -295,15 +311,51 @@ extern "C" {
 void challenge_batch(const uint8_t* r, const uint8_t* a, const uint8_t* msgs,
                      const int64_t* offs, int64_t n, uint8_t* out) {
 #pragma omp parallel for schedule(static)
+  for (int64_t i = 0; i < n; ++i)
+    challenge(r + 32 * i, a + 32 * i, msgs + offs[i],
+              (uint64_t)(offs[i + 1] - offs[i]), out + 32 * i);
+}
+
+// The verifier's whole staging of a pile, from the joined wire bytes to the
+// arrays the kernel takes, in one call that needs no interpreter. For row
+// i < n: wire[i] = S || k || R (96 bytes; sig[i] = R || S, k as in
+// challenge_batch) and precheck[i] = ok[i] and S < L and R.y < p (the
+// reject policy: malleable S, non-canonical R.y; bit 255 of R is the sign
+// of x and is ignored). Rows n..size are the bucket's padding: zeroed, with
+// precheck false. `parallel` 0 hashes on the calling thread and leaves the
+// OpenMP pool asleep (the caller holds the interpreter lock for a small
+// pile); otherwise the rows fan out as in challenge_batch.
+void prepare_wire(const uint8_t* pub, const uint8_t* sig, const uint8_t* msgs,
+                  const int64_t* offs, const uint8_t* ok, int64_t n,
+                  int64_t size, int parallel, uint8_t* wire,
+                  uint8_t* precheck) {
+#pragma omp parallel for schedule(static) if (parallel)
   for (int64_t i = 0; i < n; ++i) {
-    Sha512Ctx c;
-    sha512_init(&c);
-    sha512_update(&c, r + 32 * i, 32);
-    sha512_update(&c, a + 32 * i, 32);
-    sha512_update(&c, msgs + offs[i], (uint64_t)(offs[i + 1] - offs[i]));
-    uint8_t digest[64];
-    sha512_final(&c, digest);
-    sc_reduce(digest, out + 32 * i);
+    const uint8_t* r = sig + 64 * i;
+    const uint8_t* s = r + 32;
+    uint8_t* row = wire + 96 * i;
+    std::memcpy(row, s, 32);
+    challenge(r, pub + 32 * i, msgs + offs[i],
+              (uint64_t)(offs[i + 1] - offs[i]), row + 32);
+    std::memcpy(row + 64, r, 32);
+    // S >= L, from the most significant limb down (equal counts)
+    bool s_ge_l = true;
+    for (int j = 3; j >= 0; --j) {
+      uint64_t w = 0;
+      for (int b = 7; b >= 0; --b) w = (w << 8) | s[8 * j + b];
+      if (w != kL[j]) {
+        s_ge_l = w > kL[j];
+        break;
+      }
+    }
+    // y >= p = 2^255 - 19: bits 8..254 all ones and the low byte >= 0xed
+    bool y_ge_p = r[0] >= 0xed && (r[31] & 0x7f) == 0x7f;
+    for (int j = 1; j < 31 && y_ge_p; ++j) y_ge_p = r[j] == 0xff;
+    precheck[i] = (ok[i] && !s_ge_l && !y_ge_p) ? 1 : 0;
+  }
+  if (size > n) {
+    std::memset(wire + 96 * n, 0, (size_t)(96 * (size - n)));
+    std::memset(precheck + n, 0, (size_t)(size - n));
   }
 }
 

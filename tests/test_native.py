@@ -10,6 +10,7 @@ library falls back to Python — these tests then exercise the fallback.
 import hashlib
 
 import numpy as np
+import pytest
 
 from simple_pbft_tpu import native
 from simple_pbft_tpu.crypto import ed25519_cpu as ref
@@ -79,3 +80,40 @@ def test_empty_batch():
         np.zeros((0, 32), np.uint8), np.zeros((0, 32), np.uint8), []
     ).shape == (0, 32)
     assert native.sha512_batch([]).shape == (0, 64)
+
+
+@pytest.mark.parametrize("hold_lock", [True, False], ids=["held", "released"])
+def test_prepare_wire_matches_oracle(hold_lock):
+    """The verifier's staging in one call, on random rows over every
+    SHA-512 padding edge: S || k || R with the oracle's k, the reject
+    policy as Python bigints state it, the padding zeroed."""
+    if not native.available():
+        pytest.skip("no native host-prep library on this machine")
+    rng = np.random.default_rng(12)
+    n, size = len(EDGE_LENS), 32
+    pub = rng.integers(0, 256, 32 * n, dtype=np.uint8).tobytes()
+    sig = bytearray(rng.integers(0, 256, 64 * n, dtype=np.uint8).tobytes())
+    for i in range(0, n, 2):
+        sig[64 * i + 63] &= 0x0F  # every other S below L
+    msgs = [rng.integers(0, 256, ln, dtype=np.uint8).tobytes() for ln in EDGE_LENS]
+    ok = bytearray(b"\x01") * n
+    ok[4] = 0
+    wire, precheck = native.prepare_wire(pub, bytes(sig), msgs, ok, size, hold_lock)
+    assert wire.shape == (size, 96) and wire.dtype == np.uint8
+    assert precheck.shape == (size,) and precheck.dtype == np.bool_
+    for i in range(n):
+        r, s = bytes(sig[64 * i : 64 * i + 32]), bytes(sig[64 * i + 32 : 64 * i + 64])
+        k = ref.challenge_scalar(r, pub[32 * i : 32 * i + 32], msgs[i])
+        assert wire[i].tobytes() == s + k.to_bytes(32, "little") + r, f"row {i}"
+        y = int.from_bytes(r, "little") & ((1 << 255) - 1)
+        want = ok[i] == 1 and int.from_bytes(s, "little") < ref.L and y < ref.P
+        assert bool(precheck[i]) == want, f"row {i}"
+    assert precheck[:n].sum() >= 6  # both verdicts occur
+    assert not wire[n:].any() and not precheck[n:].any()
+
+
+def test_prepare_wire_empty_pile():
+    if not native.available():
+        pytest.skip("no native host-prep library on this machine")
+    wire, precheck = native.prepare_wire(b"", b"", [], bytearray(), 8, True)
+    assert wire.shape == (8, 96) and not wire.any() and not precheck.any()

@@ -294,9 +294,8 @@ def test_pallas_accumulate_matches_xla():
     items[5] = BatchItem(items[5].pubkey, items[5].msg, bytes(broken))
 
     bank = KeyBank()
-    prep, _ = prepare_wire_batch(items, bank)
-    wire, a_idx, precheck = prep.arrays()
-    args = (wire, a_idx, bank.device_tables(), precheck)
+    prep = prepare_wire_batch(items, bank, 8)
+    args = (prep.wire, prep.a_idx, bank.device_tables(), prep.precheck)
     try:
         comb.use_accum_impl("xla")
         want = np.asarray(comb.fused_verify_wire_kernel(*args))
@@ -368,3 +367,246 @@ def test_shape_stability_hook_post_warm(monkeypatch):
     assert v2.post_warm_compiles == 0
     assert v2.verify_batch(items[:20]) == [True] * 20  # pads to 32
     assert v2.post_warm_compiles == 1
+
+
+# ---------------------------------------------------------------------------
+# host staging: native.prepare_wire against the numpy staging (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def pool():
+    """32 signed items over 4 keys, message lengths 0..31: what the piles
+    below are tiled from (signing is the slow part)."""
+    return [_signed(1 + i % 4, b"m" * i) for i in range(32)]
+
+
+@pytest.fixture
+def native_lib():
+    from simple_pbft_tpu import native
+
+    if not native.available():
+        pytest.skip("no native host-prep library on this machine")
+    return native
+
+
+def _stage_both(items, monkeypatch, bank=None):
+    """(native staging, numpy staging) of one pile padded to its bucket,
+    each on a bank of its own built the same way."""
+    from simple_pbft_tpu.crypto import tpu_verifier as tv
+
+    bank = bank or tv.KeyBank
+    size = tv._bucket_size(len(items))
+    got = tv.prepare_wire_batch(items, bank(), size)
+    with monkeypatch.context() as m:
+        m.setattr(tv.native, "prepare_wire", lambda *a, **kw: None)
+        want = tv.prepare_wire_batch(items, bank(), size)
+    assert got.native and not want.native
+    return got, want
+
+
+def _assert_same_staging(got, want, size):
+    for field, dtype, shape in (
+        ("wire", np.uint8, (size, 96)),
+        ("a_idx", np.int32, (size,)),
+        ("precheck", np.bool_, (size,)),
+    ):
+        g, w = getattr(got, field), getattr(want, field)
+        assert g.dtype == w.dtype == dtype and g.shape == w.shape == shape, field
+        assert g.flags.c_contiguous, field
+        assert g.tobytes() == w.tobytes(), field
+    assert got.fallback == want.fallback
+
+
+def _with_s(it, s: int):
+    return BatchItem(it.pubkey, it.msg, it.sig[:32] + s.to_bytes(32, "little"))
+
+
+def _with_r(it, y: int, sign: int):
+    return BatchItem(
+        it.pubkey, it.msg, (y | sign << 255).to_bytes(32, "little") + it.sig[32:]
+    )
+
+
+@pytest.mark.parametrize("n", [1, 7, 128, 129, 512, 513, 2048])
+def test_native_staging_equals_numpy_by_pile_size(n, pool, native_lib, monkeypatch):
+    """Byte for byte, padded to the pile's bucket, on both sides of every
+    bucket edge and of LOCK_HELD_BUCKET; rejected rows inside the pile."""
+    from simple_pbft_tpu.crypto import tpu_verifier as tv
+
+    items = [pool[i % 32] for i in range(n)]
+    if n >= 7:
+        items[3] = BatchItem(items[3].pubkey[:31], items[3].msg, items[3].sig)
+        items[5] = _with_s(items[5], ref.L)
+        items[n - 1] = _with_r(items[n - 1], ref.P, 1)
+    size = tv._bucket_size(n)
+    got, want = _stage_both(items, monkeypatch)
+    _assert_same_staging(got, want, size)
+    reject = {3, 5, n - 1} if n >= 7 else set()
+    assert got.precheck.tolist() == [
+        i < n and i not in reject for i in range(size)
+    ]
+    assert not got.wire[n:].any() and not got.a_idx[n:].any()
+
+
+@pytest.mark.parametrize("mlen", [0, 1, 111, 112, 127, 128, 239, 240, 1000])
+def test_native_staging_equals_numpy_by_message_length(
+    mlen, pool, native_lib, monkeypatch
+):
+    """SHA-512's padding edges (R || A adds 64 bytes to every message):
+    the k column equals the oracle's challenge scalar as well."""
+    msgs = [bytes([i]) * mlen for i in range(3)] + [b"", b"q" * 300]
+    items = [_signed(1 + i % 4, m) for i, m in enumerate(msgs)]
+    got, want = _stage_both(items, monkeypatch)
+    _assert_same_staging(got, want, 8)
+    for row, it in zip(got.wire, items):
+        k = ref.challenge_scalar(it.sig[:32], it.pubkey, it.msg)
+        assert row[32:64].tobytes() == k.to_bytes(32, "little")
+        assert row[:32].tobytes() == it.sig[32:]
+        assert row[64:].tobytes() == it.sig[:32]
+    assert got.precheck.tolist() == [True] * 5 + [False] * 3
+
+
+@pytest.mark.parametrize(
+    "s, ok",
+    [(ref.L - 1, True), (ref.L, False), (ref.L + 1, False), (2**256 - 1, False)],
+    ids=["L-1", "L", "L+1", "2^256-1"],
+)
+def test_native_staging_rejects_s_ge_l(s, ok, pool, native_lib, monkeypatch):
+    items = [pool[0], _with_s(pool[1], s), pool[2]]
+    got, want = _stage_both(items, monkeypatch)
+    _assert_same_staging(got, want, 8)
+    assert got.precheck[:3].tolist() == [True, ok, True]
+
+
+@pytest.mark.parametrize("sign", [0, 1])
+@pytest.mark.parametrize(
+    "y, ok", [(ref.P - 1, True), (ref.P, False), (ref.P + 1, False)],
+    ids=["p-1", "p", "p+1"],
+)
+def test_native_staging_rejects_noncanonical_r(
+    y, ok, sign, pool, native_lib, monkeypatch
+):
+    """R.y >= p rejects whatever bit 255 (the sign of x) says."""
+    items = [_with_r(pool[0], y, sign), pool[1]]
+    got, want = _stage_both(items, monkeypatch)
+    _assert_same_staging(got, want, 8)
+    assert got.precheck[:2].tolist() == [ok, True]
+
+
+MALFORMED = {
+    "31-byte key": lambda it: BatchItem(it.pubkey[:31], it.msg, it.sig),
+    "63-byte signature": lambda it: BatchItem(it.pubkey, it.msg, it.sig[:63]),
+    "key not a curve point": lambda it: BatchItem(_not_a_point(), it.msg, it.sig),
+    "key over the bank's cap": lambda it: _signed(77, it.msg),
+}
+
+
+@pytest.mark.parametrize("kind", list(MALFORMED))
+def test_native_staging_masks_malformed_rows(kind, pool, native_lib, monkeypatch):
+    """A bank of two keys, both taken by the pile's first rows: the row is
+    masked on both paths, and only the over-cap key is handed to the CPU."""
+    from simple_pbft_tpu.crypto import tpu_verifier as tv
+
+    items = [pool[0], pool[1], MALFORMED[kind](pool[5]), pool[4]]
+    got, want = _stage_both(
+        items, monkeypatch,
+        bank=lambda: tv.KeyBank(initial_capacity=2, max_keys=2),
+    )
+    _assert_same_staging(got, want, 8)
+    assert got.precheck[:4].tolist() == [True, True, False, True]
+    assert got.fallback == ([2] if kind == "key over the bank's cap" else [])
+    assert got.a_idx[:4].tolist() == [
+        0, 1, 1 if kind == "63-byte signature" else 0, 0,
+    ]
+
+
+@pytest.mark.parametrize("n, size", [(1, 8), (130, 512), (600, 2048)])
+def test_lock_held_and_lock_released_calls_give_the_same_bytes(
+    n, size, pool, native_lib
+):
+    from simple_pbft_tpu.crypto.tpu_verifier import KeyBank
+
+    items = [pool[i % 32] for i in range(n)]
+    items[0] = _with_s(items[0], ref.L)
+    pub, sig, msgs, ok, _a_idx, _fb = KeyBank().lookup_pile(items, size)
+    held = native_lib.prepare_wire(pub, sig, msgs, ok, size, hold_lock=True)
+    released = native_lib.prepare_wire(pub, sig, msgs, ok, size, hold_lock=False)
+    assert held[0].tobytes() == released[0].tobytes()
+    assert held[1].tobytes() == released[1].tobytes()
+    assert held[1].sum() == n - 1
+
+
+@pytest.mark.parametrize("staged_by", ["native", "numpy"])
+def test_prep_counters_count(staged_by, pool, native_lib, monkeypatch):
+    """native_prep_items / fallback_prep_items count the items of finished
+    passes by who staged them, and ride shape_snapshot()."""
+    from simple_pbft_tpu.crypto import tpu_verifier as tv
+
+    if staged_by == "numpy":
+        monkeypatch.setattr(tv.native, "prepare_wire", lambda *a, **kw: None)
+    v = TpuVerifier()
+    assert v.verify_batch(pool[:5]) == [True] * 5
+    finish = v.dispatch_batch(pool[:3])
+    counted = (5, 0) if staged_by == "native" else (0, 5)
+    snap = v.shape_snapshot()
+    assert (snap["native_prep_items"], snap["fallback_prep_items"]) == counted
+    assert finish() == [True] * 3
+    counted = (8, 0) if staged_by == "native" else (0, 8)
+    snap = v.shape_snapshot()
+    assert (snap["native_prep_items"], snap["fallback_prep_items"]) == counted
+
+
+class _CountingLib:
+    """A loaded library whose every call is written down."""
+
+    def __init__(self, lib, calls: list, tag: str):
+        self._lib, self._calls, self._tag = lib, calls, tag
+
+    def __getattr__(self, name):
+        fn = getattr(self._lib, name)
+
+        def call(*args):
+            self._calls.append((self._tag, name))
+            return fn(*args)
+
+        return call
+
+
+def test_staging_is_one_native_call_and_no_numpy_loops(
+    pool, native_lib, monkeypatch
+):
+    """The finding, without a clock: a pile of 2,048 makes exactly one
+    call into the native library (through the handle that gives the
+    interpreter lock up) and none of the numpy steps that each gave it up
+    before; a pile of 128 goes through the lock-held handle."""
+    from simple_pbft_tpu.crypto import tpu_verifier as tv
+
+    bank = tv.KeyBank()
+    for it in pool[:4]:
+        bank.lookup(it.pubkey)  # table builds call the other libraries
+    calls: list = []
+
+    def counted(name, fn):
+        def call(*args, **kw):
+            calls.append(("numpy", name))
+            return fn(*args, **kw)
+
+        return call
+
+    monkeypatch.setattr(
+        native_lib, "_lib", _CountingLib(native_lib._lib, calls, "released"))
+    monkeypatch.setattr(
+        native_lib, "_lib_held", _CountingLib(native_lib._lib_held, calls, "held"))
+    monkeypatch.setattr(tv, "_ge_l_np", counted("_ge_l_np", tv._ge_l_np))
+    monkeypatch.setattr(tv, "_ge_p_np", counted("_ge_p_np", tv._ge_p_np))
+    monkeypatch.setattr(np, "concatenate", counted("concatenate", np.concatenate))
+    monkeypatch.setattr(np, "pad", counted("pad", np.pad))
+
+    big = tv.prepare_wire_batch([pool[i % 32] for i in range(2048)], bank, 2048)
+    assert calls == [("released", "prepare_wire")]
+    assert big.native and big.wire.shape == (2048, 96)
+    del calls[:]
+    small = tv.prepare_wire_batch([pool[i % 32] for i in range(128)], bank, 128)
+    assert calls == [("held", "prepare_wire")]
+    assert small.native and small.wire.shape == (128, 96)

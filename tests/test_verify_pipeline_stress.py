@@ -2,7 +2,7 @@
 next-round #9, SURVEY §5 sanitizers row).
 
 The replica runtime overlaps consecutive sweeps' signature verifies in
-separate executor threads, so KeyBank.lookup/lookup_many/device_tables
+separate executor threads, so KeyBank.lookup/lookup_pile/device_tables
 race: an unlocked check-then-append once could map one pubkey onto
 another's table row — every later signature from that key failing (or,
 adversarially, verifying against the wrong key). These tests hammer the
