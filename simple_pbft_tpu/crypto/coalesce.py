@@ -72,6 +72,16 @@ class VerifyService:
     # call to land coalesces harder for free.
     MIN_SECOND_DISPATCH = 256
     MAX_DEPTH = 2
+    # the round-trip estimate is sampled by device passes alone, so an
+    # estimate that routes every pile to the CPU is never sampled again:
+    # one pass stalled for 1.5 s put the EMA at 313 ms and the cutoff at
+    # 1,895 items, and n16-inflight8, whose piles are 130 items, never
+    # touched the device again (ISSUE 34: a traced run that latches
+    # before its profiler opens has no device plane and no result; an
+    # untraced one reads p50 85 ms for 73). With no pass finished for
+    # this long and none in flight, the next pile goes to the device
+    # whatever its size, and its round trip replaces the estimate.
+    ESTIMATE_STALE_S = 0.5
 
     def __init__(
         self,
@@ -137,6 +147,7 @@ class VerifyService:
         # the round trip chip_smoke.py measures on the co-located chip.
         self._rtt_ema = 0.030
         self._cpu_rate_ema = 25000.0
+        self._rtt_sampled = time.perf_counter()  # when a device pass last finished
         # observability (read by bench_consensus / ReplicaStats dumps)
         self.device_passes = 0
         self.device_pass_items = 0
@@ -151,6 +162,8 @@ class VerifyService:
         self.quarantine_probes = 0
         self.cpu_reroute_passes = 0
         self.cpu_reroute_items = 0
+        self._gate_cutoff = 0  # the cutoff _can_dispatch_locked last read
+        self.rtt_probes = 0  # small piles sent to the device to resample a stale estimate
         self.cpu_reroute_chunks = 0
         self.late_device_completions = 0
         # quarantine lifecycle as counters (telemetry plane): an ENTRY is
@@ -316,6 +329,7 @@ class VerifyService:
             "quarantine_entries": self.quarantine_entries,
             "quarantine_probes": self.quarantine_probes,
             "quarantine_recoveries": self.quarantine_recoveries,
+            "rtt_probes": self.rtt_probes,
             "cpu_reroute_passes": self.cpu_reroute_passes,
             "cpu_reroute_items": self.cpu_reroute_items,
             "cpu_reroute_chunks": self.cpu_reroute_chunks,
@@ -403,11 +417,14 @@ class VerifyService:
         paid a full device round trip. Small piles must never wait: the
         CPU path clears them in ~1 ms while the device absorbs the
         bulk."""
+        # read ONCE per decision: the dispatch loop routes the take by the
+        # value this gate admitted it under (_gate_cutoff)
+        self._gate_cutoff = self._cutoff()
         if not self._pending:
             return False
         if self.quarantined:
             return True  # everything drains on the CPU path right now
-        if self._pending_items <= self._cutoff():
+        if self._pending_items <= self._gate_cutoff:
             return True  # CPU path (or a free device slot) is immediate
         if self._inflight >= self.MAX_DEPTH:
             return False  # big pile, depth full: wait for a slot
@@ -441,10 +458,15 @@ class VerifyService:
                 # CPU in ~total/cpu_rate ms no matter what the device is
                 # doing; piles > cutoff (CPU time would exceed half an
                 # RTT) go to the device. The ADAPTIVE cutoff moves with
-                # the EMAs between the gate check and here, so for it the
-                # depth bound is re-asserted rather than assumed: a pile
-                # the gate admitted as small that now reads big must not
-                # become a depth-exceeding third device pass. A FIXED
+                # the EMAs (the completion thread writes them without this
+                # lock), so the route reads the value the gate admitted
+                # the pile under: re-reading it here sent a pile admitted
+                # as small, which a shorter round trip had just made
+                # "big", to the reroute thread as a depth-full big pile
+                # (88 items in a traced n64-inflight8, ISSUE 34:
+                # cpu_reroute_items counts a fallback that never was
+                # needed). The depth bound stays re-asserted for the
+                # adaptive cutoff rather than assumed. A FIXED
                 # cutoff never moves, so that clause must not apply — a
                 # device-only service (cpu_cutoff=0) draining its backlog
                 # at close() keeps its items off the CPU path, briefly
@@ -456,9 +478,25 @@ class VerifyService:
                 # backoff expires; the first post-backoff big pile is the
                 # probe that decides whether the device is back.
                 quarantined = self.quarantined
-                route_cpu = quarantined or total <= self._cutoff() or (
-                    self._fixed_cutoff is None
-                    and self._inflight >= self.MAX_DEPTH
+                # (close() skips the gate: its drain reads a fresh one)
+                cutoff = self._cutoff() if self._closed else self._gate_cutoff
+                # a small pile is the probe of an estimate gone stale
+                # (ESTIMATE_STALE_S); quarantine has its own ladder
+                probe = (
+                    total <= cutoff
+                    and self._fixed_cutoff is None
+                    and self._inflight == 0
+                    and not quarantined
+                    and time.perf_counter() - self._rtt_sampled
+                    > self.ESTIMATE_STALE_S
+                )
+                route_cpu = (
+                    quarantined
+                    or (total <= cutoff and not probe)
+                    or (
+                        self._fixed_cutoff is None
+                        and self._inflight >= self.MAX_DEPTH
+                    )
                 )
                 if not route_cpu:
                     if (
@@ -469,6 +507,7 @@ class VerifyService:
                         # device again: this dispatch is the re-probe
                         self.quarantine_probes += 1
                     self._inflight += 1
+                    self.rtt_probes += probe
             self.coalesced_submissions += len(subs)
             self.max_coalesced = max(self.max_coalesced, total)
             for wait_s, n in waits:
@@ -481,7 +520,7 @@ class VerifyService:
             # it whole — the chunked reroute works from `subs` directly,
             # so the big-pile case pays no O(total) copy in this loop
             if route_cpu:
-                if total > self._cutoff():
+                if total > cutoff:
                     # big pile forced onto the CPU (quarantine OR the
                     # adaptive depth-full clause): run it on its own
                     # thread so the dispatch loop keeps clearing small
@@ -525,6 +564,8 @@ class VerifyService:
                     # NEXT take's event inherits this take's queue wait
                     devledger.take_annotation()
                     self._fail(subs, e)
+                    # a device that raises is probed once a period too
+                    self._rtt_sampled = time.perf_counter()
                     with self._cond:
                         self._inflight -= 1
                         self._cond.notify_all()
@@ -565,9 +606,14 @@ class VerifyService:
                         verdicts = finisher()
             except BaseException as e:  # noqa: BLE001
                 self._fail(subs, e)
+                self._rtt_sampled = time.perf_counter()
             else:
-                rtt = time.perf_counter() - t0
-                self._rtt_ema = 0.8 * self._rtt_ema + 0.2 * rtt
+                now = time.perf_counter()
+                rtt = now - t0
+                # dispatched on a stale estimate: the sample replaces it
+                stale = t0 - self._rtt_sampled > self.ESTIMATE_STALE_S
+                self._rtt_ema = rtt if stale else 0.8 * self._rtt_ema + 0.2 * rtt
+                self._rtt_sampled = now
                 self.device_passes += 1
                 self.device_pass_items += total
                 # dispatch -> result RTT of one coalesced device pass

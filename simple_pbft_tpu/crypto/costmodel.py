@@ -4,8 +4,9 @@ For each jit shape (mode, window, bucket) of the device ledger, what the
 kernel draws is an analytic function of the geometry:
 
 - **table-row gathers**: ONE Niels row per window position per item
-  (the (s_nibble, k_nibble) pair indexes a joint table) — 64 rows of
-  256 B, 16,384 B an item.
+  (the (s_nibble, k_nibble) pair indexes a joint table), fetched as the
+  512 B table line that holds it and its neighbour (comb._gather_rows):
+  64 lines, 32,768 B an item.
 - **madds**: one mixed Edwards add per gathered row.
 - **host->device wire bytes**: what the staging path ships per item
   (S||k||R + key index + precheck, 101 B).
@@ -34,9 +35,9 @@ def shape_cost(mode: str, window: int, bucket: int) -> Dict[str, Any]:
     any other lane mode (the QC lane's ``pairing``) returns a
     zero-gather row so callers can sum blindly.
     """
-    row_bytes = comb.ROW * 4
+    row_bytes = comb.LINE * 4  # the line fetched for one row
     if mode == "fused":
-        gathers = comb.NPOS  # joint (s, k) window: one table row/pos
+        gathers = comb.NPOS  # joint (s, k) window: one table line/pos
         wire = 96 + 4 + 1  # S||k||R + a_idx + precheck per item
     else:
         gathers = wire = 0

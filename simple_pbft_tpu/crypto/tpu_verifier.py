@@ -107,13 +107,59 @@ def _bucket_size(n: int) -> int:
 # ---------------------------------------------------------------------------
 
 
+# One key's table on the device: comb.ROWS_PER_KEY Niels rows of 64 int32
+# words, 4 MiB.
+KEY_BYTES = comb.ROWS_PER_KEY * comb.ROW * 4
+# The flat table is indexed in int32: it stays under 2^31 elements (a
+# power-of-two capacity of 2,048 keys is exactly 2^31, and 8.6 GB).
+MAX_INDEXED_KEYS = (2**31 - 1) // (comb.ROWS_PER_KEY * comb.ROW)
+# Up to here a sized bank's capacity is the next power of two, as it
+# always was (128 keys for an n=64 committee with 8 clients, 64 for
+# n=16). Past it a power of two wastes up to half the table (1,096 keys
+# -> 2,048), so capacity goes up in granules of KEY_GRANULE keys (1,096
+# -> 1,152, 4.83 GB).
+POW2_KEYS = 512
+KEY_GRANULE = 128
+# The share of the device's memory (memory_stats()["bytes_limit"]) the
+# tables may take. Beside the table the six buckets' working set is
+# small (64 positions x 8,192 rows x 512 B = 268 MB gathered at the
+# largest bucket, 544 MB of temporaries in all), but a key registered
+# after the upload sends the whole table again, and the old copy lives
+# until the new one has landed: two tables and the working set have to
+# fit.
+TABLE_SHARE = 0.45
+# Where the platform reports no memory (the CPU): 2 GiB, 512 keys.
+DEFAULT_TABLE_BYTES = 2 << 30
+
+
+def bank_capacity(population: int, device_bytes: Optional[int]) -> int:
+    """Keys a bank holds for a published `population` (keys plus headroom)
+    on a device of `device_bytes` (None: the platform reports none). The
+    population rounded up (a power of two up to POW2_KEYS, KEY_GRANULE
+    past it), bounded by TABLE_SHARE of the device and by the int32
+    index. A population over the bound gets the bound: its over-cap keys
+    report UNCACHED and verify on the CPU."""
+    if population <= POW2_KEYS:
+        want = 1 << max(3, int(population - 1).bit_length())
+    else:
+        want = -(-population // KEY_GRANULE) * KEY_GRANULE
+    budget = (
+        DEFAULT_TABLE_BYTES if device_bytes is None
+        else int(device_bytes * TABLE_SHARE)
+    )
+    return max(1, min(want, budget // KEY_BYTES, MAX_INDEXED_KEYS))
+
+
 class KeyBank:
     """Cache of per-pubkey fused comb tables (the committee's key set).
 
     PBFT pubkeys are few and endlessly reused, so each is decompressed and
     expanded into Niels rows once on the host (exact bigints) and kept on
-    device. The bank's capacity grows in powers of two so kernel shapes
-    (and thus compiles) change only on committee growth.
+    device, KEY_BYTES a key. A verifier built for a published population
+    (TpuVerifier(initial_keys=...)) fixes the capacity at construction
+    (bank_capacity) and gives it as `max_keys` too, so the table's shape,
+    and with it the jit signature, never moves. Only an unsized bank
+    grows, in powers of two up to `max_keys`.
 
     `max_keys` bounds the bank: a Byzantine sender must not be able to
     grow device memory and force recompiles by spraying fresh valid curve
@@ -123,17 +169,20 @@ class KeyBank:
 
     UNCACHED = -2
 
-    # 2 GiB of device table memory at 4 MiB a key (comb.ROWS_PER_KEY
-    # rows of 64 int32 words). Chosen against the v5e chip: an n=256 committee +
-    # clients is 264 keys = 1.11 GB, and a 1 GB budget pushed exactly
-    # the CLIENT keys (registered after the replicas, signing every
-    # request — the bulk of the verify load) over the cap, onto the CPU
-    # fallback.
-    MAX_KEYS = 512
+    # An unsized bank's bound: DEFAULT_TABLE_BYTES of tables, which is
+    # also what a sized one may take where the platform reports no memory.
+    MAX_KEYS = DEFAULT_TABLE_BYTES // KEY_BYTES
 
     def __init__(self, initial_capacity: int = 8, max_keys: int = MAX_KEYS):
         self._index: Dict[bytes, int] = {}
         self._invalid_cache: set = set()
+        if max_keys > MAX_INDEXED_KEYS:
+            # here, and not in a gather: past 2^31 int32 elements the
+            # flat table's offsets no longer fit the kernel's indices
+            raise ValueError(
+                f"KeyBank of {max_keys} keys: the flat table would hold "
+                f"2^31 elements or more (at most {MAX_INDEXED_KEYS} keys)"
+            )
         self._max_keys = max_keys
         # clamp: capacity beyond max_keys would allocate (and upload)
         # table memory the lookup path refuses to ever use
@@ -141,6 +190,10 @@ class KeyBank:
         self._np = np.zeros((self._cap, comb.ROWS_PER_KEY, comb.ROW), np.int32)
         self._dev = None
         self._dirty = True
+        # whole-table host-to-device copies so far: one, in the warm, for
+        # a population registered before it (4.6 GB at 1,096 keys is
+        # seconds under the device lock)
+        self.uploads = 0
         # the replica pipeline verifies sweep k+1 in a second worker thread
         # while sweep k is in flight — bank mutation must be atomic or two
         # first-sighted pubkeys can race `len(self._index)` and share a
@@ -166,6 +219,11 @@ class KeyBank:
                 if len(self._invalid_cache) < 4096:  # bounded negative cache
                     self._invalid_cache.add(pubkey)
             return -1
+        with self._lock:
+            if len(self._index) >= self._max_keys:
+                # a full bank builds nothing: a key past the cap costs
+                # its decompression, not 4 MiB of table thrown away
+                return self.UNCACHED
         table = comb.fused_table_np(pt)
         with self._lock:
             idx = self._index.get(pubkey)
@@ -230,14 +288,19 @@ class KeyBank:
         a_idx = np.array(rows, dtype=np.int32)
         return b"".join(pubs), b"".join(sigs), msgs, ok, a_idx, fallback
 
+    def table_shape(self) -> "tuple[int, int]":
+        """The device table's shape at the current capacity: two Niels
+        rows a line (comb._gather_rows; the host array is the same
+        bytes)."""
+        return self._cap * comb.ROWS_PER_KEY // 2, comb.LINE
+
     def device_tables(self) -> jnp.ndarray:
-        """Flat (cap * comb.ROWS_PER_KEY, ROW) table on device."""
+        """The flat table on device, table_shape()."""
         with self._lock:
             if self._dirty or self._dev is None:
-                self._dev = jnp.asarray(
-                    self._np.reshape(self._cap * comb.ROWS_PER_KEY, comb.ROW)
-                )
+                self._dev = jnp.asarray(self._np.reshape(self.table_shape()))
                 self._dirty = False
+                self.uploads += 1
             return self._dev
 
 
@@ -330,6 +393,14 @@ class _CompileWatch:
         jax.monitoring.unregister_event_listener(self._on_event)
 
 
+def _device_bytes(mesh: Optional[jax.sharding.Mesh]) -> Optional[int]:
+    """What one device may hold, as its runtime reports it; None where it
+    reports nothing (the CPU). The tables replicate across a mesh, so one
+    device's limit is the limit."""
+    dev = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    return (dev.memory_stats() or {}).get("bytes_limit")
+
+
 # The one jitted kernel, shared by every unmeshed TpuVerifier. A
 # per-instance `jax.jit` wrapper would give each verifier its own compile
 # cache — an N-replica committee would then compile the same kernel N
@@ -373,11 +444,18 @@ class TpuVerifier:
         # replica in the process (measured: an n=16 committee spending
         # its entire 120 s client patience inside back-to-back compiles,
         # committing nothing). A PBFT deployment knows its key set up
-        # front — size the bank once and the shape never moves.
-        cap = 8
-        if initial_keys is not None:
-            cap = 1 << max(3, int(initial_keys - 1).bit_length())
-        self._bank = KeyBank(initial_capacity=cap)
+        # front — size the bank once and the shape never moves: the
+        # capacity bank_capacity gives (a power of two up to 512 keys,
+        # granules of 128 past it, bounded by the device's memory) is
+        # the bank's cap as well.
+        if initial_keys is None:
+            self._bank = KeyBank()
+        else:
+            cap = bank_capacity(initial_keys, _device_bytes(mesh))
+            self._bank = KeyBank(initial_capacity=cap, max_keys=cap)
+        # seconds the warm spent building the population's tables and
+        # putting them on the device
+        self.bank_build_s = 0.0
         self._cpu_fb = None  # lazy batched native verifier (over-cap keys)
         if mesh is not None:
             # shard_map, not a GSPMD-sharded jit: each device runs the
@@ -466,6 +544,10 @@ class TpuVerifier:
         # it where the library is absent
         self.native_prep_items = 0
         self.fallback_prep_items = 0
+        # summed over finished passes: the distinct table rows a pass's
+        # items name (72 at most at n=64 with 8 clients; one a client
+        # where a thousand sign)
+        self.pass_distinct_keys = 0
 
     @classmethod
     def for_population(
@@ -491,9 +573,10 @@ class TpuVerifier:
         """Register the key population and warm every batch bucket up
         to the one covering `max_sweep` items. Single-sourced bucket
         policy for node.py and the committee benches. Logs when the
-        population exceeds the bank budget — over-cap keys fall back to
-        the per-batch CPU path forever, which is safe but silently
-        forfeits the device for those signers."""
+        population exceeds the bank's capacity, which happens only
+        where bank_capacity's bound (the device's memory, the int32
+        index) lies under it: the keys past it verify on the batched CPU
+        path, which is safe but forfeits the device for those signers."""
         if len(pubkeys) > self._bank._max_keys:
             import logging
 
@@ -522,8 +605,18 @@ class TpuVerifier:
         process-wide (_SHARED_JIT), warming ONE verifier warms every
         replica in a simulated committee — provided they were built with
         the same initial_keys, so their table shapes match."""
-        for pk in pubkeys:
-            self._bank.lookup(pk)
+        from .. import spans
+
+        # one build and one upload, before the first pass asks for the
+        # tables under the device lock
+        t0 = time.perf_counter()
+        with spans.annotation(spans.VERIFY_BANK_BUILD):
+            for pk in pubkeys:
+                self._bank.lookup(pk)
+            self._bank.device_tables().block_until_ready()
+        build_s = time.perf_counter() - t0
+        self.bank_build_s += build_s
+        spans.record(spans.VERIFY_BANK_BUILD, build_s, n=len(pubkeys))
         # wrong-length pubkey: KeyBank.lookup_pile masks the row and the bank
         # rejects it without registering — an all-zero 32-byte key would
         # decompress to a valid (order-4) point and permanently occupy a
@@ -577,6 +670,13 @@ class TpuVerifier:
             "overcap_fallback_items": self.overcap_fallback_items,
             "native_prep_items": self.native_prep_items,
             "fallback_prep_items": self.fallback_prep_items,
+            "pass_distinct_keys": self.pass_distinct_keys,
+            # the key bank as the warm left it
+            "bank_keys": len(self._bank._index),
+            "bank_capacity": self._bank._cap,
+            "table_bytes": self._bank._np.nbytes,
+            "bank_uploads": self._bank.uploads,
+            "bank_build_s": round(self.bank_build_s, 3),
         }
 
     def lowered_text(self, size: int) -> str:
@@ -585,11 +685,10 @@ class TpuVerifier:
         reads it to show the Pallas accumulator went through Mosaic (a
         ``tpu_custom_call``) and was not interpreted."""
         struct = jax.ShapeDtypeStruct
-        rows = self._bank._cap * comb.ROWS_PER_KEY
         return self._fn.lower(
             struct((size, 96), jnp.uint8),
             struct((size,), jnp.int32),
-            struct((rows, comb.ROW), jnp.int32),
+            struct(self._bank.table_shape(), jnp.int32),
             struct((size,), jnp.bool_),
         ).as_text()
 
@@ -669,6 +768,9 @@ class TpuVerifier:
             dev_out = self._fn(*args)  # async: enqueue only
             self.device_calls += 1
             self.device_items += len(items)
+        # while the device works on the pass: the distinct table rows its
+        # items name, added to pass_distinct_keys where the pass ends
+        distinct = int(np.unique(prep.a_idx[: len(items)]).size)
 
         def finish() -> List[bool]:
             # np.array (copy): fallback rows below are written in place
@@ -687,6 +789,7 @@ class TpuVerifier:
                     self.native_prep_items += len(items)
                 else:
                     self.fallback_prep_items += len(items)
+                self.pass_distinct_keys += distinct
             # per-dispatch device ledger event (ISSUE 14): one row per
             # jit dispatch with the full cost tuple — the continuously-
             # measured form of the r05 hand decomposition

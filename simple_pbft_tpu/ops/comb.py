@@ -50,6 +50,7 @@ NPOS = 256 // WBITS  # comb positions covering 256-bit scalars: 64
 WINDOW = 1 << WBITS  # entries per scalar per position: 16
 ROWS_PER_KEY = NPOS * WINDOW * WINDOW  # one key's table: 16,384 rows
 ROW = 64  # Niels row: 3*17 int32 limbs + 13 pad to a 256B row
+LINE = 2 * ROW  # the device table's line: two rows, the TPU's 128 lanes
 
 # ---------------------------------------------------------------------------
 # Host-side table construction (exact Python bigints -> limb rows)
@@ -202,13 +203,28 @@ def _ident_like(batch_ref: jnp.ndarray) -> jnp.ndarray:
 def _gather_rows(flat_table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """One flat fetch of every position's row, staged position-major.
 
-    flat_table: (M, ROW). idx: (NPOS, B) row indices. -> (NPOS, ROW, B).
+    flat_table: (M/2, LINE), rows 2i and 2i+1 side by side on line i.
+    idx: (NPOS, B) row indices. -> (NPOS, ROW, B).
     A single big `take` keeps the gather dense (the per-position-in-loop
     form is ~20x slower on TPU); the transpose to batch-minor happens once
     here, not per position.
+
+    Why two rows a line. The TPU holds an (M, 64) int32 array
+    column-major (its compact layout: 64 words would pad to a 128-lane
+    line), and a row gather wants it row-major, so a program over that
+    shape first copies the WHOLE table into the padded row-major form: a
+    temporary of twice the table, written every pass (a third to two
+    thirds of a pass's device time at a few hundred MB; 9.7 GB beside a
+    4.8 GB table). Two rows fill the 128 lanes, the array is compact and
+    row-major at once, and the program fetches the line and keeps the
+    half it wants: twice the gathered bytes (268 MB at the largest
+    bucket), no copy.
     """
     npos, b = idx.shape
-    rows = jnp.take(flat_table, idx.reshape(-1), axis=0)  # (NPOS*B, ROW)
+    flat = idx.reshape(-1)
+    lines = jnp.take(flat_table, flat >> 1, axis=0)  # (NPOS*B, LINE)
+    odd = (flat & 1).astype(jnp.bool_)[:, None]
+    rows = jnp.where(odd, lines[:, ROW:], lines[:, :ROW])
     return rows.reshape(npos, b, ROW).transpose(0, 2, 1)
 
 
@@ -222,7 +238,7 @@ def fused_accumulate(
     mixed add per window position (NPOS = 64 in all).
 
     s_windows, k_windows: (NPOS, B) int32. row_base: (B,) int32 =
-    key_index * ROWS_PER_KEY. f_flat: (n_keys*ROWS_PER_KEY, ROW).
+    key_index * ROWS_PER_KEY. f_flat: (n_keys*ROWS_PER_KEY/2, LINE).
 
     The madd loop runs either as plain XLA (fori_loop) or as a Pallas
     kernel that keeps the accumulator and every field-mul intermediate in
@@ -384,7 +400,7 @@ def fused_verify_kernel(
     s_windows: jnp.ndarray,  # (NPOS, B) int32 — S scalar windows
     k_windows: jnp.ndarray,  # (NPOS, B) int32 — challenge scalar windows
     a_index: jnp.ndarray,  # (B,) int32 — key row into the fused table bank
-    f_table: jnp.ndarray,  # (n_keys*ROWS_PER_KEY, ROW) Niels rows
+    f_table: jnp.ndarray,  # (n_keys*ROWS_PER_KEY/2, LINE) Niels rows
     r_y: jnp.ndarray,  # (17, B) int32 — R's canonical y limbs
     r_sign: jnp.ndarray,  # (B,) int32 — R's x sign bit
     precheck: jnp.ndarray,  # (B,) bool — host-side validity mask
@@ -399,7 +415,7 @@ def fused_verify_kernel(
 def fused_verify_wire_kernel(
     wire: jnp.ndarray,  # (B, 96) uint8 — S (32) ‖ k (32) ‖ R (32) raw bytes
     a_index: jnp.ndarray,  # (B,) int32 — key row into the fused table bank
-    f_table: jnp.ndarray,  # (n_keys*ROWS_PER_KEY, ROW) Niels rows
+    f_table: jnp.ndarray,  # (n_keys*ROWS_PER_KEY/2, LINE) Niels rows
     precheck: jnp.ndarray,  # (B,) bool — host-side validity mask
 ) -> jnp.ndarray:
     """The verify kernel: RAW wire bytes in, one (B, 96) uint8 array per

@@ -18,6 +18,7 @@ no dict is built unless the span is exported or persisted. Stages:
   verify.device      device dispatch -> result RTT (one coalesced pass)
   verify.cpu         CPU small-batch pass
   verify.cpu_reroute CPU reroute chunk (quarantine / depth-full big pile)
+  verify.bank_build  the warm's key-table build and its one upload
   qc.queue           QcVerifyLane wait (cert submit -> batch start)
   qc.pairing         one RLC multi-pairing batch
   replica.verify_wait  a sweep's verify from the replica's seat (queue +
@@ -108,6 +109,7 @@ VERIFY_HOST_PREP = "verify.host_prep"
 VERIFY_DEVICE = "verify.device"
 VERIFY_CPU = "verify.cpu"
 VERIFY_REROUTE = "verify.cpu_reroute"
+VERIFY_BANK_BUILD = "verify.bank_build"
 QC_QUEUE = "qc.queue"
 QC_PAIRING = "qc.pairing"
 REPLICA_VERIFY_WAIT = "replica.verify_wait"

@@ -342,6 +342,96 @@ def test_big_cpu_reroute_does_not_serialize_small_sweeps():
     svc.close()
 
 
+def test_a_pile_admitted_as_small_is_routed_as_small():
+    """The adaptive cutoff moves under the dispatcher (the completion
+    thread writes its EMAs): a pile the gate admitted under one value is
+    routed by that value. Re-read, a cutoff that had just shrunk sent 88
+    items of a traced n64-inflight8 run to the reroute thread as a
+    depth-full big pile, and cpu_reroute_items, which the benchmark holds
+    to 0, counted a fallback that was never needed (ISSUE 34)."""
+    dev, cpu = FakeDevice(gate=True), FakeCpu()
+    svc = VerifyService(dev, cpu=cpu, cpu_cutoff=None)
+    reads = []
+
+    def cutoff():  # 100 to the gate, 10 to whoever reads after it
+        reads.append(10 if reads else 100)
+        return reads[-1]
+
+    svc._cutoff = lambda: 100
+    big = []
+    for t in range(VerifyService.MAX_DEPTH):  # every device slot taken, none finishes
+        big.append(svc.submit(_items(300, tag=bytes([t]))))
+        for _ in range(400):  # one pile a pass: the next waits for this take
+            if len(dev.batches) > t:
+                break
+            time.sleep(0.005)
+    assert dev.batches == [300, 300]
+    svc._cutoff = cutoff
+    small = svc.submit(_items(50, tag=b"s"))
+    assert small.result(10) == [True] * 50
+    assert cpu.batches == [50]
+    assert svc.cpu_reroute_items == 0 and svc.cpu_reroute_passes == 0
+    assert svc.snapshot()["cpu_pass_items"] == 50
+    dev.release()
+    assert [f.result(10) for f in big] == [[True] * 300] * 2
+    svc.close()
+
+
+def test_a_stale_round_trip_estimate_is_probed_and_replaced():
+    """Device passes alone sample the round trip, so an estimate that
+    sends every pile to the CPU is never sampled again: one pass stalled
+    for 1.5 s put it at 313 ms, the cutoff at 1,895 items, and a traced
+    n16-inflight8 (piles of 130) ended with no device plane (ISSUE 34).
+    Fresh, the high estimate routes the small pile to the CPU as before;
+    stale, with nothing in flight, the pile is the probe and its round
+    trip replaces the estimate; a pass in flight defers the probe."""
+    dev, cpu = FakeDevice(), FakeCpu()
+    svc = VerifyService(dev, cpu=cpu, cpu_cutoff=None)
+    svc._rtt_ema = 0.313
+    assert svc._cutoff() > 130
+    assert svc.verify_batch(_items(130, tag=b"a")) == [True] * 130
+    assert (cpu.batches, dev.batches, svc.rtt_probes) == ([130], [], 0)
+
+    svc._rtt_sampled -= 2 * VerifyService.ESTIMATE_STALE_S
+    assert svc.verify_batch(_items(130, tag=b"b")) == [True] * 130
+    assert (cpu.batches, dev.batches, svc.rtt_probes) == ([130], [130], 1)
+    assert svc.snapshot()["rtt_probes"] == 1
+    assert svc.rtt_ms < 100 and svc._cutoff() < 130  # replaced, not averaged
+    assert svc.verify_batch(_items(130, tag=b"c")) == [True] * 130
+    assert (dev.batches, svc.rtt_probes) == ([130, 130], 1)  # a plain pass
+    svc.close()
+
+    dev, cpu = FakeDevice(gate=True), FakeCpu()
+    svc = VerifyService(dev, cpu=cpu, cpu_cutoff=None)
+    svc._cutoff = lambda: 200
+    big = svc.submit(_items(300, tag=b"d"))
+    for _ in range(400):
+        if dev.batches:
+            break
+        time.sleep(0.005)
+    svc._rtt_sampled -= 2 * VerifyService.ESTIMATE_STALE_S
+    assert svc.submit(_items(130, tag=b"e")).result(10) == [True] * 130
+    assert (cpu.batches, dev.batches, svc.rtt_probes) == ([130], [300], 0)
+    dev.release()
+    assert big.result(10) == [True] * 300
+    svc.close()
+
+    class BoomDevice(FakeDevice):  # a device that raises is probed once a period, not every pile
+        def dispatch_batch(self, items):
+            self.batches.append(len(items))
+            raise RuntimeError("device gone")
+
+    dev, cpu = BoomDevice(), FakeCpu()
+    svc = VerifyService(dev, cpu=cpu, cpu_cutoff=None)
+    svc._cutoff = lambda: 200
+    svc._rtt_sampled -= 2 * VerifyService.ESTIMATE_STALE_S
+    with pytest.raises(RuntimeError, match="device gone"):
+        svc.verify_batch(_items(130, tag=b"f"))
+    assert svc.verify_batch(_items(130, tag=b"g")) == [True] * 130
+    assert (cpu.batches, dev.batches) == ([130], [130])
+    svc.close()
+
+
 def test_cpu_reroute_resolves_submissions_progressively():
     """Chunked reroute: submissions coalesced into one rerouted take
     resolve in order as their chunk completes — the first submitter

@@ -219,11 +219,12 @@ def test_per_device_events_normalize_occupancy(fresh_ledger):
 
 
 def test_costmodel_r05_anchor_points():
-    # the kernel: 64 joint-window gathers x 256 B rows = 16,384 B an item
+    # the kernel: 64 joint-window gathers x 512 B table lines (the row
+    # wanted and its neighbour) = 32,768 B an item
     c4 = costmodel.shape_cost("fused", 4, 8192)
     assert c4["gathers_per_item"] == 64
-    assert c4["gather_bytes_per_item"] == 16384
-    assert c4["gather_bytes_per_pass"] == 16384 * 8192
+    assert c4["gather_bytes_per_item"] == 32768
+    assert c4["gather_bytes_per_pass"] == 32768 * 8192
     assert c4["madds_per_item"] == 64
     # wire staging ships 101 B/item
     assert c4["wire_bytes_per_item"] == 101
@@ -363,6 +364,9 @@ def test_dev_cell_renders_and_blanks():
     }}}
     cell = pbft_top.dev_cell(snap)
     assert cell == "8.8/s 95% 4.1kv/s 12%"
+    # the key bank beside it, where the verifier reports one
+    snap["verify"]["device_shapes"] = {"bank_keys": 1064, "bank_capacity": 1152}
+    assert pbft_top.dev_cell(snap) == "8.8/s 95% 4.1kv/s 12% k1064/1152"
     assert pbft_top.dev_cell({"verify": {"device": {"dispatches": 0}}}) == ""
     assert pbft_top.dev_cell({}) == ""
     # the column is wired into the row renderer

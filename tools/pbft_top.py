@@ -117,19 +117,26 @@ def trace_cell(snap: dict) -> str:
 def dev_cell(snap: dict) -> str:
     """DEV: device-plane observatory aggregates (ISSUE 14) —
     ``disp/s occ% eff-verifies/s pad%`` from the verify service's
-    ``device`` ledger block. Works identically from a live scrape and
-    from a flight-file tail (the block rides every frame), so a wedged
-    node's last device posture is still one glance. Blank when the node
-    never dispatched to a device (CPU-verifier committees)."""
-    dev = (snap.get("verify") or {}).get("device") or {}
+    ``device`` ledger block, then ``k<keys>/<capacity>`` of the key bank
+    where ``device_shapes`` reports it (keys at the capacity: the next
+    walk-in key verifies on the CPU). Works identically from a live
+    scrape and from a flight-file tail (the block rides every frame), so
+    a wedged node's last device posture is still one glance. Blank when
+    the node never dispatched to a device (CPU-verifier committees)."""
+    verify = snap.get("verify") or {}
+    dev = verify.get("device") or {}
     if not dev.get("dispatches"):
         return ""
-    return (
+    cell = (
         f"{dev.get('dispatches_per_s', 0):.1f}/s "
         f"{dev.get('occupancy', 0) * 100:.0f}% "
         f"{_fmt_rate(dev.get('verifies_per_s_effective', 0))}v/s "
         f"{dev.get('pad_waste_pct', 0):.0f}%"
     )
+    shapes = verify.get("device_shapes") or {}
+    if shapes.get("bank_capacity"):
+        cell += f" k{shapes.get('bank_keys', 0)}/{shapes['bank_capacity']}"
+    return cell
 
 
 def spec_cell(snap: dict) -> str:
