@@ -615,6 +615,7 @@ class Replica:
         called ``spans`` shadows the telemetry module imported above
         (pbftlint PBL004 caught exactly that wart here)."""
         decoded: List[Message] = []
+        fast = 0
         for raw in sweep:
             try:
                 msg = Message.from_wire(raw)
@@ -622,6 +623,10 @@ class Replica:
                 self.metrics["malformed"] += 1
                 continue
             decoded.append(msg)
+            if "_payload" in msg.__dict__:
+                # the codec's fast path took the frame (messages.py): the
+                # signing payload came with the decode
+                fast += 1
             # vote-arrival capture for the trace plane's quorum-margin
             # statistics. Deliberately HERE — at decode, pre-verification
             # and pre-shed — because post-quorum straggler votes are
@@ -633,6 +638,7 @@ class Replica:
                 self.qstats.note_vote("prepare", msg.view, msg.seq, msg.sender)
             elif isinstance(msg, Commit):
                 self.qstats.note_vote("commit", msg.view, msg.seq, msg.sender)
+        self.metrics["frames_fast_decoded"] += fast
         decoded = self._shed_for_overload(decoded)
         self.stats.sweep_size.record(len(sweep))
         sig_spans: List[Tuple[int, int]] = []

@@ -16,14 +16,27 @@ VoteMsg{Prepare,Commit}, ReplyMsg; JSON wire format). Redesigned here:
 Canonical encoding = JSON with sorted keys and compact separators, bytes as
 lowercase hex. The signing payload is the canonical encoding with the ``sig``
 field blanked, so signatures are over a deterministic byte string.
+
+``from_wire`` has a fast path for flat kinds (every field an int or a str):
+a per-class matcher, derived from ``_field_specs()``, ``_AUTH_FIELDS`` and
+the layout ``canonical_json(to_dict())`` writes, accepts only a frame that is
+byte for byte the canonical encoding of the message it decodes to, so the
+frame with its authenticators cut out IS that message's signing payload.
+For every byte string it either declines (the generic path runs) or gives
+the type, the fields and the ``signing_payload()`` the generic path gives;
+change ``to_dict``, ``_AUTH_FIELDS`` or ``canonical_json`` and
+tests/test_fast_decode.py is what breaks if the matcher drifts.
 """
 
 from __future__ import annotations
 
 import hashlib
 import json
+import re
 from dataclasses import dataclass, field, fields
-from typing import Any, Callable, ClassVar, Dict, List, Optional, Tuple, Type
+from typing import (
+    Any, Callable, ClassVar, Dict, List, NamedTuple, Optional, Tuple, Type,
+)
 
 # ---------------------------------------------------------------------------
 # Canonical encoding helpers
@@ -283,6 +296,10 @@ class Message:
                 and b'"kind":"blockreply"' not in raw
             ):
                 raise ValueError("message too large for its type")
+        else:
+            msg = _fast_decode(raw)
+            if msg is not None:
+                return msg
         try:
             d = json.loads(raw)
         except (json.JSONDecodeError, UnicodeDecodeError, RecursionError) as e:
@@ -774,6 +791,99 @@ class NewViewFetch(Message):
 
 
 EMPTY_BLOCK_DIGEST = PrePrepare.block_digest([])
+
+# ---------------------------------------------------------------------------
+# Fast decode of flat kinds (the invariant is in the module docstring)
+# ---------------------------------------------------------------------------
+
+# A string as canonical_json writes it when nothing needs an escape:
+# printable ASCII but the quote and the backslash (ensure_ascii escapes
+# DEL and everything past it). An integer as it writes one, non-negative
+# and short enough that int() is exact on any build. Neither nests a
+# quantifier, so a match is linear in the frame.
+_FAST_STR = r'"([ !#-\[\]-~]*)"'
+_FAST_INT = r"(0|[1-9][0-9]{0,17})"
+
+
+class _FastEntry(NamedTuple):
+    """What the fast path holds for one flat class."""
+
+    cls: Type[Message]
+    matcher: Callable[[str], Optional["re.Match[str]"]]
+    names: Tuple[str, ...]  # the fields, in the matcher's group order
+    ints: Tuple[int, ...]  # indexes into names of the int fields
+    #: group numbers of the authenticators, in frame order; () where the
+    #: class has a signing payload of its own
+    auth: Tuple[int, ...]
+    defaults: Dict[str, Any]
+
+
+def _fast_entry(cls: Type[Message]) -> Optional[_FastEntry]:
+    """Derive a class's entry from its field specs; None for a class that
+    keeps the generic path."""
+    specs = {name: want for name, want, _elem in cls._field_specs()}
+    if (
+        any(want is None for want in specs.values())
+        or cls.MAX_WIRE_BYTES < Message.MAX_WIRE_BYTES
+    ):
+        return None
+    names = tuple(sorted(specs))
+    members = [
+        (name, _FAST_INT if specs[name] is int else _FAST_STR) for name in names
+    ]
+    members.append(("kind", '"%s"' % re.escape(cls.KIND)))
+    matcher = re.compile(
+        r"\{" + ",".join('"%s":%s' % kv for kv in sorted(members)) + r"\}"
+    ).fullmatch
+    auth: Tuple[int, ...] = ()
+    if cls.signing_payload is Message.signing_payload and all(
+        specs.get(f_) is str for f_ in cls._AUTH_FIELDS
+    ):
+        auth = tuple(
+            i + 1 for i, name in enumerate(names) if name in cls._AUTH_FIELDS
+        )
+    ints = tuple(i for i, name in enumerate(names) if specs[name] is int)
+    return _FastEntry(cls, matcher, names, ints, auth, cls._default_spec()[0])
+
+
+# by the kind as a frame's bytes spell it; fixed once the module is loaded
+_FAST_KINDS: Dict[bytes, _FastEntry] = {
+    kind.encode(): entry
+    for kind, cls in _REGISTRY.items()
+    if (entry := _fast_entry(cls)) is not None
+}
+
+
+def _fast_decode(raw: bytes) -> Optional[Message]:
+    """The message a frame in its kind's exact canonical layout decodes
+    to, its signing payload already cached; None for every other frame."""
+    at = raw.find(b'"kind":"')
+    if at < 0:
+        return None
+    entry = _FAST_KINDS.get(raw[at + 8 : raw.find(b'"', at + 8)])
+    if entry is None or not raw.isascii():
+        return None
+    cls, matcher, names, ints, auth, defaults = entry
+    m = matcher(raw.decode("ascii"))
+    if m is None:
+        return None
+    values = list(m.groups())
+    for i in ints:
+        values[i] = int(values[i])
+    msg = cls.__new__(cls)
+    od = msg.__dict__
+    od.update(defaults)  # the generic path's attribute order
+    od.update(zip(names, values))
+    if auth:
+        # ASCII: the match's offsets are the frame's
+        payload, pos = b"", 0
+        for group in auth:
+            start, end = m.span(group)
+            payload += raw[pos:start]
+            pos = end
+        od["_payload"] = payload + raw[pos:]
+    return msg
+
 
 ALL_KINDS = tuple(sorted(_REGISTRY))
 
