@@ -377,10 +377,17 @@ class VerifyService:
             target=self._complete_loop, name="verify-complete", daemon=True
         ).start()
 
+    def _ladder_seconds(self) -> float:
+        """What finished passes waited for their table-free rows after
+        the comb's verdicts had come (TpuVerifier.ladder_seconds); 0 for a
+        device that has no such program."""
+        return getattr(self._device, "ladder_seconds", 0.0)
+
     def _cutoff(self) -> int:
         """Largest batch the CPU path should take: the point where CPU
-        time ≈ half a device round trip. Clamped so a glitchy RTT sample
-        can neither starve the device nor flood the core."""
+        time ≈ half a device round trip, the round trip of a pass whose
+        keys all have tables (see _complete_loop). Clamped so a glitchy
+        RTT sample can neither starve the device nor flood the core."""
         if self._fixed_cutoff is not None:
             return self._fixed_cutoff
         c = int(self._cpu_rate_ema * self._rtt_ema * 0.5)
@@ -587,6 +594,7 @@ class VerifyService:
             # how long the CURRENT device pass has been in flight — the
             # number that names a silent device in a wedge autopsy
             self._finishing_t0 = t0
+            ladder_s0 = self._ladder_seconds()
             try:
                 if self._deadline is not None:
                     verdicts = self._finish_with_deadline(
@@ -610,9 +618,23 @@ class VerifyService:
             else:
                 now = time.perf_counter()
                 rtt = now - t0
+                # The estimate is of a pass's round trip as the CUTOFF
+                # means it: what a small pile would pay to go to the
+                # device at all. What this pass waited for its table-free
+                # rows beyond the comb's verdicts is work in proportion
+                # to those rows (a ladder row costs the device about what
+                # it costs a core), not part of that price: left in, a
+                # deployment with more signers than tables reads round
+                # trips of 50-100 ms, the cutoff rises to its clamp, and
+                # the piles that carry the ladder's rows go to the CPU.
+                # Finishers run on this thread one at a time, so the
+                # device's counter moved by this pass alone.
+                sample = max(0.0, rtt - (self._ladder_seconds() - ladder_s0))
                 # dispatched on a stale estimate: the sample replaces it
                 stale = t0 - self._rtt_sampled > self.ESTIMATE_STALE_S
-                self._rtt_ema = rtt if stale else 0.8 * self._rtt_ema + 0.2 * rtt
+                self._rtt_ema = (
+                    sample if stale else 0.8 * self._rtt_ema + 0.2 * sample
+                )
                 self._rtt_sampled = now
                 self.device_passes += 1
                 self.device_pass_items += total

@@ -26,6 +26,12 @@ those verifies would sit). This module fills that gap TPU-first:
   bounded; the verdict bitmap maps back per item, so one bad signature
   never poisons a quorum that still holds 2f+1 valid votes (SURVEY.md §7
   "Correct Byzantine semantics under batching").
+- A key the bank has no room for (more signers than the device's share of
+  tables) takes the table-free program (ops/ladder.py) in the same pass:
+  its rows go along with the key's 32 bytes, the device decompresses the
+  key and runs a windowed ladder, and one finisher merges the two
+  programs' verdicts. Which program a row takes follows from whether its
+  key has a table; a pile with no such row launches the comb alone.
 
 Verification equation (cofactorless, RFC 8032 permits): [S]B == R + [k]A,
 rearranged to [S]B + [k](−A) == R so the device computes a single
@@ -43,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import native
-from ..ops import comb
+from ..ops import comb, ladder
 from . import ed25519_cpu as ref
 from .verifier import BatchItem
 
@@ -138,7 +144,7 @@ def bank_capacity(population: int, device_bytes: Optional[int]) -> int:
     population rounded up (a power of two up to POW2_KEYS, KEY_GRANULE
     past it), bounded by TABLE_SHARE of the device and by the int32
     index. A population over the bound gets the bound: its over-cap keys
-    report UNCACHED and verify on the CPU."""
+    report UNCACHED and verify on the device by the table-free ladder."""
     if population <= POW2_KEYS:
         want = 1 << max(3, int(population - 1).bit_length())
     else:
@@ -164,7 +170,9 @@ class KeyBank:
     `max_keys` bounds the bank: a Byzantine sender must not be able to
     grow device memory and force recompiles by spraying fresh valid curve
     points through the Verifier seam. Keys beyond the cap report UNCACHED
-    and are verified on the CPU fallback path.
+    and cost the host nothing further: no decompression, no state. Their
+    rows take the table-free ladder (ops/ladder.py), which decompresses
+    the key on the device.
     """
 
     UNCACHED = -2
@@ -201,15 +209,21 @@ class KeyBank:
         self._lock = threading.Lock()
 
     def lookup(self, pubkey: bytes) -> int:
-        """-> table row for pubkey, -1 if the key is invalid (bad length /
-        not a curve point), or UNCACHED if the bank is full. Builds and
-        caches the table on miss. Thread-safe."""
+        """-> table row for pubkey, -1 if the key is invalid (bad length;
+        not a curve point, where the bank had room to find out), or
+        UNCACHED if the bank is full. Builds and caches the table on miss.
+        Thread-safe."""
         with self._lock:
             idx = self._index.get(pubkey)
             if idx is not None:
                 return idx
             if len(pubkey) != 32 or pubkey in self._invalid_cache:
                 return -1
+            if len(self._index) >= self._max_keys:
+                # a full bank builds nothing and decompresses nothing: a
+                # key past the cap costs a dict miss, whoever sends it
+                # (the device decides whether it is a curve point)
+                return self.UNCACHED
         # table construction runs outside the lock, re-checking on
         # re-entry (native C++ builds ~11 ms/key — a cold n=64 bank is
         # ~0.7 s; the pure-Python bigint fallback is ~0.2 s/key)
@@ -219,11 +233,6 @@ class KeyBank:
                 if len(self._invalid_cache) < 4096:  # bounded negative cache
                     self._invalid_cache.add(pubkey)
             return -1
-        with self._lock:
-            if len(self._index) >= self._max_keys:
-                # a full bank builds nothing: a key past the cap costs
-                # its decompression, not 4 MiB of table thrown away
-                return self.UNCACHED
         table = comb.fused_table_np(pt)
         with self._lock:
             idx = self._index.get(pubkey)
@@ -243,50 +252,55 @@ class KeyBank:
             return idx
 
     def lookup_pile(self, items: Sequence[BatchItem], size: int):
-        """One pass over a pile: -> (pub, sig, msgs, ok, a_idx, fallback).
+        """One pass over a pile: -> (pub, sig, msgs, ok, a_idx, uncached).
 
         `pub` and `sig` are the items' keys (32 bytes a row) and
         signatures (64) joined, `msgs` their messages, `ok` one byte a row
-        (0 = malformed lengths, or a key that is no curve point or is over
-        the cap; such a row's key and signature are zeroed), `a_idx` the
-        (size,) int32 table rows padded to the bucket in its one
-        allocation, `fallback` the positions whose key is valid but over
-        the cap. Plain Python under one lock acquisition, so the
+        (0 = malformed lengths, whose key and signature are zeroed, or a
+        key known to be no curve point), `a_idx` the (size,) int32 table
+        rows padded to the bucket in its one allocation, `uncached` the
+        positions of well-formed rows whose key has no table (the bank is
+        full): `ok` stays 1 there, so the row is staged in full for the
+        ladder. Plain Python under one lock acquisition, so the
         interpreter lock is never given up: no numpy call here loops over
-        the rows. Misses take the slow build path."""
+        the rows. A miss takes lookup(), which builds a table only while
+        the bank has room."""
         n = len(items)
         pubs: List[bytes] = []
         sigs: List[bytes] = []
         msgs: List[bytes] = []
         rows = [0] * size
-        bad: List[int] = []  # rows that miss the bank or carry a wrong length
+        uncached: List[int] = []
+        bad: List[int] = []  # rows that miss a bank with room, or carry a wrong length
         with self._lock:
             row_of = self._index.get
+            full = len(self._index) >= self._max_keys
             for i, it in enumerate(items):
                 pk, sg = it.pubkey, it.sig
                 idx = row_of(pk)
-                if idx is None or len(sg) != 64:
-                    bad.append(i)
-                else:
+                if idx is not None and len(sg) == 64:
                     rows[i] = idx
+                elif idx is None and full and len(pk) == 32 and len(sg) == 64:
+                    uncached.append(i)  # what lookup() would answer
+                else:
+                    bad.append(i)
                 pubs.append(pk)
                 sigs.append(sg)
                 msgs.append(it.msg)
         ok = bytearray(b"\x01") * n
-        fallback: List[int] = []
         for i in bad:
             idx = self.lookup(pubs[i])
             if idx >= 0:
                 rows[i] = idx
-            elif idx == KeyBank.UNCACHED:
-                fallback.append(i)
             if len(pubs[i]) != 32 or len(sigs[i]) != 64:
                 pubs[i], sigs[i] = _ZERO32, _ZERO64
                 ok[i] = 0
+            elif idx == KeyBank.UNCACHED:  # the bank filled meanwhile
+                uncached.append(i)
             elif idx < 0:
                 ok[i] = 0
         a_idx = np.array(rows, dtype=np.int32)
-        return b"".join(pubs), b"".join(sigs), msgs, ok, a_idx, fallback
+        return b"".join(pubs), b"".join(sigs), msgs, ok, a_idx, uncached
 
     def table_shape(self) -> "tuple[int, int]":
         """The device table's shape at the current capacity: two Niels
@@ -304,17 +318,27 @@ class KeyBank:
             return self._dev
 
 
+class LadderBatch(NamedTuple):
+    """The rows of a pile whose key has no table, staged for the
+    table-free program (ops/ladder.ladder_verify_wire_kernel) and padded
+    to a bucket of their own."""
+
+    rows: np.ndarray  # (n,) positions in the pile
+    wire: np.ndarray  # (size, 128) uint8: S ‖ k ‖ R ‖ A per row
+    precheck: np.ndarray  # (size,) bool
+
+
 class WireBatch(NamedTuple):
     """Raw-bytes staging for the kernel, padded to its bucket: one
     (size, 96) uint8 array (S ‖ k ‖ R per row) plus key rows and the
-    precheck mask (pad rows carry precheck=False). Window extraction,
-    limb decomposition and the sign bit happen on the device
-    (ops/comb.fused_verify_wire_kernel)."""
+    precheck mask (pad rows carry precheck=False, and so do the rows
+    that `ladder` took). Window extraction, limb decomposition and the
+    sign bit happen on the device (ops/comb.fused_verify_wire_kernel)."""
 
     wire: np.ndarray  # (size, 96) uint8
     a_idx: np.ndarray  # (size,) int32
     precheck: np.ndarray  # (size,) bool
-    fallback: List[int]  # positions the caller verifies on the CPU path
+    ladder: Optional[LadderBatch]  # None: every key of the pile has a table
     native: bool  # staged by native.prepare_wire, not by numpy
 
 
@@ -335,21 +359,45 @@ def _stage_numpy(pub: bytes, sig: bytes, msgs: Sequence[bytes],
     return np.pad(wire, ((0, pad), (0, 0))), np.pad(precheck, (0, pad))
 
 
+def _ladder_batch(
+    pub: bytes, wire: np.ndarray, precheck: np.ndarray,
+    uncached: List[int], align: int,
+) -> LadderBatch:
+    """Copy the uncached rows out of a staged pile, each with its key's
+    32 bytes behind it, and mask them in the pile: the comb then answers
+    False there, and the finisher writes the ladder's verdicts over it.
+    One native call that keeps the interpreter lock (native.ladder_rows),
+    or its numpy stand-in where the library is absent."""
+    n = len(uncached)
+    size = _bucket_size(max(n, align))
+    staged = native.ladder_rows(wire, pub, precheck, uncached, size)
+    if staged is not None:
+        return LadderBatch(*staged)
+    rows = np.array(uncached, dtype=np.int64)
+    out = np.zeros((size, ladder.ROW_BYTES), dtype=np.uint8)
+    out[:n, :96] = wire[rows]
+    out[:n, 96:] = np.frombuffer(pub, dtype=np.uint8).reshape(-1, 32)[rows]
+    pre = np.zeros(size, dtype=np.bool_)
+    pre[:n] = precheck[rows]
+    precheck[rows] = False
+    return LadderBatch(rows, out, pre)
+
+
 def prepare_wire_batch(
-    items: Sequence[BatchItem], bank: KeyBank, size: int
+    items: Sequence[BatchItem], bank: KeyBank, size: int, align: int = 1
 ) -> WireBatch:
     """Wire bytes -> WireBatch padded to the bucket `size`, registering
-    pubkeys in `bank`.
+    pubkeys in `bank` while it has room.
 
-    `fallback` lists item positions whose pubkey is valid but over the
-    bank's cap — the caller must verify those on the CPU path (their
-    device rows are masked out). Host work is one Python pass over the
-    items (the byte joins and the bank's dict lookup) and one native call
-    for the challenge hash, the canonicality reject policy (S >= L
-    malleability, non-canonical R.y) and the padding — no window/limb
-    unpacking. The pile's size alone decides how that call is made
-    (LOCK_HELD_BUCKET)."""
-    pub, sig, msgs, ok, a_idx, fallback = bank.lookup_pile(items, size)
+    Rows whose key has no table (the bank is full) come back a second
+    time in `ladder`, with their keys' bytes, padded to their own bucket
+    (a multiple of `align`), and masked in the pile. Host work is one
+    Python pass over the items (the byte joins and the bank's dict
+    lookup) and one native call for the challenge hash, the canonicality
+    reject policy (S >= L malleability, non-canonical R.y) and the
+    padding — no window/limb unpacking. The pile's size alone decides
+    how that call is made (LOCK_HELD_BUCKET)."""
+    pub, sig, msgs, ok, a_idx, uncached = bank.lookup_pile(items, size)
     staged = native.prepare_wire(
         pub, sig, msgs, ok, size, hold_lock=size <= LOCK_HELD_BUCKET
     )
@@ -357,7 +405,11 @@ def prepare_wire_batch(
         staged if staged is not None
         else _stage_numpy(pub, sig, msgs, ok, size)
     )
-    return WireBatch(wire, a_idx, precheck, fallback, staged is not None)
+    lad = (
+        _ladder_batch(pub, wire, precheck, uncached, align)
+        if uncached else None
+    )
+    return WireBatch(wire, a_idx, precheck, lad, staged is not None)
 
 
 # One device pass at a time, process-wide. The replica runtime calls
@@ -407,14 +459,18 @@ def _device_bytes(mesh: Optional[jax.sharding.Mesh]) -> Optional[int]:
 # times per bucket size (minutes of wasted wall clock, and a practical
 # deadlock on single-core CI hosts).
 _SHARED_JIT = jax.jit(comb.fused_verify_wire_kernel)
+# and the table-free program, for rows whose key has no table
+_SHARED_LADDER_JIT = jax.jit(ladder.ladder_verify_wire_kernel)
 
 
 class TpuVerifier:
     """The `tpu` backend behind the crypto.Verifier seam.
 
-    One kernel, the fused comb (ops/comb.py): cached per-pubkey
-    dual-scalar tables, zero doublings, no on-device decompression, one
-    madd per nibble position, batch-amortized inversion.
+    The fused comb (ops/comb.py) for every key with a table: cached
+    per-pubkey dual-scalar tables, zero doublings, no on-device
+    decompression, one madd per nibble position, batch-amortized
+    inversion. The ladder (ops/ladder.py) for the keys the bank has no
+    room for, in the same pass.
 
     Pads drained batches to bucketed sizes, runs one jitted device pass per
     chunk, and returns the per-item bitmap. Pass a `jax.sharding.Mesh` via
@@ -456,7 +512,9 @@ class TpuVerifier:
         # seconds the warm spent building the population's tables and
         # putting them on the device
         self.bank_build_s = 0.0
-        self._cpu_fb = None  # lazy batched native verifier (over-cap keys)
+        # lazy batched native verifier: uncached rows of a verifier that
+        # was warmed WITHOUT the ladder (see _dispatch_chunk)
+        self._cpu_fb = None
         if mesh is not None:
             # shard_map, not a GSPMD-sharded jit: each device runs the
             # kernel on its LOCAL batch shard, so the Pallas Mosaic
@@ -482,17 +540,19 @@ class TpuVerifier:
             # mismatched varying manual axes"). The body has no
             # collectives and every output is per-shard, so the
             # check has nothing to protect.
-            self._fn = jax.jit(
-                shard_map(
-                    comb.fused_verify_wire_kernel,
-                    mesh=mesh,
-                    in_specs=(
-                        PS(axis, None), PS(axis), PS(None, None),
-                        PS(axis),
-                    ),
-                    out_specs=PS(axis),
-                    check_vma=False,
-                )
+            def sharded(kernel, in_specs):
+                return jax.jit(shard_map(
+                    kernel, mesh=mesh, in_specs=in_specs,
+                    out_specs=PS(axis), check_vma=False,
+                ))
+
+            self._fn = sharded(
+                comb.fused_verify_wire_kernel,
+                (PS(axis, None), PS(axis), PS(None, None), PS(axis)),
+            )
+            # the ladder's rows split the same way: (wire (B,128), precheck)
+            self._ladder_fn = sharded(
+                ladder.ladder_verify_wire_kernel, (PS(axis, None), PS(axis))
             )
             self._align = int(np.prod(mesh.devices.shape))
             if self._align & (self._align - 1):
@@ -506,6 +566,7 @@ class TpuVerifier:
                 )
         else:
             self._fn = _SHARED_JIT
+            self._ladder_fn = _SHARED_LADDER_JIT
             self._align = 1
         # Device-side accounting, owned by the verifier: seconds are
         # measured INSIDE the device lock by the holder, so they are
@@ -536,9 +597,18 @@ class TpuVerifier:
         # (compile or cache load included) and the persistent cache's
         # part in it — see _CompileWatch
         self.warm_log: List[dict] = []
-        # items answered by the over-cap CPU fallback instead of the
-        # device (keys beyond the bank's max_keys)
+        # items answered by the CPU instead of the device because their
+        # key had no table and no ladder bucket was warmed for them: 0
+        # wherever the published population was given to the warm
         self.overcap_fallback_items = 0
+        # items of finished passes that took the table-free ladder, the
+        # passes that launched it, and the seconds those passes waited
+        # for its verdicts AFTER the comb's had come: what the pass would
+        # not have cost had every key had a table (VerifyService takes it
+        # out of its round-trip estimate)
+        self.ladder_items = 0
+        self.ladder_passes = 0
+        self.ladder_seconds = 0.0
         # items of finished passes by who staged them: the native library's
         # one call (prepare_wire) or the numpy staging that stands in for
         # it where the library is absent
@@ -572,21 +642,28 @@ class TpuVerifier:
     ) -> None:
         """Register the key population and warm every batch bucket up
         to the one covering `max_sweep` items. Single-sourced bucket
-        policy for node.py and the committee benches. Logs when the
-        population exceeds the bank's capacity, which happens only
-        where bank_capacity's bound (the device's memory, the int32
-        index) lies under it: the keys past it verify on the batched CPU
-        path, which is safe but forfeits the device for those signers."""
+        policy for node.py and the committee benches. Where the
+        population exceeds the bank's capacity, which happens only where
+        bank_capacity's bound (the device's memory, the int32 index) lies
+        under it, the keys past it have no table: the same buckets of
+        the table-free ladder are warmed for them, and logged. A
+        population that fits warms the comb alone."""
+        top = _bucket_size(max(1, min(max_sweep, BUCKETS[-1])))
+        buckets = [b for b in BUCKETS if b <= top]
+        self.warm(pubkeys=pubkeys, buckets=buckets)
         if len(pubkeys) > self._bank._max_keys:
             import logging
 
             logging.warning(
                 "TpuVerifier bank clamped: %d published keys > max_keys=%d; "
-                "over-cap keys verify on the CPU fallback path",
-                len(pubkeys), self._bank._max_keys,
+                "over-cap keys verify on the device by the table-free "
+                "ladder, warmed at buckets %s",
+                len(pubkeys), self._bank._max_keys, buckets,
             )
-        top = _bucket_size(max(1, min(max_sweep, BUCKETS[-1])))
-        self.warm(pubkeys=pubkeys, buckets=[b for b in BUCKETS if b <= top])
+            # a well-formed key that no one holds: the bank is full, so
+            # it reports UNCACHED and every row takes the ladder
+            self._warm_buckets(
+                buckets, BatchItem(bytes(32), b"", bytes(64)), "ladder")
         # the shape set is now closed: any later first-time signature is
         # a mid-run compile — counted in post_warm_compiles and surfaced
         # through the telemetry plane (the r5 qc256 suspect made visible)
@@ -621,28 +698,33 @@ class TpuVerifier:
         # rejects it without registering — an all-zero 32-byte key would
         # decompress to a valid (order-4) point and permanently occupy a
         # bank slot, skewing the very capacity this warmup pins
-        dummy = BatchItem(bytes(31), b"", bytes(64))
+        self._warm_buckets(buckets, BatchItem(bytes(31), b"", bytes(64)), "comb")
+
+    def _warm_buckets(
+        self, buckets: Sequence[int], dummy: BatchItem, program: str
+    ) -> None:
+        """One throwaway pass of `dummy` rows a bucket, through the same
+        call path traffic takes, and its row in warm_log."""
         for b in buckets:
             t0 = time.perf_counter()
             with _CompileWatch() as watch:
                 self.verify_batch([dummy] * b)
             self.warm_log.append({
                 "bucket": b,
+                "program": program,
                 "seconds": round(time.perf_counter() - t0, 3),
                 "compile_requests": watch.requests,
                 "cache_hits": watch.hits,
             })
 
-    def _record_shape(self, size: int) -> bool:
+    def _record_shape(self, sig: tuple) -> bool:
         """Track the jit signature this dispatch hits. Must run AFTER
         host prep (bank lookups can grow the table capacity, which is
-        part of the signature) and records under the bank lock's
+        part of the comb's signature) and records under the bank lock's
         protection being unnecessary: GIL-atomic set/dict ops, and the
         counters are observability, not control flow. Returns whether
         the signature is FRESH (this dispatch traces and compiles) —
         the device ledger's compile-vs-cache column."""
-        sig = (self._mode, self._window, size, self._bank._cap)
-        self.bucket_hits[size] = self.bucket_hits.get(size, 0) + 1
         fresh = sig not in self.shape_signatures
         if fresh:
             self.shape_signatures.add(sig)
@@ -668,6 +750,8 @@ class TpuVerifier:
             "post_warm_compiles": self.post_warm_compiles,
             "bucket_hits": {str(k): v for k, v in sorted(self.bucket_hits.items())},
             "overcap_fallback_items": self.overcap_fallback_items,
+            "ladder_items": self.ladder_items,
+            "ladder_passes": self.ladder_passes,
             "native_prep_items": self.native_prep_items,
             "fallback_prep_items": self.fallback_prep_items,
             "pass_distinct_keys": self.pass_distinct_keys,
@@ -740,13 +824,28 @@ class TpuVerifier:
         # planes show the prep beside the device's modules
         with spans.annotation(spans.VERIFY_HOST_PREP):
             size = _bucket_size(max(len(items), self._align))
-            prep = prepare_wire_batch(items, self._bank, size)
-            fallback = prep.fallback
+            prep = prepare_wire_batch(items, self._bank, size, self._align)
             args = (
                 prep.wire, prep.a_idx, self._bank.device_tables(),
                 prep.precheck,
             )
-            compile_fresh = self._record_shape(size)
+            self.bucket_hits[size] = self.bucket_hits.get(size, 0) + 1
+            compile_fresh = self._record_shape(
+                (self._mode, self._window, size, self._bank._cap))
+            lad = prep.ladder
+            cpu_rows = None
+            lad_fresh = False
+            if lad is not None:
+                lad_sig = ("ladder", self._window, len(lad.precheck))
+                if self._warm_done and lad_sig not in self.shape_signatures:
+                    # the warm was given a population that fits the bank,
+                    # so it compiled no ladder bucket, and this key walked
+                    # in after the bank filled: nothing compiles under
+                    # traffic, the rows keep the batched CPU route (their
+                    # comb rows are masked either way)
+                    cpu_rows, lad = lad.rows, None
+                else:
+                    lad_fresh = self._record_shape(lad_sig)
         # host-side prep (byte joins, challenge hashes, padding) is CPU
         # work on the dispatcher's thread — if it rivals the
         # device RTT the pipeline is host-bound, and only a span can say
@@ -763,9 +862,15 @@ class TpuVerifier:
         # (the coalescing dispatcher sets it on this thread; direct
         # callers default to zero wait / one submission)
         queue_wait_s, submissions = annotation
+        lad_out = None
         with _DEVICE_LOCK:
             t0 = time.perf_counter()
             dev_out = self._fn(*args)  # async: enqueue only
+            if lad is not None:
+                # behind the comb on the device's queue, under the same
+                # hold of the lock: one pass, two programs
+                with spans.annotation(spans.VERIFY_LADDER):
+                    lad_out = self._ladder_fn(lad.wire, lad.precheck)
             self.device_calls += 1
             self.device_items += len(items)
         # while the device works on the pass: the distinct table rows its
@@ -773,9 +878,19 @@ class TpuVerifier:
         distinct = int(np.unique(prep.a_idx[: len(items)]).size)
 
         def finish() -> List[bool]:
-            # np.array (copy): fallback rows below are written in place
+            # np.array (copy): the other program's rows are written in place
             verdict = np.array(dev_out)  # blocks until the device answers
-            rtt = time.perf_counter() - t0
+            t_comb = time.perf_counter()
+            n_lad = 0
+            if lad_out is not None:
+                n_lad = len(lad.rows)
+                with spans.annotation(spans.VERIFY_LADDER):
+                    verdict[lad.rows] = np.asarray(lad_out)[:n_lad]
+            t_end = time.perf_counter()
+            rtt = t_end - t0
+            # what the pass waited for the ladder after the comb's
+            # verdicts had come
+            lad_wait = t_end - t_comb
             # dispatch->result wall time. Overlapped calls each count
             # their full span, so the sum can exceed wall clock under
             # pipelining — device_seconds is a latency integral, not an
@@ -790,40 +905,52 @@ class TpuVerifier:
                 else:
                     self.fallback_prep_items += len(items)
                 self.pass_distinct_keys += distinct
+                if n_lad:
+                    self.ladder_items += n_lad
+                    self.ladder_passes += 1
+                    self.ladder_seconds += lad_wait
             # per-dispatch device ledger event (ISSUE 14): one row per
             # jit dispatch with the full cost tuple — the continuously-
-            # measured form of the r05 hand decomposition
+            # measured form of the r05 hand decomposition. A pass that
+            # launched both programs leaves two rows that add up to it:
+            # the comb's has the rows it answered (the ladder's are pad
+            # to it) and the time to its own verdicts, the ladder's its
+            # own rows and the wait from there to the pass's end
             devledger.record(
                 devledger.LANE_ED25519, self._mode, self._window, size,
-                len(items), host_prep_s=prep_s, rtt_s=rtt,
+                len(items) - n_lad, host_prep_s=prep_s, rtt_s=t_comb - t0,
                 compile_fresh=compile_fresh, bytes_up=bytes_up,
                 bytes_down=size, queue_wait_s=queue_wait_s,
                 submissions=submissions,
             )
-            if fallback:
+            if n_lad:
+                # dispatch to result of the ladder program, a round trip
+                # like verify.device (which goes on covering the pass)
+                spans.record(spans.VERIFY_LADDER, rtt, n=n_lad)
+                devledger.record(
+                    devledger.LANE_ED25519, "ladder", self._window,
+                    len(lad.precheck), n_lad, rtt_s=lad_wait,
+                    compile_fresh=lad_fresh,
+                    bytes_up=lad.wire.nbytes + lad.precheck.nbytes,
+                    bytes_down=len(lad.precheck), queue_wait_s=0.0,
+                    submissions=0,
+                )
+            if cpu_rows is not None:
                 if self._cpu_fb is None:
                     from .verifier import kernel_equivalent_cpu_verifier
 
                     # kernel-EQUIVALENT only (native batched Ed25519,
-                    # else the RFC 8032 oracle — never OpenSSL): the
-                    # fallback rows share a verdict bitmap with kernel
-                    # rows, so the two accept/reject sets must agree on
-                    # every edge vector (non-canonical R/S, off-curve
-                    # points) or a crafted signature splits the pile
-                    # (ADVICE r5; agreement pinned by
-                    # test_overbank_fallback_agrees_with_kernel)
+                    # else the RFC 8032 oracle — never OpenSSL): these
+                    # rows share a verdict bitmap with kernel rows, so
+                    # the two accept/reject sets must agree on every edge
+                    # vector (non-canonical R/S, off-curve points) or a
+                    # crafted signature splits the pile (ADVICE r5)
                     self._cpu_fb = kernel_equivalent_cpu_verifier()
-                # keys over the bank cap: ONE batched native-CPU pass,
-                # not a scalar loop — at n=256 the over-cap keys were
-                # the clients', i.e. most of the pile, and the
-                # pure-Python per-item path turned one coalesced batch
-                # into a ~75 s stall (round-5 chip record, qc256 attempt 1)
-                fb_out = self._cpu_fb.verify_batch(
-                    [items[i] for i in fallback]
+                # ONE batched native-CPU pass, not a scalar loop
+                verdict[cpu_rows] = self._cpu_fb.verify_batch(
+                    [items[i] for i in cpu_rows]
                 )
-                self.overcap_fallback_items += len(fallback)
-                for i, ok_i in zip(fallback, fb_out):
-                    verdict[i] = ok_i
+                self.overcap_fallback_items += len(cpu_rows)
             return verdict[: len(items)].tolist()
 
         return finish

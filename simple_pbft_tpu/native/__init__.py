@@ -155,6 +155,10 @@ def _configure_hostprep(lib):
             ctypes.c_int, _u8p, _u8p,
         ]
         handle.prepare_wire.restype = None
+    _lib_held.ladder_rows.argtypes = [
+        _u8p, _u8p, _u8p, _i64p, ctypes.c_int64, ctypes.c_int64, _u8p, _u8p,
+    ]
+    _lib_held.ladder_rows.restype = None
     return lib
 
 
@@ -470,6 +474,30 @@ def prepare_wire(
         wire, precheck.view(np.uint8),
     )
     return wire, precheck
+
+
+def ladder_rows(
+    wire: np.ndarray, pub: bytes, precheck: np.ndarray,
+    rows: Sequence[int], size: int,
+) -> "Optional[tuple[np.ndarray, np.ndarray, np.ndarray]]":
+    """The rows of a staged pile (prepare_wire's `wire` and `precheck`,
+    `pub` the keys it was staged from) whose key has no table, copied out
+    for the table-free program: -> (rows as an int64 array, out (size, 128)
+    uint8 of S || k || R || A rows, out_pre (size,) bool), padded with
+    zeros, and `precheck` cleared at `rows`; None when the library is
+    absent. Memory copies under the interpreter lock, whatever the size:
+    the numpy equivalent is four loops over more than 500 elements, each
+    of which gives the lock up and queues for it behind the event loop."""
+    if _load() is None:
+        return None
+    idx = np.array(rows, dtype=np.int64)
+    out = np.empty((size, 128), dtype=np.uint8)
+    out_pre = np.empty(size, dtype=np.bool_)
+    _lib_held.ladder_rows(
+        wire, np.frombuffer(pub, np.uint8), precheck.view(np.uint8), idx,
+        len(idx), size, out, out_pre.view(np.uint8),
+    )
+    return idx, out, out_pre
 
 
 def sc_reduce_batch(digests: np.ndarray) -> np.ndarray:

@@ -21,6 +21,8 @@
 //     flat numpy buffers (no per-item allocation).
 //   - prepare_wire: a pile's joined bytes -> the staged (size, 96) rows and
 //     the precheck mask the kernel takes, padding included.
+//   - ladder_rows: the staged rows whose key has no table, copied out with
+//     their keys for the table-free program.
 //
 // The reference implements none of this (it has no signatures at all —
 // /root/reference/utils/utils.go:13-17 is its entire crypto surface); this
@@ -356,6 +358,28 @@ void prepare_wire(const uint8_t* pub, const uint8_t* sig, const uint8_t* msgs,
   if (size > n) {
     std::memset(wire + 96 * n, 0, (size_t)(96 * (size - n)));
     std::memset(precheck + n, 0, (size_t)(size - n));
+  }
+}
+
+// The rows of a staged pile whose key has no table on the device, copied
+// out for the table-free verify program (ops/ladder.py): out row i is wire
+// row rows[i] (S || k || R, 96 bytes) with that row's key (32 bytes) behind
+// it, out_pre[i] its precheck, and precheck[rows[i]] is cleared, so that
+// the comb answers false there. Rows n..size of out and out_pre are zeroed.
+// Memory copies only: the caller keeps the interpreter lock across it.
+void ladder_rows(const uint8_t* wire, const uint8_t* pub, uint8_t* precheck,
+                 const int64_t* rows, int64_t n, int64_t size, uint8_t* out,
+                 uint8_t* out_pre) {
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t r = rows[i];
+    std::memcpy(out + 128 * i, wire + 96 * r, 96);
+    std::memcpy(out + 128 * i + 96, pub + 32 * r, 32);
+    out_pre[i] = precheck[r];
+    precheck[r] = 0;
+  }
+  if (size > n) {
+    std::memset(out + 128 * n, 0, (size_t)(128 * (size - n)));
+    std::memset(out_pre + n, 0, (size_t)(size - n));
   }
 }
 

@@ -3,17 +3,17 @@
 Points are int32 arrays of shape (4, 17, ...): stacked (X, Y, Z, T) limb
 vectors with x = X/Z, y = Y/Z, T = XY/Z. Like the field layer
 (ops/field25519.py), the limb axis leads and batch axes trail so the batch
-fills the 128-wide vector lanes — the layout that makes the fixed ladder
-VPU-dense instead of HBM-bound. The stacked layout keeps constant-shape
-table selection (jnp.where over a (k, 4, 17, ...) table) trivial — the
-design constraint is XLA: no data-dependent control flow, every verify is
-the same fixed ladder.
+fills the 128-wide vector lanes. The design constraint is XLA: no
+data-dependent control flow, every row runs the same fixed sequence.
 
 Formulas: unified add-2008-hwcd-3 and dbl-2008-hwcd (same formulas the CPU
 oracle in crypto/ed25519_cpu.py uses, so both planes agree bit-for-bit).
 
-The double-scalar ladder computes [s]B + [k]Q in one 256-iteration
-interleaved (Straus) pass: shared doublings, one table add per bit pair.
+Who runs what: the table-free verify program (ops/ladder.py) calls
+`decompress` on every row's key, and its windowed ladder is these
+formulas with a group operation's independent multiplies stacked into one
+call; `point_add`, `point_double` and `compress` are the plain forms the
+tests hold that ladder (and the comb's mixed add) to.
 """
 
 from __future__ import annotations
@@ -22,7 +22,6 @@ from typing import Tuple
 
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from . import field25519 as fe
 from ..crypto import ed25519_cpu as ref
@@ -40,13 +39,6 @@ def _point_const(p: Tuple[int, int, int, int]) -> np.ndarray:
 
 
 IDENTITY = _point_const(ref.IDENTITY)  # (4, 17)
-BASE = _point_const(ref.B)
-
-
-def _pconst(c: np.ndarray, like: jnp.ndarray) -> jnp.ndarray:
-    """(4, 17) point constant -> broadcastable against (4, 17, ...)."""
-    return jnp.asarray(c).reshape((4, fe.NLIMB) + (1,) * (like.ndim - 2))
-
 
 # -- coordinate accessors ---------------------------------------------------
 
@@ -94,44 +86,6 @@ def point_neg(p: jnp.ndarray) -> jnp.ndarray:
     """-(x, y) = (-x, y); T = xy negates too."""
     x, y, z, t = _unpack(p)
     return _pack(fe.neg(x), y, z, fe.neg(t))
-
-
-def point_select(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-    """table[idx] with constant shape: table (k, 4, 17, ...), idx (...,).
-    A where-chain (not gather) so XLA vectorizes it across the batch."""
-    k = table.shape[0]
-    out = table[0]
-    for i in range(1, k):
-        out = jnp.where((idx == i)[None, None], table[i], out)
-    return out
-
-
-# -- scalar multiplication --------------------------------------------------
-
-
-def double_scalar_mul_base(
-    s_bits: jnp.ndarray, k_bits: jnp.ndarray, q: jnp.ndarray
-) -> jnp.ndarray:
-    """[s]B + [k]Q via interleaved Straus ladder.
-
-    s_bits, k_bits: (256, ...) int32 bits, MSB first. q: (4, 17, ...).
-    One shared doubling per bit; the per-bit addend is selected from the
-    4-entry table {identity, B, Q, B+Q} by the bit pair. 256 uniform
-    iterations — constant shape, no data-dependent control flow.
-    """
-    base = jnp.broadcast_to(_pconst(BASE, q), q.shape)
-    # derive from q (not broadcast a constant) so the loop carry inherits
-    # q's varying manual axes under shard_map
-    ident = q * 0 + _pconst(IDENTITY, q)
-    table = jnp.stack([ident, base, q, point_add(base, q)], axis=0)
-
-    def body(i, acc):
-        acc = point_double(acc)
-        idx = s_bits[i] + 2 * k_bits[i]
-        addend = point_select(idx, table)
-        return point_add(acc, addend)
-
-    return lax.fori_loop(0, 256, body, ident)
 
 
 # -- compression / decompression -------------------------------------------

@@ -16,6 +16,8 @@ no dict is built unless the span is exported or persisted. Stages:
   verify.queue       VerifyService admission-queue wait (submit -> take)
   verify.host_prep   TpuVerifier host-side batch prep before dispatch
   verify.device      device dispatch -> result RTT (one coalesced pass)
+  verify.ladder      the same, of a pass's table-free program alone (the
+                     rows whose key has no table); n = those rows
   verify.cpu         CPU small-batch pass
   verify.cpu_reroute CPU reroute chunk (quarantine / depth-full big pile)
   verify.bank_build  the warm's key-table build and its one upload
@@ -107,6 +109,7 @@ log = logging.getLogger("pbft.spans")
 VERIFY_QUEUE = "verify.queue"
 VERIFY_HOST_PREP = "verify.host_prep"
 VERIFY_DEVICE = "verify.device"
+VERIFY_LADDER = "verify.ladder"
 VERIFY_CPU = "verify.cpu"
 VERIFY_REROUTE = "verify.cpu_reroute"
 VERIFY_BANK_BUILD = "verify.bank_build"

@@ -432,6 +432,61 @@ def test_a_stale_round_trip_estimate_is_probed_and_replaced():
     svc.close()
 
 
+class LadderHeavyDevice(FakeDevice):
+    """A device whose passes wait `ladder_wait` seconds for table-free
+    rows after the comb's verdicts, and say so as TpuVerifier does: on the
+    monotonic counter `ladder_seconds`, in the finisher."""
+
+    def __init__(self, comb_wait: float, ladder_wait: float):
+        super().__init__()
+        self.ladder_seconds = 0.0
+        self._waits = (comb_wait, ladder_wait)
+
+    def dispatch_batch(self, items):
+        inner = super().dispatch_batch(items)
+
+        def finish():
+            time.sleep(sum(self._waits))
+            self.ladder_seconds += self._waits[1]
+            return inner()
+
+        return finish
+
+
+def test_ladder_heavy_passes_keep_piles_over_the_cutoff_on_the_device():
+    """A deployment with more signers than tables (ISSUE 36): its passes
+    take 60 ms where the comb alone would take 5, because 4 rows of 10
+    run the ladder. The estimate the cutoff is made of leaves out what a
+    pass waited for the ladder, so the cutoff stays where the comb puts it
+    and a pile of a few hundred items still goes to the device; with the
+    wait left in (a device that does not report it) the same traffic puts
+    the cutoff over the pile and sends it to the CPU."""
+    def drive(dev):
+        cpu = FakeCpu()
+        svc = VerifyService(dev, cpu=cpu, cpu_cutoff=None)
+        svc._cpu_rate_ema = 25000.0
+        svc._run_cpu = lambda batch, subs, stage=None: (
+            cpu.batches.append(len(batch)),
+            svc._resolve(subs, [it.sig == it.msg for it in batch]))
+        for i in range(12):  # big piles: the EMA converges on their round trip
+            assert svc.verify_batch(_items(1500, tag=b"p%d" % i)) == [True] * 1500
+        cutoff = svc._cutoff()
+        assert svc.verify_batch(_items(400, tag=b"q")) == [True] * 400
+        svc.close()
+        return cutoff, svc.rtt_ms, cpu.batches, dev.batches
+
+    cutoff, rtt_ms, on_cpu, on_dev = drive(LadderHeavyDevice(0.005, 0.055))
+    assert rtt_ms < 25 and cutoff < 400
+    assert (on_cpu, on_dev) == ([], [1500] * 12 + [400])
+
+    class Unreported(LadderHeavyDevice):
+        ladder_seconds = property(lambda self: 0.0, lambda self, v: None)
+
+    cutoff, rtt_ms, on_cpu, on_dev = drive(Unreported(0.005, 0.055))
+    assert rtt_ms > 45 and cutoff > 400
+    assert (on_cpu, on_dev) == ([400], [1500] * 12)
+
+
 def test_cpu_reroute_resolves_submissions_progressively():
     """Chunked reroute: submissions coalesced into one rerouted take
     resolve in order as their chunk completes — the first submitter

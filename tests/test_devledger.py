@@ -234,6 +234,27 @@ def test_costmodel_r05_anchor_points():
     assert pairing["madds_per_item"] == pairing["wire_bytes_per_item"] == 0
 
 
+def test_costmodel_ladder_row():
+    """The table-free program's shape key: no key-table gather, the key's
+    32 bytes on the wire, ops/ladder.py's own count of field multiplies,
+    7-8 times the comb's 64 mixed adds of 7 and its ending."""
+    from simple_pbft_tpu.ops import ladder
+
+    assert costmodel.parse_shape_key("ed25519:ladder/w4/b2048") == {
+        "lane": "ed25519", "mode": "ladder", "window": 4, "bucket": 2048}
+    lad = costmodel.shape_cost("ladder", 4, 2048)
+    assert lad["gathers_per_item"] == lad["gather_bytes_per_pass"] == 0
+    assert lad["wire_bytes_per_item"] == 128 + 1
+    assert lad["madds_per_item"] == 128  # one add a scalar a window
+    assert lad["flops_per_item"] == ladder.FIELD_MULS * costmodel.MUL_INT_OPS
+    assert ladder.FIELD_MULS == 278 + 129 + 64 * (4 * 8 + 8 + 7) + 5 == 3420
+    assert 7 < ladder.FIELD_MULS / (64 * 7 + 5) < 8
+    # a run's shapes block with both programs sums the comb's gathers only
+    shapes = {"ed25519:fused/w4/b2048": {"dispatches": 3, "items": 3000},
+              "ed25519:ladder/w4/b512": {"dispatches": 3, "items": 1290}}
+    assert costmodel.gather_bytes_for_shapes(shapes) == 3 * 2048 * 32768
+
+
 def test_costmodel_shapes_rollup():
     shapes = {
         "ed25519:fused/w4/b8": {"dispatches": 2, "items": 10,
@@ -367,6 +388,11 @@ def test_dev_cell_renders_and_blanks():
     # the key bank beside it, where the verifier reports one
     snap["verify"]["device_shapes"] = {"bank_keys": 1064, "bank_capacity": 1152}
     assert pbft_top.dev_cell(snap) == "8.8/s 95% 4.1kv/s 12% k1064/1152"
+    # and the share of verified rows that took the table-free ladder
+    snap["verify"]["device_shapes"] = {
+        "bank_keys": 1814, "bank_capacity": 1814, "ladder_items": 430,
+        "native_prep_items": 1000, "fallback_prep_items": 0}
+    assert pbft_top.dev_cell(snap) == "8.8/s 95% 4.1kv/s 12% k1814/1814 L43%"
     assert pbft_top.dev_cell({"verify": {"device": {"dispatches": 0}}}) == ""
     assert pbft_top.dev_cell({}) == ""
     # the column is wired into the row renderer

@@ -178,24 +178,71 @@ class TestPointOps:
         assert affine(ed.point_add(pt(p_ref), ed.point_neg(pt(p_ref)))) == (0, 1)
 
     def test_double_scalar_mul(self):
-        # batched (trailing dim 3): one compile covers all cases
+        """ops/ladder.py's windowed Straus ladder, [s]B + [k]Q, against
+        bigint scalar multiplication: random scalars, and the windows'
+        edges (0, 15 in every window, the top window alone)."""
+        from simple_pbft_tpu.ops import comb, ladder
+
         qs = []
-        for _ in range(3):
+        for s, k in [(None, None), (None, None), (0, 0), (2**256 - 1, 1),
+                     (1, 2**256 - 1), (15 << 252, 15 << 252), (ref.L - 1, 0),
+                     (0, ref.L - 1)]:
             q_ref, _ = self.rand_point()
-            qs.append((q_ref, rng.randrange(ref.L), rng.randrange(ref.L)))
-        q_arr = pt_batch([q for q, _, _ in qs])
-        s_bits = jnp.asarray(
-            [[(s >> (255 - i)) & 1 for _, s, _ in qs] for i in range(256)],
-            dtype=jnp.int32,
-        )  # (256, 3) — bit axis leading
-        k_bits = jnp.asarray(
-            [[(k >> (255 - i)) & 1 for _, _, k in qs] for i in range(256)],
-            dtype=jnp.int32,
-        )
-        got = jax.jit(ed.double_scalar_mul_base)(s_bits, k_bits, q_arr)
+            qs.append((q_ref,
+                       rng.randrange(ref.L) if s is None else s,
+                       rng.randrange(ref.L) if k is None else k))
+
+        def windows(vals):
+            data = np.stack([np.frombuffer(v.to_bytes(32, "little"), np.uint8)
+                             for v in vals])
+            return jnp.asarray(fe.extract_windows_np(data, ladder.WBITS, ladder.NPOS))
+
+        # Q's affine Niels rows, as the comb's tables hold a point
+        q_niels = jnp.asarray(comb._batch_affine_niels_np(
+            [q for q, _, _ in qs])[:, : 3 * fe.NLIMB].T)
+        got = jax.jit(ladder.double_scalar_mul_base)(
+            windows([s for _, s, _ in qs]), windows([k for _, _, k in qs]),
+            q_niels)
+        assert got.shape == (4, fe.NLIMB, len(qs))
         for i, (q_ref, s, k) in enumerate(qs):
             want = ref.point_add(ref.point_mul(s, ref.B), ref.point_mul(k, q_ref))
             assert affine(got[:, :, i]) == ref.point_to_affine(want)
+            t = unlimbs(fe.to_canonical(got[3, :, i]))
+            x, y, z = (unlimbs(fe.to_canonical(got[c, :, i])) for c in range(3))
+            assert (t * z - x * y) % P == 0  # T = XY/Z survives the ladder
+
+    def test_stacked_group_law_is_the_plain_one(self):
+        """ladder._double, _add_cached and _madd (a group operation's
+        multiplies stacked into one call) against edwards.point_double,
+        point_add and the oracle, identity and a point's own negative
+        included."""
+        from simple_pbft_tpu.ops import comb, ladder
+
+        pts = [self.rand_point()[0] for _ in range(3)] + [ref.IDENTITY]
+        qts = [self.rand_point()[0], ref.IDENTITY, None, pts[0]]
+        x, y, z, t = pts[2]
+        qts[2] = ((-x) % P, y, z, (-t) % P)
+        p_arr, q_arr = pt_batch(pts), pt_batch(qts)
+        stacked = jnp.moveaxis(p_arr, 0, 1)  # (17, 4, n)
+
+        def unstack(a):
+            return jnp.moveaxis(a, 1, 0)
+
+        dbl = unstack(jax.jit(ladder._double)(stacked))
+        add = unstack(jax.jit(
+            lambda p, q: ladder._add_cached(p, ladder._cached(q))
+        )(stacked, jnp.moveaxis(q_arr, 0, 1)))
+        niels = jnp.asarray(
+            comb._batch_affine_niels_np(qts)[:, : 3 * fe.NLIMB].T)
+        madd = unstack(jax.jit(ladder._madd)(stacked, niels))
+        plain_dbl = jax.jit(ed.point_double)(p_arr)
+        plain_add = jax.jit(ed.point_add)(p_arr, q_arr)
+        for i, (p_ref, q_ref) in enumerate(zip(pts, qts)):
+            assert affine(dbl[:, :, i]) == affine(plain_dbl[:, :, i]) \
+                == ref.point_to_affine(ref.point_double(p_ref))
+            want = ref.point_to_affine(ref.point_add(p_ref, q_ref))
+            assert affine(add[:, :, i]) == affine(plain_add[:, :, i]) == want
+            assert affine(madd[:, :, i]) == want
 
     def test_compress_decompress_roundtrip(self):
         pts = [self.rand_point()[0] for _ in range(4)]

@@ -160,7 +160,11 @@ def test_clamped_bank_warns_and_holds_what_fits(device, population):
     assert bank.device_tables().shape == bank.table_shape()
     assert bank._np.nbytes == bank.device_tables().nbytes
     assert any("bank clamped: 16 published keys > max_keys=12" in w
+               and "table-free ladder, warmed at buckets [8, 32, 128]" in w
                for w in device.warnings)
+    # the comb's three buckets, then the ladder's same three
+    assert [(r["program"], r["bucket"]) for r in device.warm_log] == [
+        (p, b) for p in ("comb", "ladder") for b in (8, 32, 128)]
     for s in population[:CAP]:
         assert bank.lookup(s.pub) >= 0
     for s in population[CAP:]:
@@ -210,9 +214,10 @@ def test_the_gather_keeps_the_wanted_half_of_each_line():
     assert np.array_equal(got, rows[idx].transpose(0, 2, 1))
 
 
-def test_over_cap_keys_fall_back_and_agree_with_the_oracle(device, population):
+def test_over_cap_keys_take_the_ladder_and_agree_with_the_oracle(device, population):
     """What a deployment larger than the device's share gets: the keys
-    past the cap verify on the CPU, with the kernel's verdicts."""
+    past the cap verify on the device by the table-free program, with
+    the comb's verdicts, at a bucket the warm compiled."""
     items = [_signed(s, b"late %d" % i) for i, s in enumerate(population[CAP - 4:])]
     bad = bytearray(items[-1].sig)
     bad[5] ^= 4
@@ -222,8 +227,78 @@ def test_over_cap_keys_fall_back_and_agree_with_the_oracle(device, population):
     before = device.shape_snapshot()
     assert device.verify_batch(items) == oracle
     after = device.shape_snapshot()
-    assert after["overcap_fallback_items"] - before["overcap_fallback_items"] == 4
+    assert after["ladder_items"] - before["ladder_items"] == 4
+    assert after["ladder_passes"] - before["ladder_passes"] == 1
+    assert after["overcap_fallback_items"] == 0
     assert after["bank_uploads"] == 1 and after["post_warm_compiles"] == 0
+
+
+def test_a_mixed_pile_launches_both_programs_and_counts_the_uncached_rows(
+        device, population):
+    """A pile over banked and unbanked keys with the benchmark's seven
+    planted failures on either side of the bank's edge: one pass, two
+    programs, verdicts equal RFC 8032's item for item, the counters count
+    the rows the ladder answered, the device ledger holds a row a program
+    (which add up to the pass), and nothing compiled or went to the CPU."""
+    from simple_pbft_tpu import devledger
+    from simple_pbft_tpu.crypto import costmodel
+
+    rng = random.Random(36)
+    items = [_signed(population[i % len(population)], b"mixed %d" % i)
+             for i in range(40)]
+    planted = _plant_seven(items, rng, population[0])
+    oracle = [ref.verify(it.pubkey, it.msg, it.sig) for it in items]
+    assert [i for i, ok in enumerate(oracle) if not ok] == planted
+    banked = set(device._bank._index)
+    uncached = [i for i, it in enumerate(items)
+                if it.pubkey not in banked
+                and len(it.pubkey) == 32 and len(it.sig) == 64]
+    assert 8 <= len(uncached) <= 16  # c8-c11's rows, and the key that is no point
+
+    devledger.configure("mixed-pile", enabled=True)
+    try:
+        before = device.shape_snapshot()
+        assert device.verify_batch(items) == oracle
+        after = device.shape_snapshot()
+        rows = [r for r in devledger.recent() if r["lane"] == "ed25519"]
+        shapes = devledger.snapshot()["shapes"]
+    finally:
+        devledger.configure("")  # a fresh window for whoever comes next
+    assert after["ladder_items"] - before["ladder_items"] == len(uncached)
+    assert after["ladder_passes"] - before["ladder_passes"] == 1
+    assert after["overcap_fallback_items"] == after["post_warm_compiles"] == 0
+    assert after["bank_uploads"] == 1
+    assert [(r["mode"], r["bucket"], r["n"]) for r in rows] == [
+        ("fused", 128, 40 - len(uncached)), ("ladder", 32, len(uncached))]
+    assert not any(r["compile"] for r in rows)
+    assert set(shapes) == {"ed25519:fused/w4/b128", "ed25519:ladder/w4/b32"}
+    lad = costmodel.parse_shape_key("ed25519:ladder/w4/b32")
+    assert lad == {"lane": "ed25519", "mode": "ladder", "window": 4, "bucket": 32}
+    cost = costmodel.shape_cost(lad["mode"], lad["window"], lad["bucket"])
+    fused = costmodel.shape_cost("fused", 4, 128)
+    assert cost["gather_bytes_per_item"] == 0 and cost["wire_bytes_per_item"] == 129
+    assert 6 < cost["flops_per_item"] / fused["flops_per_item"] < 10
+    assert costmodel.gather_bytes_for_shapes(shapes) == fused["gather_bytes_per_pass"]
+
+
+def test_a_population_that_fits_warms_no_ladder_bucket(monkeypatch):
+    """The three standing configurations: the warm compiles the comb's
+    buckets and nothing else, and every pass launches one program."""
+    monkeypatch.setattr(tv, "_device_bytes", lambda mesh: None)
+    signers = [Signer(f"fit{i}", bytes([200 + i]) * 32) for i in range(6)]
+    v = tv.TpuVerifier(initial_keys=len(signers) + 2)
+    monkeypatch.setattr(v, "_ladder_fn", lambda *a: pytest.fail(
+        "the ladder was launched for a population that fits its bank"))
+    v.warm_for_population([s.pub for s in signers], max_sweep=32)
+    assert [(r["program"], r["bucket"]) for r in v.warm_log] == [
+        ("comb", 8), ("comb", 32)]
+    assert {sig[0] for sig in v.shape_signatures} == {"fused"}
+    items = [_signed(s, b"fits %d" % i) for i, s in enumerate(signers * 3)]
+    assert v.verify_batch(items) == [True] * 18
+    snap = v.shape_snapshot()
+    assert snap["ladder_items"] == snap["ladder_passes"] == 0
+    assert snap["post_warm_compiles"] == snap["overcap_fallback_items"] == 0
+    assert v.ladder_seconds == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -240,7 +315,7 @@ def test_one_request_clients_against_the_reference(
     (groups of one stay a Reply). Eight clients are all in the 12-key
     bank; of forty, c8-c39 are past it (44 keys' tables are 176 MiB), a
     deployment larger than the device's share: their signatures verify on
-    the CPU and nothing else moves. Either way the warm's upload stays the
+    the device by the table-free ladder and nothing else moves. Either way the warm's upload stays the
     only one. Counters are read over the whole run: how much of it falls
     in the two-second window depends on what else the machine runs."""
     from simple_pbft_tpu.committee import LocalCommittee
@@ -297,8 +372,10 @@ def test_one_request_clients_against_the_reference(
     after = snap["device_shapes"]
     assert after["bank_uploads"] == 1 and after["post_warm_compiles"] == 0
     assert after["bank_keys"] == CAP
-    over_cap = after["overcap_fallback_items"] - before["overcap_fallback_items"]
+    assert after["overcap_fallback_items"] == 0
+    over_cap = after["ladder_items"] - before["ladder_items"]
     assert (over_cap > 0) == (4 + n_clients > CAP)
+    assert ("verify.ladder" in served["spans"]) == (4 + n_clients > CAP)
     # a gauge is still over the window, and the counter beside it is not
     assert served["counters"]["verify"]["device_shapes.bank_keys"] == 0
     passes = snap["device_passes"]

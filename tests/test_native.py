@@ -112,6 +112,34 @@ def test_prepare_wire_matches_oracle(hold_lock):
     assert not wire[n:].any() and not precheck[n:].any()
 
 
+@pytest.mark.parametrize("n_rows, size", [(1, 8), (5, 8), (600, 2048)])
+def test_ladder_rows_copies_rows_with_their_keys_and_masks_the_pile(n_rows, size):
+    """native.ladder_rows on a random staged pile: out row i is the pile's
+    row rows[i] with its key behind it, its precheck goes along and is
+    cleared in the pile, untouched rows stay, the padding is zero."""
+    if not native.available():
+        pytest.skip("no native host-prep library on this machine")
+    rng = np.random.default_rng(36)
+    pile = 3 * n_rows + 1
+    wire = rng.integers(0, 256, (pile, 96), dtype=np.uint8)
+    pub = rng.integers(0, 256, 32 * pile, dtype=np.uint8).tobytes()
+    precheck = rng.integers(0, 2, pile).astype(np.bool_)
+    before = precheck.copy()
+    rows = sorted(rng.choice(pile, n_rows, replace=False).tolist())
+    idx, out, out_pre = native.ladder_rows(wire, pub, precheck, rows, size)
+    assert idx.dtype == np.int64 and idx.tolist() == rows
+    assert out.shape == (size, 128) and out.dtype == np.uint8
+    assert out_pre.shape == (size,) and out_pre.dtype == np.bool_
+    assert np.array_equal(out[:n_rows, :96], wire[rows])
+    keys = np.frombuffer(pub, np.uint8).reshape(pile, 32)
+    assert np.array_equal(out[:n_rows, 96:], keys[rows])
+    assert np.array_equal(out_pre[:n_rows], before[rows])
+    assert not out[n_rows:].any() and not out_pre[n_rows:].any()
+    assert not precheck[rows].any()
+    others = np.setdiff1d(np.arange(pile), rows)
+    assert np.array_equal(precheck[others], before[others])
+
+
 def test_prepare_wire_empty_pile():
     if not native.available():
         pytest.skip("no native host-prep library on this machine")

@@ -146,9 +146,10 @@ def test_initial_keys_pins_table_shape_and_warm_is_inert():
     assert v._bank._cap == 32  # capacity untouched by traffic
 
 
-def test_keybank_cap_falls_back_to_cpu():
-    """Keys beyond the bank cap must still verify correctly (CPU path),
-    and the bank must not grow past max_keys."""
+def test_keybank_cap_takes_the_ladder():
+    """Keys beyond the bank cap must still verify correctly (on the
+    device, by the table-free program), and the bank must not grow past
+    max_keys."""
     from simple_pbft_tpu.crypto.tpu_verifier import KeyBank
 
     v = TpuVerifier()
@@ -159,16 +160,19 @@ def test_keybank_cap_falls_back_to_cpu():
     items.append(BatchItem(items[3].pubkey, items[3].msg, bytes(bad)))
     assert v.verify_batch(items) == [True, True, True, True, False]
     assert len(v._bank._index) == 2
+    assert (v.ladder_items, v.ladder_passes) == (3, 1)
+    assert v.overcap_fallback_items == 0 and v._cpu_fb is None
 
 
-def test_overbank_fallback_agrees_with_kernel():
-    """The over-bank-cap fallback must be KERNEL-EQUIVALENT (ADVICE r5):
-    the same batch split between kernel rows and fallback rows shares
-    one verdict bitmap, so the two paths must agree on every known edge
-    vector — non-canonical S (>= L), y >= p key encodings, wrong
-    lengths, tampered bits — or a crafted signature could verify on one
-    replica's split and not another's. Pins both the agreement and the
-    fallback CLASS (native/oracle, never OpenSSL)."""
+def test_overbank_ladder_agrees_with_kernel():
+    """The program for keys over the bank's cap must be
+    KERNEL-EQUIVALENT (ADVICE r5): the same batch split between comb
+    rows and ladder rows shares one verdict bitmap, so the two programs
+    must agree on every known edge vector — non-canonical S (>= L),
+    y >= p key encodings, wrong lengths, tampered bits — or a crafted
+    signature could verify on one replica's split and not another's.
+    Pins the agreement, that the ladder ran, and the one case that keeps
+    the CPU route and its CLASS (native/oracle, never OpenSSL)."""
     from simple_pbft_tpu.crypto.tpu_verifier import KeyBank
     from simple_pbft_tpu.crypto.verifier import (
         CpuVerifier,
@@ -198,8 +202,8 @@ def test_overbank_fallback_agrees_with_kernel():
     # kernel verdicts: roomy bank, every key resident
     kernel = TpuVerifier().verify_batch(edge_items)
     assert kernel == oracle
-    # fallback verdicts: bank capacity 1, pre-occupied by an unrelated
-    # key, so EVERY edge item routes to the over-cap fallback path
+    # ladder verdicts: bank capacity 1, pre-occupied by an unrelated
+    # key, so EVERY well-formed edge item routes to the table-free program
     v = TpuVerifier()
     v._bank = KeyBank(initial_capacity=1, max_keys=1)
     occupier = _signed(99, b"occupier")
@@ -208,8 +212,19 @@ def test_overbank_fallback_agrees_with_kernel():
     got = v.verify_batch(edge_items)
     assert got == kernel == oracle
     assert len(v._bank._index) == 1  # nothing evicted/registered
-    # the fallback actually ran and is a kernel-equivalent class
-    assert v._cpu_fb is not None
+    # the ladder ran: every row but the wrong-length one, and no CPU
+    assert (v.ladder_items, v.ladder_passes) == (len(edge_items) - 1, 1)
+    assert v.overcap_fallback_items == 0 and v._cpu_fb is None
+    # a verifier whose warm closed the shape set WITHOUT the ladder (its
+    # published population fit the bank) compiles nothing under traffic:
+    # a walk-in key past the cap keeps the CPU route, a kernel-equivalent
+    # class, with the same verdicts
+    v._warm_done = True
+    v.shape_signatures = {g for g in v.shape_signatures if g[0] != "ladder"}
+    assert v.verify_batch(edge_items) == oracle
+    assert v.post_warm_compiles == 0
+    assert v.overcap_fallback_items == len(edge_items) - 1
+    assert (v.ladder_items, v.ladder_passes) == (len(edge_items) - 1, 1)
     assert isinstance(v._cpu_fb, (NativeEdVerifier, CpuVerifier))
     assert type(kernel_equivalent_cpu_verifier()) is type(v._cpu_fb)
 
@@ -222,6 +237,27 @@ def test_meshed_tpu_verifier_fused(meshed_verifier):
     items.append(forged)
     oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
     assert meshed_verifier.verify_batch(items) == oracle == [True] * 12 + [False]
+
+
+def test_meshed_ladder_agrees_with_the_oracle():
+    """The table-free program under shard_map: a meshed verifier whose
+    bank is full sends uncached rows down the ladder, one row a device
+    here, and the verdicts are the oracle's."""
+    import jax
+    from jax.sharding import Mesh
+
+    from simple_pbft_tpu.crypto.tpu_verifier import KeyBank
+
+    v = TpuVerifier(mesh=Mesh(np.asarray(jax.devices()[:8]), ("dp",)))
+    v._bank = KeyBank(initial_capacity=1, max_keys=1)
+    assert v.verify_batch([_signed(99, b"occupier")]) == [True]
+    items = [_signed(60 + i % 3, b"meshed ladder %d" % i) for i in range(6)]
+    items[4] = BatchItem(items[4].pubkey, b"not the msg", items[4].sig)
+    items[5] = BatchItem(b"\xff" * 32, items[5].msg, items[5].sig)
+    oracle = [ref.verify(i.pubkey, i.msg, i.sig) for i in items]
+    assert v.verify_batch(items) == oracle == [True] * 4 + [False] * 2
+    assert (v.ladder_items, v.ladder_passes) == (6, 1)
+    assert v.overcap_fallback_items == 0
 
 
 def _not_a_point() -> bytes:
@@ -400,6 +436,7 @@ def _stage_both(items, monkeypatch, bank=None):
     got = tv.prepare_wire_batch(items, bank(), size)
     with monkeypatch.context() as m:
         m.setattr(tv.native, "prepare_wire", lambda *a, **kw: None)
+        m.setattr(tv.native, "ladder_rows", lambda *a, **kw: None)
         want = tv.prepare_wire_batch(items, bank(), size)
     assert got.native and not want.native
     return got, want
@@ -415,7 +452,10 @@ def _assert_same_staging(got, want, size):
         assert g.dtype == w.dtype == dtype and g.shape == w.shape == shape, field
         assert g.flags.c_contiguous, field
         assert g.tobytes() == w.tobytes(), field
-    assert got.fallback == want.fallback
+    assert (got.ladder is None) == (want.ladder is None)
+    if got.ladder is not None:
+        for g, w in zip(got.ladder, want.ladder):
+            assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
 
 
 def _with_s(it, s: int):
@@ -505,7 +545,10 @@ MALFORMED = {
 @pytest.mark.parametrize("kind", list(MALFORMED))
 def test_native_staging_masks_malformed_rows(kind, pool, native_lib, monkeypatch):
     """A bank of two keys, both taken by the pile's first rows: the row is
-    masked on both paths, and only the over-cap key is handed to the CPU."""
+    masked in the comb's pile on both paths, and a well-formed row whose
+    key has no table is staged again for the ladder, key bytes behind it.
+    A full bank decompresses nothing, so whether such a key is a curve
+    point is the device's to say."""
     from simple_pbft_tpu.crypto import tpu_verifier as tv
 
     items = [pool[0], pool[1], MALFORMED[kind](pool[5]), pool[4]]
@@ -515,7 +558,15 @@ def test_native_staging_masks_malformed_rows(kind, pool, native_lib, monkeypatch
     )
     _assert_same_staging(got, want, 8)
     assert got.precheck[:4].tolist() == [True, True, False, True]
-    assert got.fallback == ([2] if kind == "key over the bank's cap" else [])
+    if kind in ("key over the bank's cap", "key not a curve point"):
+        lad = got.ladder
+        assert lad.rows.tolist() == [2]
+        assert lad.wire.shape == (8, 128) and lad.precheck.tolist() == [True] + [False] * 7
+        assert lad.wire[0, :96].tobytes() == got.wire[2].tobytes()
+        assert lad.wire[0, 96:].tobytes() == items[2].pubkey
+        assert not lad.wire[1:].any()
+    else:
+        assert got.ladder is None
     assert got.a_idx[:4].tolist() == [
         0, 1, 1 if kind == "63-byte signature" else 0, 0,
     ]

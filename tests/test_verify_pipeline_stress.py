@@ -11,7 +11,7 @@ through the max_keys/UNCACHED boundary and then audit the bank:
 
 - every cached pubkey maps to a UNIQUE row, and the row's table content
   bit-exactly matches a freshly built table for that key;
-- keys beyond the cap consistently report UNCACHED (CPU fallback), never
+- keys beyond the cap consistently report UNCACHED (the ladder), never
   a stolen row;
 - invalid keys stay -1 and the negative cache stays bounded;
 - a two-thread TpuVerifier pipeline returns the same verdict bitmap as

@@ -119,7 +119,9 @@ def dev_cell(snap: dict) -> str:
     ``disp/s occ% eff-verifies/s pad%`` from the verify service's
     ``device`` ledger block, then ``k<keys>/<capacity>`` of the key bank
     where ``device_shapes`` reports it (keys at the capacity: the next
-    walk-in key verifies on the CPU). Works identically from a live
+    walk-in key has no table), then ``L<share>%`` where rows took the
+    table-free ladder: their share of the items finished passes verified
+    (``ladder_items`` over the two staging counters). Works identically from a live
     scrape and from a flight-file tail (the block rides every frame), so
     a wedged node's last device posture is still one glance. Blank when
     the node never dispatched to a device (CPU-verifier committees)."""
@@ -136,6 +138,10 @@ def dev_cell(snap: dict) -> str:
     shapes = verify.get("device_shapes") or {}
     if shapes.get("bank_capacity"):
         cell += f" k{shapes.get('bank_keys', 0)}/{shapes['bank_capacity']}"
+    if shapes.get("ladder_items"):
+        staged = (shapes.get("native_prep_items", 0)
+                  + shapes.get("fallback_prep_items", 0))
+        cell += f" L{100 * shapes['ladder_items'] / max(staged, 1):.0f}%"
     return cell
 
 
