@@ -28,6 +28,7 @@ class LocalCommittee:
     lag_gauge: Optional[object] = None  # LoopLagGauge (attach_loop_lag)
     traffic_stats: Optional[object] = None  # workload.TrafficStats (ISSUE 17)
     knob_registry: Optional[object] = None  # controller.KnobRegistry (ISSUE 19)
+    heap_settled: bool = False  # start() settled the heap; stop() releases it
 
     @staticmethod
     def build(
@@ -76,12 +77,18 @@ class LocalCommittee:
         return committee
 
     def start(self) -> None:
-        from . import clock
+        from . import clock, heap
 
         for r in self.replicas:
             r.start()
         for c in self.clients:
             c.start()
+        if not self.heap_settled:
+            # the collector's policy for a process that serves (heap.py):
+            # what is built by now (the verifier's tables and programs,
+            # the replicas, the clients) is frozen before the first request
+            heap.settle_heap()
+            self.heap_settled = True
         if self.lag_gauge is None and not clock.simulated():
             # the heartbeat (loop.lag, loop.offcpu, loop.unattributed,
             # gc.pause): one loop runs every node here, so one serves all.
@@ -91,6 +98,11 @@ class LocalCommittee:
     async def stop(self) -> None:
         import asyncio
 
+        from . import heap
+
+        if self.heap_settled:
+            self.heap_settled = False
+            heap.release_heap()
         if self.lag_gauge is not None:
             await self.lag_gauge.stop()
             self.lag_gauge = None

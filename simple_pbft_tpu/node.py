@@ -150,7 +150,7 @@ def _dump_final(node_id: str, replica, transport, watchdog=None) -> None:
 
 
 async def run_node(args) -> None:
-    from . import spans
+    from . import heap, spans
     from .telemetry import (
         FlightRecorder,
         LoopLagGauge,
@@ -304,6 +304,10 @@ async def run_node(args) -> None:
             args.id, dep.addr(args.id), args.verifier, dep.cfg.n, dep.cfg.f,
         )
 
+        # the collector's policy for a process that serves (heap.py): the
+        # verifier is warm and every plane of the node is built, so what
+        # is alive now is what the node serves from
+        heap.settle_heap()
         stop = asyncio.Event()
         loop = asyncio.get_running_loop()
         for sig in (signal.SIGINT, signal.SIGTERM):
@@ -332,6 +336,7 @@ async def run_node(args) -> None:
             logging.exception("%s: telemetry teardown failed", args.id)
         _dump_final(args.id, replica, transport, watchdog=watchdog)
         spans.recorder().close()
+        heap.release_heap()  # nothing, where the run never came to settle
 
 
 def main() -> None:

@@ -73,6 +73,9 @@ and from the heartbeat (telemetry.LoopLagGauge, one task per process):
                      time, per tick (floored at 0: sections are timed on
                      the wall clock and may hold off-CPU time)
   gc.pause           one collection (gc.callbacks), generation as n
+  gc.full            the same pause again where the collection was a full
+                     one (generation 2): its count and its maximum are the
+                     full collections', which heap.py's policy keeps few
 
 While a profiler capture is open (``annotating``), every section and the
 verify threads' stages (``annotation(stage)``; verify.collect is the
@@ -148,10 +151,12 @@ LOOP_STAGES = (
     LOOP_SIGN_VOTE, LOOP_SEND, LOOP_EXECUTE, LOOP_SIGN_REPLY, LOOP_CLIENT,
 )
 # loop health, one sample per heartbeat tick; gc.pause one per collection
+# of any generation, gc.full one per full collection (a part of gc.pause)
 LOOP_LAG = "loop.lag"
 LOOP_OFFCPU = "loop.offcpu"
 LOOP_UNATTRIBUTED = "loop.unattributed"
 GC_PAUSE = "gc.pause"
+GC_FULL = "gc.full"
 
 # the slot-level stages that tile a commit's end-to-end latency, in
 # pipeline order (critical_path.py reconciles their sum against commit_ms)
@@ -629,8 +634,9 @@ _gc_open: List[Any] = []  # [t0, annotation | None] of the running collection
 def _on_gc(phase: str, info: Dict[str, Any]) -> None:
     """gc.callbacks hook: one ``gc.pause`` per collection, with the
     generation as ``n``, on whichever thread tripped it (every Python
-    thread waits meanwhile). Never part of the loop's sum: a collection
-    is inside whichever section it interrupted."""
+    thread waits meanwhile); a full collection's pause goes to ``gc.full``
+    as well. Never part of the loop's sum: a collection is inside
+    whichever section it interrupted."""
     if phase == "start":
         ann = None
         if _annotating:
@@ -641,7 +647,10 @@ def _on_gc(phase: str, info: Dict[str, Any]) -> None:
         t0, ann = _gc_open
         del _gc_open[:]
         dur = clock.now() - t0
-        _recorder.accum(GC_PAUSE).add(dur, info.get("generation", 0))
+        generation = info.get("generation", 0)
+        _recorder.accum(GC_PAUSE).add(dur, generation)
+        if generation == 2:
+            _recorder.accum(GC_FULL).add(dur)
         if ann is not None:
             ann.__exit__(None, None, None)
 

@@ -43,6 +43,7 @@ latency attribution in ``simple_pbft_tpu/spans.py`` +
 from __future__ import annotations
 
 import asyncio
+import gc
 import hashlib
 import json
 import logging
@@ -630,6 +631,10 @@ class LoopLagGauge:
             "ema_ms": round(self.ema_ms, 3),
             "last_ms": round(self.last_ms, 3),
             "samples": self.samples,
+            # objects no collection examines (heap.settle_heap froze
+            # them): hundreds of thousands on a settled node, the few
+            # hundred an interpreter starts with on one that is not
+            "gc_frozen": gc.get_freeze_count(),
         }
 
 

@@ -430,9 +430,12 @@ def test_all_nine_stages_and_the_heartbeat_count_on_a_live_committee(
         assert stages[stage]["count"] > 0, stage
         assert stages[stage]["sum"] > 0.0, stage
     for stage in (spans.LOOP_LAG, spans.LOOP_OFFCPU, spans.LOOP_UNATTRIBUTED,
-                  spans.GC_PAUSE):
+                  spans.GC_PAUSE, spans.GC_FULL):
         assert stages[stage]["count"] > 0, stage
     assert stages[spans.GC_PAUSE]["n"] >= 2  # generations: one full one
+    # the full one is in both, so gc.full is a part of gc.pause
+    assert stages[spans.GC_FULL]["count"] <= stages[spans.GC_PAUSE]["count"]
+    assert 0.0 < stages[spans.GC_FULL]["sum"] <= stages[spans.GC_PAUSE]["sum"]
     # what each stage handled
     assert stages[spans.LOOP_EXECUTE]["n"] >= 2 * 4 * committed  # spec + final
     assert stages[spans.LOOP_CLIENT]["n"] >= 3 * committed  # 2f+1 replies each

@@ -53,7 +53,9 @@ def loop_cell(snap: dict, prev: Optional[dict]) -> str:
     wall time): ``ingest38 off12``. Between refreshes in the live loop,
     cumulative post-mortem / on the first frame. Blank for a snapshot
     without the accumulators. One loop runs every in-process node, so
-    every row of such a committee reads the same."""
+    every row of such a committee reads the same. ``fz412k``, where the
+    heartbeat reports it, is the objects the collector's policy froze
+    (heap.settle_heap; thousands): ``fz0k`` is a node that never settled."""
     def sums(doc: Optional[dict]) -> Dict[str, float]:
         stages = ((doc or {}).get("spans") or {}).get("stages") or {}
         return {k: v.get("sum", 0.0) for k, v in stages.items()
@@ -69,8 +71,12 @@ def loop_cell(snap: dict, prev: Optional[dict]) -> str:
     if total <= 0 or not held:
         return ""
     top = max(held, key=held.get)
-    return (f"{top[5:]}{100 * held[top] / total:.0f} "
+    cell = (f"{top[5:]}{100 * held[top] / total:.0f} "
             f"off{100 * took.get('loop.offcpu', 0.0) / total:.0f}")
+    frozen = (snap.get("loop_lag") or {}).get("gc_frozen")
+    if frozen is not None:
+        cell += f" fz{frozen / 1000:.0f}k"
+    return cell
 
 
 def netio_cell(snap: dict, prev: Optional[dict], dt: float) -> str:
