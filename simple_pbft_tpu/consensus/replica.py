@@ -43,7 +43,7 @@ from ..config import (
     config_doc,
     config_from_doc,
 )
-from ..crypto.coalesce import Overloaded
+from ..crypto.coalesce import Overloaded, since_resolved
 from ..crypto.signer import Signer
 from ..crypto.verifier import BatchItem, Verifier, best_cpu_verifier
 from ..logutil import ReplicaStats
@@ -747,7 +747,6 @@ class Replica:
         # their ~0 ms samples would dilute verify batch-size and
         # latency stats toward zero
         if n_fresh:
-            self.stats.verify_ms.record(dt * 1e3)
             self.stats.verify_items += n_fresh
             self.stats.verify_seconds += dt
             # the replica's seat at the verify pipeline: the full round
@@ -786,18 +785,24 @@ class Replica:
             out, fresh, fresh_keys = await clock.off_thread(
                 self._cache_filter, items
             )
-            verdict = self._submit_fresh(fresh)
+            submitted, verdict = self._submit_fresh(fresh)
         else:
             # one section for the synchronous head of a sweep's verify,
             # the submit charged out of it
             spans.begin(spans.LOOP_SIGCACHE)
             try:
                 out, fresh, fresh_keys = self._cache_filter(items)
-                verdict = self._submit_fresh(fresh)
+                submitted, verdict = self._submit_fresh(fresh)
             finally:
                 spans.end(spans.LOOP_SIGCACHE, len(items))
         if verdict is not None:
             verdicts = await verdict
+            # verify.return, the way back from the service's resolve to
+            # here: both ends on the service's clock (time.perf_counter),
+            # so under the sim's virtual clock nothing is recorded
+            back = since_resolved(submitted)
+            if back is not None and not clock.simulated():
+                spans.record(spans.VERIFY_RETURN, back, persist=False)
             spans.begin(spans.LOOP_SIGCACHE)
             try:
                 self._cache_store(fresh_keys, verdicts, out)
@@ -808,14 +813,16 @@ class Replica:
         return out
 
     def _submit_fresh(self, fresh: List[BatchItem]):
-        """Hand a sweep's uncached items to the verify service; the
-        verdict's future, or None where the cache answered everything."""
+        """Hand a sweep's uncached items to the verify service: the
+        service's future and the loop's wrap of it, or two Nones where
+        the cache answered everything."""
         if not fresh:
-            return None
+            return None, None
         t0 = clock.now()
-        verdict = asyncio.wrap_future(self.verifier.submit(fresh))
+        submitted = self.verifier.submit(fresh)
+        verdict = asyncio.wrap_future(submitted)
         spans.charge(spans.LOOP_VERIFY_SUBMIT, clock.now() - t0, len(fresh))
-        return verdict
+        return submitted, verdict
 
     async def _finish_sweep(self, decoded, sig_spans, verify_task) -> None:
         if not decoded:

@@ -55,6 +55,15 @@ class Overloaded(RuntimeError):
     sweep (peers/clients retransmit) while the pile stays bounded."""
 
 
+def since_resolved(fut) -> Optional[float]:
+    """Seconds since ``VerifyService._resolve`` set ``fut``, on the clock
+    it stamps with; None for a future it did not set (an overload
+    rejection, a closed service's CPU answer, an empty submit, a failure,
+    a test double's future)."""
+    t = getattr(fut, "t_resolved", None)
+    return None if t is None else time.perf_counter() - t
+
+
 class VerifyService:
     """Coalescing front for a device verifier + CPU small-batch path.
 
@@ -130,7 +139,8 @@ class VerifyService:
         self._inflight = 0
         self._closed = False
         self._started = False
-        # completion queue: (finisher, subs, t_dispatch, n_items)
+        # completion queue: (finisher, subs, t_dispatch, n_items,
+        # t_dispatched: when dispatch_batch returned)
         self._done_q: deque = deque()
         self._done_cond = threading.Condition(
             sanitize.wrap_lock(threading.Lock(), "verify_service.done_cond")
@@ -563,7 +573,7 @@ class VerifyService:
                     )
                 t0 = time.perf_counter()
                 try:
-                    with spans.annotation(spans.VERIFY_DEVICE):
+                    with spans.annotation(spans.VERIFY_DISPATCH):
                         finisher = self._device.dispatch_batch(batch)
                 except BaseException as e:  # noqa: BLE001
                     # the annotation above was never consumed (the
@@ -578,7 +588,9 @@ class VerifyService:
                         self._cond.notify_all()
                     continue
                 with self._done_cond:
-                    self._done_q.append((finisher, subs, t0, total))
+                    self._done_q.append(
+                        (finisher, subs, t0, total, time.perf_counter())
+                    )
                     self._done_cond.notify_all()
 
     def _complete_loop(self) -> None:
@@ -589,7 +601,8 @@ class VerifyService:
                 entry = self._done_q.popleft()
                 if entry is None:  # dispatcher's shutdown sentinel
                     return
-                finisher, subs, t0, total = entry
+                finisher, subs, t0, total, t_dispatched = entry
+            t_taken = time.perf_counter()
             # plain attribute (GIL-atomic): snapshot() reads it to expose
             # how long the CURRENT device pass has been in flight — the
             # number that names a silent device in a wedge autopsy
@@ -597,10 +610,10 @@ class VerifyService:
             ladder_s0 = self._ladder_seconds()
             try:
                 if self._deadline is not None:
-                    verdicts = self._finish_with_deadline(
+                    finished = self._finish_with_deadline(
                         finisher, subs, t0, total
                     )
-                    if verdicts is None:
+                    if finished is None:
                         # watchdog fired: the pile was already failed over
                         # to the CPU and the device quarantined — only the
                         # in-flight slot remains to release
@@ -609,9 +622,11 @@ class VerifyService:
                             self._inflight -= 1
                             self._cond.notify_all()
                         continue
+                    verdicts, t_finished = finished
                 else:
-                    with spans.annotation(spans.VERIFY_DEVICE):
+                    with spans.annotation(spans.VERIFY_RESULT_WAIT):
                         verdicts = finisher()
+                    t_finished = time.perf_counter()
             except BaseException as e:  # noqa: BLE001
                 self._fail(subs, e)
                 self._rtt_sampled = time.perf_counter()
@@ -638,8 +653,13 @@ class VerifyService:
                 self._rtt_sampled = now
                 self.device_passes += 1
                 self.device_pass_items += total
-                # dispatch -> result RTT of one coalesced device pass
+                # dispatch -> result RTT of one coalesced device pass,
+                # and its four legs: adjacent stamps, so they add up to it
                 spans.record(spans.VERIFY_DEVICE, rtt, n=total)
+                spans.record(spans.VERIFY_DISPATCH, t_dispatched - t0)
+                spans.record(spans.VERIFY_FINISH_QUEUE, t_taken - t_dispatched)
+                spans.record(spans.VERIFY_RESULT_WAIT, t_finished - t_taken)
+                spans.record(spans.VERIFY_FINISH, now - t_finished)
                 self._resolve(subs, verdicts)
                 # a completed pass within deadline is proof of device
                 # health: end any quarantine and reset the re-probe ladder
@@ -665,7 +685,8 @@ class VerifyService:
         stuck pile must not block later small piles' completions through
         this loop), quarantine the device path with exponential re-probe
         backoff, and abandon the stuck finisher (daemon thread). Returns
-        the verdicts, or None when the watchdog fired; device exceptions
+        the verdicts and the moment the finisher returned (stamped on the
+        sidecar), or None when the watchdog fired; device exceptions
         re-raise exactly like the undeadlined path."""
         # per-pass sidecar thread: ~100 us of spawn cost per device pass.
         # A persistent watcher would save it at the price of lifecycle
@@ -675,8 +696,9 @@ class VerifyService:
 
         def run() -> None:
             try:
-                with spans.annotation(spans.VERIFY_DEVICE):
+                with spans.annotation(spans.VERIFY_RESULT_WAIT):
                     box["r"] = finisher()
+                box["t"] = time.perf_counter()
             except BaseException as e:  # noqa: BLE001
                 box["e"] = e
             done.set()
@@ -694,7 +716,7 @@ class VerifyService:
         if done.wait(max(0.0, remaining)):
             if "e" in box:
                 raise box["e"]
-            return box["r"]
+            return box["r"], box["t"]
         # deadline exceeded: this is the stalled-device shape (r5 qc256:
         # svc_rtt_ms_ema ~15 s, one 25-minute wedge). Quarantine first so
         # the dispatch loop reroutes everything still pending, THEN
@@ -721,7 +743,7 @@ class VerifyService:
                 self._quarantine_backoff = self._quarantine_base
             if "e" in box:
                 raise box["e"]
-            return box["r"]
+            return box["r"], box["t"]
         if not was_quarantined:
             self.quarantine_entries += 1  # healthy -> quarantined
         self.cpu_reroute_passes += 1
@@ -784,10 +806,15 @@ class VerifyService:
 
     @staticmethod
     def _resolve(subs, verdicts: List[bool]) -> None:
+        # each future carries the moment it was set (before set_result
+        # wakes anyone): Replica._submit_verify's verify.return starts
+        # here. One stamp a take: every submission in it resolves now.
+        t_resolved = time.perf_counter()
         off = 0
         for items, fut in subs:
             n = len(items)
             if not fut.cancelled():
+                fut.t_resolved = t_resolved
                 fut.set_result(verdicts[off : off + n])
             off += n
 

@@ -15,9 +15,24 @@ no dict is built unless the span is exported or persisted. Stages:
 
   verify.queue       VerifyService admission-queue wait (submit -> take)
   verify.host_prep   TpuVerifier host-side batch prep before dispatch
-  verify.device      device dispatch -> result RTT (one coalesced pass)
-  verify.ladder      the same, of a pass's table-free program alone (the
-                     rows whose key has no table); n = those rows
+  verify.device      device dispatch -> result RTT (one coalesced pass),
+                     tiled exactly by the four legs below (adjacent
+                     endpoints on time.perf_counter, one of each a pass):
+    verify.dispatch      t0 -> dispatch_batch returned (dispatcher thread:
+                         prep, the device lock, the enqueue)
+    verify.finish_queue  dispatch_batch returned -> the completion thread
+                         takes the pass off its queue (behind the pass
+                         before it at depth 2)
+    verify.result_wait   taken -> finisher() returned, on the thread that
+                         ran it (the watchdog's verify-finish sidecar)
+    verify.finish        finisher() returned -> the completion thread's
+                         round-trip stamp (the sidecar's wake-up)
+  verify.return      a submission's future resolved -> the replica's
+                     coroutine resumed after ``await`` (the way back
+                     through the event loop; histogram only)
+  verify.ladder      verify.device's round trip, of a pass's table-free
+                     program alone (the rows whose key has no table);
+                     n = those rows
   verify.cpu         CPU small-batch pass
   verify.cpu_reroute CPU reroute chunk (quarantine / depth-full big pile)
   verify.bank_build  the warm's key-table build and its one upload
@@ -80,7 +95,10 @@ and from the heartbeat (telemetry.LoopLagGauge, one task per process):
 While a profiler capture is open (``annotating``), every section and the
 verify threads' stages (``annotation(stage)``; verify.collect is the
 dispatcher's wait for a pile) also enter ``jax.profiler.TraceAnnotation``
-so they land on the device trace's clock.
+so they land on the device trace's clock. Of verify.device's legs the two
+a thread is busy in are annotated (verify.dispatch, verify.result_wait);
+verify.device itself, and the queue residencies verify.finish_queue and
+verify.return, are spans only.
 
 One recorder per process (like consensus/qc.py's verify lane): the
 coalescing service and the QC lane are process-wide anyway, and
@@ -112,6 +130,18 @@ log = logging.getLogger("pbft.spans")
 VERIFY_QUEUE = "verify.queue"
 VERIFY_HOST_PREP = "verify.host_prep"
 VERIFY_DEVICE = "verify.device"
+# the four legs that tile verify.device, in order (record only where
+# verify.device is recorded, so all five count alike)
+VERIFY_DISPATCH = "verify.dispatch"
+"""t0 -> ``dispatch_batch`` returned, on the dispatcher thread."""
+VERIFY_FINISH_QUEUE = "verify.finish_queue"
+"""``dispatch_batch`` returned -> ``_complete_loop`` takes the pass."""
+VERIFY_RESULT_WAIT = "verify.result_wait"
+"""``_complete_loop`` took the pass -> ``finisher()`` returned."""
+VERIFY_FINISH = "verify.finish"
+"""``finisher()`` returned -> the completion thread's round-trip stamp."""
+VERIFY_RETURN = "verify.return"
+"""``_resolve`` set the future -> ``Replica._submit_verify`` resumed."""
 VERIFY_LADDER = "verify.ladder"
 VERIFY_CPU = "verify.cpu"
 VERIFY_REROUTE = "verify.cpu_reroute"
@@ -282,8 +312,8 @@ class SpanRecorder:
         slot in the recent ring (an autopsy's last-N window must hold the
         pipeline spans that diagnose a wedge, not thousands of transport
         residencies)."""
-        end = clock.now()
-        rec = (stage, end, dur, node, view, seq, rid, n)
+        if persist:  # only the ring and the file read the end stamp
+            rec = (stage, clock.now(), dur, node, view, seq, rid, n)
         with self._lock:
             h = self._hists.get(stage)
             if h is None:

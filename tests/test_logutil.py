@@ -34,13 +34,14 @@ def test_histogram_summary():
 def test_replica_stats_dump_is_json():
     st = ReplicaStats()
     st.sweep_size.record(3)
-    st.verify_ms.record(1.5)
+    st.sweep_ms.record(1.5)
     st.verify_items += 10
     st.verify_seconds += 0.01
     doc = json.loads(st.dump({"committed_blocks": 2}))
     assert doc["metrics"]["committed_blocks"] == 2
     assert doc["verify_per_s"] == 1000.0
     assert doc["sweep_size"]["count"] == 1
+    assert doc["sweep_ms"]["count"] == 1
 
 
 def test_per_node_rotating_file(tmp_path):

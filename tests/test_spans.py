@@ -134,6 +134,158 @@ def test_verify_service_spans_cover_queue_and_device_paths():
 
 
 # ---------------------------------------------------------------------------
+# the verify round trip, split where it happens (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+PASS_LEGS = (spans.VERIFY_DISPATCH, spans.VERIFY_FINISH_QUEUE,
+             spans.VERIFY_RESULT_WAIT, spans.VERIFY_FINISH)
+
+
+@pytest.fixture
+def fresh_spans():
+    spans.configure("test")
+    yield
+    spans._set_annotating(False)
+    spans.configure("")
+
+
+class SleepyDevice(EchoDevice):
+    """EchoDevice whose dispatch takes ``d1`` seconds and whose finisher
+    takes ``d2``, after waiting for ``gate`` where one is given."""
+
+    def __init__(self, d1=0.0, d2=0.0, gate=None):
+        super().__init__()
+        self.d1, self.d2, self.gate = d1, d2, gate
+
+    def dispatch_batch(self, items):
+        time.sleep(self.d1)
+        finish = super().dispatch_batch(items)
+
+        def finisher():
+            if self.gate is not None:
+                assert self.gate.wait(30), "test gate never released"
+            time.sleep(self.d2)
+            return finish()
+
+        return finisher
+
+
+def _echo_items(n, tag):
+    from simple_pbft_tpu.crypto.verifier import BatchItem
+
+    return [BatchItem(b"pk", tag + bytes([i]), tag + bytes([i]))
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("deadline", [None, 5.0])
+def test_four_pass_legs_tile_the_device_round_trip(fresh_spans, deadline):
+    d1, d2, passes = 0.004, 0.008, 4
+    svc = VerifyService(SleepyDevice(d1, d2), cpu=EchoCpu(), cpu_cutoff=0,
+                        dispatch_deadline=deadline)
+    for k in range(passes):  # one pass each: the next waits for the last
+        assert svc.submit(_echo_items(32, bytes([k]))).result(10) == [True] * 32
+    svc.close()
+    hists = spans.recorder()._hists
+    assert hists[spans.VERIFY_DEVICE].count == passes
+    for leg in PASS_LEGS:
+        assert hists[leg].count == passes, leg
+    legs_ms = sum(hists[leg].total for leg in PASS_LEGS)
+    assert abs(legs_ms - hists[spans.VERIFY_DEVICE].total) < 1.0
+    assert hists[spans.VERIFY_DISPATCH].vmin >= d1 * 1e3 * 0.99
+    assert hists[spans.VERIFY_RESULT_WAIT].vmin >= d2 * 1e3 * 0.99
+
+
+def test_the_way_back_is_recorded_per_resolved_submission(fresh_spans):
+    """verify.return runs from the service's resolve to the replica's
+    resume: a loop held 50 ms after the verdict lands reads it; an
+    overload-rejected submission records nothing."""
+    import threading
+
+    from simple_pbft_tpu.crypto.coalesce import Overloaded
+
+    gate = threading.Event()
+    dev = SleepyDevice(gate=gate)
+    svc = VerifyService(dev, cpu=EchoCpu(), cpu_cutoff=0, max_pending=100)
+    com = LocalCommittee.build(n=4, clients=1, verifier_factory=lambda: svc)
+    r0 = com.replicas[0]
+
+    def returned():
+        return spans.stage_summaries().get(spans.VERIFY_RETURN, {})
+
+    async def scenario():
+        loop = asyncio.get_running_loop()
+        first = loop.create_task(r0._submit_verify(_echo_items(64, b"a")))
+        await asyncio.sleep(0.05)  # dispatched; its finisher waits on the gate
+        second = loop.create_task(r0._submit_verify(_echo_items(64, b"b")))
+        await asyncio.sleep(0.05)  # pending behind it: 64 of the cap of 100
+        assert dev.device_calls == 1
+        with pytest.raises(Overloaded):
+            await r0._submit_verify(_echo_items(64, b"c"))
+        assert returned().get("count", 0) == 0
+        gate.set()
+        time.sleep(0.05)  # the loop is held while both verdicts land
+        assert await first == [True] * 64
+        assert await second == [True] * 64
+
+    run(scenario())
+    svc.close()
+    assert returned()["count"] == 2
+    assert returned()["min"] >= 45.0
+
+
+def test_annotations_name_the_busy_legs_on_their_threads(
+        fresh_spans, monkeypatch):
+    import threading
+
+    entered = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append((self.name, threading.current_thread().name))
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(spans, "_TraceAnnotation", Note)
+    spans._set_annotating(True)
+    for deadline in (None, 5.0):
+        svc = VerifyService(EchoDevice(), cpu=EchoCpu(), cpu_cutoff=0,
+                            dispatch_deadline=deadline)
+        assert svc.submit(_echo_items(16, b"n")).result(10) == [True] * 16
+        svc.close()
+    assert (spans.VERIFY_DISPATCH, "verify-dispatch") in entered
+    assert (spans.VERIFY_RESULT_WAIT, "verify-complete") in entered
+    assert (spans.VERIFY_RESULT_WAIT, "verify-finish") in entered
+    assert not [e for e in entered if e[0] == spans.VERIFY_DEVICE]
+
+
+SPLIT_METRICS = (
+    "verify_dispatch_mean_ms", "verify_finish_queue_mean_ms",
+    "verify_result_wait_mean_ms", "verify_finish_mean_ms",
+    "verify_return_mean_ms", "verify_seat_wait_mean_ms",
+)
+
+
+@pytest.mark.parametrize("name", SPLIT_METRICS)
+def test_each_split_metric_reads_a_stage_the_program_defines(name):
+    root = os.path.join(os.path.dirname(__file__), "..")
+    with open(os.path.join(root, "benchmark", "metrics", name + ".json")) as fh:
+        spec = json.load(fh)
+    kind, stage, stat = spec["source"].split(":")
+    assert (kind, stat) == ("span", "mean")
+    defined = {v for k, v in vars(spans).items()
+               if k.isupper() and isinstance(v, str)}
+    assert stage in defined
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        manifest = {m["name"]: m for m in json.load(fh)["per_layer"]}
+    assert manifest[name]["layer"] == spec["layer"]
+
+
+# ---------------------------------------------------------------------------
 # acceptance: per-stage decomposition reconciles with commit latency
 # ---------------------------------------------------------------------------
 

@@ -56,7 +56,7 @@ def test_snapshot_schema_on_idle_node():
         assert rep["view"] == 0 and rep["executed_seq"] == 0
         assert rep["is_primary"] is True  # r0 is view-0 primary
         # idle histograms: full zeroed schema, no KeyError
-        for h in ("sweep_ms", "verify_ms", "commit_ms", "sweep_size"):
+        for h in ("sweep_ms", "commit_ms", "spec_reply_ms", "sweep_size"):
             assert rep["stats"][h]["p99"] == 0.0
             assert rep["stats"][h]["count"] == 0
         # idle transport: the FULL shared counter schema, all zero
