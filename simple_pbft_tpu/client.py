@@ -86,6 +86,10 @@ class Client:
         # a counter reader sees 0 where nothing was batched
         self.metrics["reply_frames"] = 0
         self.metrics["reply_entries_batched"] = 0
+        # transport drains that returned frames beyond the one the receive
+        # loop woke for (``recv_many``): frames over drains is what one
+        # drain's bookkeeping is shared by
+        self.metrics["recv_drains"] = 0
         # Hedged first send: also deliver each request to `hedge` backups
         # (rotating), who relay it to the primary and arm their failover
         # timers on first receipt. Kills the worst-case failover tail
@@ -178,12 +182,15 @@ class Client:
             # queue that holds more is read on without a suspension either
             # way (asyncio.Queue.get returns at once when it is not empty)
             spans.begin(spans.LOOP_CLIENT)
-            taken = 0
+            taken = 1
             try:
-                while raw is not None:
-                    taken += 1
-                    self._on_wire(raw)
-                    raw = self.transport.recv_nowait()
+                self._on_wire(raw)
+                more = self.transport.recv_many()
+                if more:
+                    self.metrics["recv_drains"] += 1
+                    taken += len(more)
+                    for raw in more:
+                        self._on_wire(raw)
             finally:
                 spans.end(spans.LOOP_CLIENT, taken)
 

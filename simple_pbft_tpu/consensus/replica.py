@@ -203,6 +203,10 @@ class Replica:
         self.snapshots: Dict[int, str] = {}  # our app snapshots, by seq
         self.pending_sync: Optional[Tuple[int, str]] = None  # (seq, digest)
         self.metrics: Dict[str, int] = defaultdict(int)
+        # ingest's transport drains that returned frames (``recv_many``),
+        # present from the start: frames over drains is what one drain's
+        # bookkeeping is shared by
+        self.metrics["recv_drains"] = 0
         self.stats = ReplicaStats()  # histograms: sweep/verify/commit
         # sampled phase-level request tracing (telemetry.RequestTracer):
         # attached after construction by node.py / committee / bench; all
@@ -550,11 +554,10 @@ class Replica:
             spans.begin(spans.LOOP_INGEST)
             sweep = [raw]
             try:
-                while len(sweep) < self.max_drain:
-                    nxt = self.transport.recv_nowait()
-                    if nxt is None:
-                        break
-                    sweep.append(nxt)
+                more = self.transport.recv_many(self.max_drain - 1)
+                if more:
+                    self.metrics["recv_drains"] += 1
+                    sweep += more
                 job = self._start_sweep(sweep)
             except Exception:
                 log.exception("%s: sweep decode failed", self.id)

@@ -870,6 +870,9 @@ class ShapedTransport:
     def recv_nowait(self):
         return self._inner.recv_nowait()
 
+    def recv_many(self, limit=None):
+        return self._inner.recv_many(limit)
+
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
@@ -1031,6 +1034,9 @@ class ByzantineTransport:
 
     def recv_nowait(self):
         return self._inner.recv_nowait()
+
+    def recv_many(self, limit=None):
+        return self._inner.recv_many(limit)
 
     def __getattr__(self, name):
         return getattr(self._inner, name)

@@ -86,6 +86,21 @@ class Histogram:
         if v > self.vmax:
             self.vmax = v
 
+    def record_many(self, vs: List[float]) -> None:
+        """``record`` of each of ``vs`` in order, with the attribute
+        lookups paid once: the same buckets, count, sum, min and max."""
+        counts, bounds, right = self.counts, self.bounds, bisect.bisect_right
+        total, vmin, vmax = self.total, self.vmin, self.vmax
+        for v in vs:
+            counts[right(bounds, v)] += 1
+            total += v
+            if v < vmin:
+                vmin = v
+            if v > vmax:
+                vmax = v
+        self.count += len(vs)
+        self.total, self.vmin, self.vmax = total, vmin, vmax
+
     def _quantile(self, q: float) -> float:
         if self.count == 0:
             return 0.0

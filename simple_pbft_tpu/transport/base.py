@@ -200,6 +200,25 @@ class WireAccounting:
         except Exception:  # noqa: BLE001 — never raises into the recv path
             pass
 
+    def account_sends(self, dests: Iterable[str], kind: str, size: int) -> None:
+        """A broadcast's accepted copies at once: ``dests`` names a link
+        once per copy, ``kind`` is the caller's classification of the
+        one frame they all carry."""
+        try:
+            sent = self.sent
+            for dest in dests:
+                kinds = sent.get(dest)
+                if kinds is None:
+                    kinds = sent[dest] = {}
+                cell = kinds.get(kind)
+                if cell is None:
+                    kinds[kind] = [1, size]
+                else:
+                    cell[0] += 1
+                    cell[1] += size
+        except Exception:  # noqa: BLE001 — never raises into the send path
+            pass
+
     def account_lost(self, bucket: str, raw: bytes, kind: str = "") -> None:
         try:
             kinds = self.lost.get(bucket)
@@ -274,6 +293,24 @@ class WireAccounting:
         }
 
 
+def account_recvs(wires: Iterable[Optional[WireAccounting]], kind: str,
+                  size: int) -> None:
+    """The receivers' half of ``WireAccounting.account_sends``: one
+    ``recv`` cell bump per accepted copy, ``None`` entries skipped."""
+    try:
+        for w in wires:
+            if w is None:
+                continue
+            cell = w.recv.get(kind)
+            if cell is None:
+                w.recv[kind] = [1, size]
+            else:
+                cell[0] += 1
+                cell[1] += size
+    except Exception:  # noqa: BLE001 — never raises into the send path
+        pass
+
+
 def wire_of(transport: Any) -> Optional[WireAccounting]:
     """The WireAccounting in a transport wrapper chain, if any. Walks
     ``_inner`` links (ShapedTransport / byzantine wrappers) to the
@@ -343,3 +380,16 @@ class Transport(Protocol):
     def recv_nowait(self) -> Optional[bytes]:
         """Drain one queued message without blocking, or None."""
         ...
+
+    def recv_many(self, limit: Optional[int] = None) -> List[bytes]:
+        """Drain up to ``limit`` queued messages (all of them when None)
+        without blocking; ``[]`` when none is queued. This default loops
+        over ``recv_nowait``; a transport that can take a drain whole
+        (local) overrides it."""
+        out: List[bytes] = []
+        while limit is None or len(out) < limit:
+            raw = self.recv_nowait()
+            if raw is None:
+                break
+            out.append(raw)
+        return out

@@ -31,7 +31,7 @@ import grpc
 import grpc.aio
 
 from .. import trace
-from .base import WireAccounting, base_metrics
+from .base import Transport, WireAccounting, base_metrics
 from .tcp import MAX_FRAME, OUTBOX_DEPTH, RECV_BUFFER_BYTES
 
 log = logging.getLogger("pbft.grpc")
@@ -63,7 +63,7 @@ _SERVER_OPTIONS = _COMMON_OPTIONS + [
 ]
 
 
-class GrpcTransport:
+class GrpcTransport(Transport):
     """One node's gRPC endpoint: an aio server + per-peer stream senders.
 
     Same construction surface as TcpTransport: ``peers`` maps node_id ->

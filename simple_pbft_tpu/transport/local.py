@@ -13,10 +13,10 @@ import asyncio
 import random
 import time
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from .. import spans, trace
-from .base import WireAccounting, base_metrics
+from .base import WireAccounting, account_recvs, base_metrics
 
 
 @dataclass
@@ -52,7 +52,7 @@ class LocalNetwork:
         self.dropped = 0
         # one WireAccounting per node id, shared by every endpoint handle
         # for that node (accounting must survive re-handles) and readable
-        # by _deliver for the receiver-side count at enqueue time
+        # by _deliver_many for the receiver-side count at enqueue time
         self.wire_accts: Dict[str, WireAccounting] = {}
         # optional delivery tap (ISSUE 13: the simulation runtime's
         # deterministic event trace): called (src, dst, kind, nbytes,
@@ -83,50 +83,78 @@ class LocalNetwork:
             # the wire-accounting entry points)
             self.trace = None
 
-    async def _deliver(self, src: str, dst: str, raw: bytes) -> None:
-        src_wire = self.wire_accts.get(src)
-        # classify ONCE per logical send: sender and receiver ledgers
-        # must agree on the kind for per-kind conservation to hold
-        kind = src_wire.kind_of(raw) if src_wire is not None else ""
-        q = self.queues.get(dst)
-        if q is None:
-            # unknown destination: silently dropped (fire-and-forget)
-            if src_wire is not None:
-                src_wire.account_lost("no_route", raw)
-            self._trace(src, dst, kind, len(raw), "no_route")
-            return
+    def _deliver_many(self, src: str, dests: Iterable[str], raw: bytes) -> None:
+        """Deliver one frame from ``src`` to each of ``dests``, in order.
+
+        What is the same for every destination is paid once: the kind
+        (classified ONCE per logical send: sender and receiver ledgers
+        must agree on it for per-kind conservation to hold), the send
+        stamp, and the ``(t_sent, raw)`` item every queue shares. What is
+        not is made per destination exactly as a send to one destination
+        makes it, in destination order: the partition test, the drop
+        draw, the duplicate draw, the delay draw of each copy, and the
+        tap — so a seeded plan replays the same deliveries and leaves
+        ``rng`` in the same state however the frames were grouped."""
+        src_wire = self.wire_for(src)
+        kind = src_wire.kind_of(raw)
+        size = len(raw)
+        queues = self.queues
         f = self.faults
-        if (src, dst) in f.partitions or f.rng.random() < f.drop_rate:
-            self.dropped += 1
-            # FaultPlan drops are network-side: the sender's ledger owns
-            # them (the receiver never saw the frame) — conservation:
-            # attempted = sent + lost, and sent == received
-            if src_wire is not None:
-                src_wire.account_lost("net_dropped", raw)
-            self._trace(src, dst, kind, len(raw), "dropped")
-            return
-        copies = 2 if f.rng.random() < f.duplicate_rate else 1
+        parts = f.partitions
+        rand = f.rng.random
+        drop_rate = f.drop_rate
+        dup_rate = f.duplicate_rate
         lo, hi = f.delay_range
+        tap = self.trace
         # messages are stamped at SEND: the receiver's recv span is the
         # full transport residency (injected fault delay + queue wait +
         # receiver scheduling) — the wire's leg of the critical path
         item = (time.perf_counter(), raw)
-        dst_wire = self.wire_accts.get(dst)
-        self._trace(src, dst, kind, len(raw), "deliver")
-        for _ in range(copies):
-            delay = f.rng.uniform(lo, hi) if hi > 0 else 0.0
-            if delay > 0:
-                asyncio.get_running_loop().call_later(delay, q.put_nowait, item)
-            else:
+        accepted = []  # one entry per delivered copy
+        for dst in dests:
+            q = queues.get(dst)
+            if q is None:
+                # unknown destination: silently dropped (fire-and-forget)
+                src_wire.account_lost("no_route", raw, kind)
+                if tap is not None:
+                    self._trace(src, dst, kind, size, "no_route")
+                continue
+            if (parts and (src, dst) in parts) or rand() < drop_rate:
+                self.dropped += 1
+                # FaultPlan drops are network-side: the sender's ledger
+                # owns them (the receiver never saw the frame) —
+                # conservation: attempted = sent + lost, and sent == received
+                src_wire.account_lost("net_dropped", raw, kind)
+                if tap is not None:
+                    self._trace(src, dst, kind, size, "dropped")
+                continue
+            copies = 2 if rand() < dup_rate else 1
+            if tap is not None:
+                self._trace(src, dst, kind, size, "deliver")
+            if hi <= 0:  # no delay draws: every copy is queued now
                 q.put_nowait(item)
-            self.delivered += 1
-            # accounted at the delivery decision (wire acceptance), not
-            # at dequeue: frames resident in the recv queue at a test's
-            # end must still reconcile; duplicates count per copy
-            if src_wire is not None:
-                src_wire.account_send(dst, raw, kind=kind)
-            if dst_wire is not None:
-                dst_wire.account_recv(raw, kind=kind)
+                accepted.append(dst)
+                if copies == 2:
+                    q.put_nowait(item)
+                    accepted.append(dst)
+                continue
+            for _ in range(copies):
+                delay = f.rng.uniform(lo, hi)
+                if delay > 0:
+                    asyncio.get_running_loop().call_later(
+                        delay, q.put_nowait, item)
+                else:
+                    q.put_nowait(item)
+                accepted.append(dst)
+        if not accepted:
+            return
+        self.delivered += len(accepted)
+        # accounted at the delivery decision (wire acceptance), not at
+        # dequeue: frames resident in the recv queue at a test's end
+        # must still reconcile; duplicates count per copy
+        src_wire.account_sends(accepted, kind, size)
+        wires = self.wire_accts
+        account_recvs([wires.get(d) for d in accepted], kind, size)
 
 
 class LocalEndpoint:
@@ -148,42 +176,49 @@ class LocalEndpoint:
 
     async def send(self, dest: str, raw: bytes) -> None:
         self.metrics["sent"] += 1
-        await self.net._deliver(self.node_id, dest, raw)
+        self.net._deliver_many(self.node_id, (dest,), raw)
 
     async def broadcast(self, raw: bytes, dests: Iterable[str]) -> None:
-        for dest in dests:
-            if dest != self.node_id:
-                self.metrics["sent"] += 1
-                await self.net._deliver(self.node_id, dest, raw)
+        me = self.node_id
+        targets = [d for d in dests if d != me]
+        self.metrics["sent"] += len(targets)
+        self.net._deliver_many(me, targets, raw)
 
     async def recv(self) -> bytes:
-        t_sent, raw = await self.queue.get()
-        self.metrics["recv"] += 1
-        # histogram/ring only (persist=False): one span per message is
-        # fine in memory, but must never become a JSONL line per message
-        spans.record(
-            spans.TRANSPORT_QUEUE,
-            time.perf_counter() - t_sent,
-            node=self.node_id,
-            persist=False,
-        )
-        # recv-stamp AFTER queue residency so the trace edge's recv time
-        # includes injected fault delay and queue wait (never raises; a
-        # substring gate makes unstamped frames free)
-        trace.recv_stamp(self.node_id, raw)
-        return raw
+        return self._took([await self.queue.get()])[0]
 
     def recv_nowait(self) -> Optional[bytes]:
-        try:
-            t_sent, raw = self.queue.get_nowait()
-        except asyncio.QueueEmpty:
-            return None
-        self.metrics["recv"] += 1
-        spans.record(
-            spans.TRANSPORT_QUEUE,
-            time.perf_counter() - t_sent,
-            node=self.node_id,
-            persist=False,
-        )
-        trace.recv_stamp(self.node_id, raw)
-        return raw
+        frames = self.recv_many(1)
+        return frames[0] if frames else None
+
+    def recv_many(self, limit: Optional[int] = None) -> List[bytes]:
+        """Up to ``limit`` queued frames (all when None), oldest first, in
+        one call and under one round of bookkeeping (``_took``)."""
+        q = self.queue
+        n = q.qsize()
+        if limit is not None and limit < n:
+            n = limit
+        if n <= 0:
+            return []
+        get = q.get_nowait
+        return self._took([get() for _ in range(n)])
+
+    def _took(self, items: List[Tuple[float, bytes]]) -> List[bytes]:
+        """The receive bookkeeping of ``(t_sent, raw)`` items just taken
+        off the queue, paid once for all of them: one clock read, the
+        residencies into the ``transport.queue`` histogram under one
+        acquisition of the recorder's lock (histogram only: one span per
+        message must never become a JSONL line per message), and
+        ``trace.recv_stamp`` for a frame that carries the trace envelope
+        alone — AFTER queue residency, so the edge's recv time includes
+        injected fault delay and queue wait."""
+        self.metrics["recv"] += len(items)
+        now = time.perf_counter()
+        spans.record_many(spans.TRANSPORT_QUEUE, [now - t for t, _ in items])
+        frames = [raw for _, raw in items]
+        # bytes.find, not `in`: `in` first tries the needle as an int
+        gate = trace._GATE
+        for raw in frames:
+            if raw.find(gate) >= 0:
+                trace.recv_stamp(self.node_id, raw)
+        return frames

@@ -26,7 +26,7 @@ from typing import Dict, Iterable, Optional, Tuple
 
 from .. import trace
 from ..messages import DEFERRABLE_KINDS
-from .base import WireAccounting, base_metrics
+from .base import Transport, WireAccounting, base_metrics
 
 log = logging.getLogger("pbft.tcp")
 
@@ -83,7 +83,7 @@ def _item_deferrable(item: list) -> bool:
     return item[2]
 
 
-class TcpTransport:
+class TcpTransport(Transport):
     """One node's TCP endpoint: a listening server + per-peer senders.
 
     peers: node_id -> (host, port) for every node we may send to.

@@ -24,9 +24,10 @@ from simple_pbft_tpu.crypto import ed25519_cpu
 from simple_pbft_tpu.crypto.mac import MacBank
 from simple_pbft_tpu.crypto.verifier import BatchItem
 from simple_pbft_tpu.messages import Message, Reply
+from simple_pbft_tpu.transport.base import Transport
 
 
-class FakeTransport:
+class FakeTransport(Transport):
     def __init__(self, node_id: str):
         self.node_id = node_id
         self.q: asyncio.Queue = asyncio.Queue()

@@ -18,9 +18,10 @@ import pytest
 from simple_pbft_tpu.committee import LocalCommittee
 from simple_pbft_tpu.crypto.signer import Signer
 from simple_pbft_tpu.messages import Commit, Message, PrePrepare, Prepare, Request
+from simple_pbft_tpu.transport.base import Transport
 
 
-class PassthroughTransport:
+class PassthroughTransport(Transport):
     """Base for Byzantine transport wrappers: subclasses override
     _mutate(raw) and/or broadcast."""
 
@@ -165,6 +166,18 @@ class SharePoisoningTransport(PassthroughTransport):
         return raw
 
 
+class DelayedTransport(PassthroughTransport):
+    """Every frame this node sends leaves 0.15 s late."""
+
+    async def send(self, dest, raw):
+        await asyncio.sleep(0.15)
+        await self.inner.send(dest, raw)
+
+    async def broadcast(self, raw, dests):
+        await asyncio.sleep(0.15)
+        await self.inner.broadcast(raw, dests)
+
+
 @pytest.mark.slow
 def test_qc_byzantine_share_poisoner_is_bisected_out():
     async def main():
@@ -176,12 +189,10 @@ def test_qc_byzantine_share_poisoner_is_bisected_out():
         plan = FaultPlan(seed=3)
         c = LocalCommittee.build(n=4, clients=1, qc_mode=True,
                                  view_timeout=6.0, fault_plan=plan)
-        real_deliver = c.net._deliver
-        async def slow_r3(src, dst, raw):
-            if src == "r3":
-                await asyncio.sleep(0.15)
-            await real_deliver(src, dst, raw)
-        c.net._deliver = slow_r3
+        slow = c.replica("r3")
+        slow.transport = DelayedTransport(
+            slow.transport, Signer("r3", c.keys["r3"].seed)
+        )
         evil = c.replica("r1")  # a BACKUP poisons its vote shares
         evil.transport = SharePoisoningTransport(
             evil.transport, Signer("r1", c.keys["r1"].seed)

@@ -20,9 +20,10 @@ from simple_pbft_tpu.config import make_test_committee
 from simple_pbft_tpu.crypto import mac as mac_mod
 from simple_pbft_tpu.crypto.signer import Signer
 from simple_pbft_tpu.messages import Reply, ReplyBatch
+from simple_pbft_tpu.transport.base import Transport
 
 
-class FakeTransport:
+class FakeTransport(Transport):
     """Message sink + injectable inbox (no network)."""
 
     def __init__(self, node_id: str):
