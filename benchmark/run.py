@@ -8,10 +8,15 @@ cell, a configuration or a per-layer metric is adding such a file (and its
 entry in BENCHMARK.json): nothing here names one.
 
 Set-up (``setup_s``: start of the process to start of the window) is
-identify -> warm the verifier -> planted-failure batch against the oracle ->
+identify -> hold the configuration's stated keys to what the harness builds
+(``stages.check_config``: a key it cannot build is refused) -> warm the
+verifier -> planted-failure batch against the oracle -> in a certificate
+committee, a planted-failure batch of certificates through the lane ->
 start the committee -> a few seconds of the cell's own traffic, unmeasured.
 Then the window: closed-loop puts for ``--seconds``. Then drain, read back,
-and hold the committee to its configuration's guarantees (``correct``).
+and hold the committee to its configuration's guarantees (``correct``); the
+certificates' verdicts meet their plain reference (``bls_ref.py``) only
+then, outside set-up.
 ``--trace 1`` is a run of its own with the JAX profiler open for a few
 seconds in the middle of the window; it reports the per-layer metrics, the
 device's busy seconds and a breakdown. ``--trace 0`` starts no profiler and
@@ -43,6 +48,7 @@ for _p in (os.path.dirname(HERE), HERE):  # the program's package; our modules
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+import certificates  # noqa: E402
 import stages  # noqa: E402
 import trace_reduce  # noqa: E402
 
@@ -154,10 +160,19 @@ def run(args) -> dict:
 
     from simple_pbft_tpu.config import make_test_committee
 
-    _cfg, keys = make_test_committee(n=config["n"], clients=config["clients"])
+    qc = stages.qc_mode(config)
+    cfg, keys = make_test_committee(n=config["n"], clients=config["clients"],
+                                    qc_mode=qc)
     pubkeys = [kp.pub for kp in keys.values()]
+    stages.check_config(config, cfg, len(pubkeys))
     service = stages.build_verifier(config, pubkeys)
+    try:
+        stages.check_config(config, cfg, len(pubkeys), service)
+    except SystemExit:
+        service.close()
+        raise
     stages.kernel_stage(config, args.seed, keys, service.device, stamp, checks)
+    lane_checked = certificates.gate(args.seed, cfg, keys) if qc else []
 
     trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_") if args.trace else None
     try:
@@ -167,6 +182,7 @@ def run(args) -> dict:
         ))
         stages.chip_stage(service, stamp, checks)
         service.close()
+        certificates.compare(lane_checked, config, checks)
         trace: dict = {}
         if args.trace:
             try:

@@ -45,6 +45,104 @@ class Checks:
 
 
 # ---------------------------------------------------------------------------
+# the configuration: every stated key is built or refused
+# ---------------------------------------------------------------------------
+
+# what a signature scheme builds: the committee's qc_mode
+SCHEMES = {"ed25519": False, "bls12-381-qc": True}
+# the pump's one operation (served_stage)
+OPERATION = "put k<i> v<12 hex digits>"
+# read by the harness to build the run
+BUILT = ("platform", "n", "clients", "keys", "block", "checkpoint_interval",
+         "watermark_window", "view_timeout_s", "request_timeout_s",
+         "signatures", "verifier")
+# prose, and the kernel gate's own sizes
+PASSED_OVER = ("name", "source", "why", "reduced", "assumed", "guarantees",
+               "kernel_check")
+# what the harness can build, for a key it does not read
+FIXED = {"transport": "local", "processes": 1, "app": "KVStore",
+         "message_delay_ms": 0, "speculative": "default",
+         "operation": OPERATION}
+VERIFIER = ("backend", "mode", "window", "max_batch", "cpu_cutoff",
+            "key_bank", "signing_keys")
+
+
+def qc_mode(config: dict) -> bool:
+    """The committee's qc_mode for the file's ``signatures``; a file that
+    states none is a plain Ed25519 one (the test committees')."""
+    scheme = config.get("signatures", "ed25519")
+    if scheme not in SCHEMES:
+        raise SystemExit(
+            f"config {config.get('name')}: signatures is {scheme!r} where "
+            f"the harness builds one of {sorted(SCHEMES)}")
+    return SCHEMES[scheme]
+
+
+def check_config(config: dict, cfg, population: int, service=None) -> None:
+    """Every key the file states is one the harness builds, and equals
+    what was built: ``cfg`` is the committee's CommitteeConfig,
+    ``population`` the keys handed to the verifier. Without ``service``
+    (before the warm) the keys that need the device are not yet checked.
+    A mismatch is a SystemExit naming the key, the file's value and the
+    built value."""
+    name = config.get("name")
+    unknown = sorted(set(config) - set(BUILT) - set(PASSED_OVER)
+                     - set(FIXED) - {"f"})
+    if unknown:
+        raise SystemExit(f"config {name}: {unknown[0]} is a key the harness "
+                         "neither builds nor checks")
+    missing = [key for key in BUILT if key not in config]
+    if missing:
+        raise SystemExit(f"config {name}: states no {missing[0]}")
+    check_committee(config, cfg)
+    for key, built in FIXED.items():
+        if key in config and config[key] != built:
+            _refuse(name, key, config[key], built,
+                    ": delay on the served path is ROADMAP R5(c)"
+                    if key == "message_delay_ms" else "")
+    vcfg = config["verifier"]
+    unknown = sorted(set(vcfg) - set(VERIFIER))
+    if unknown:
+        raise SystemExit(f"config {name}: verifier.{unknown[0]} is a key "
+                         "the harness neither builds nor checks")
+    built = {"backend": "tpu", "signing_keys": population}
+    if service is not None:
+        device = service.device
+        built.update(mode=device._mode, window=device._window,
+                     key_bank=device._bank._cap)
+        held = len(device._bank._index)
+        if held != min(population, device._bank._cap):
+            _refuse(name, "verifier.signing_keys", vcfg.get("signing_keys"),
+                    held, " keys in the bank")
+    for key, value in built.items():
+        if key in vcfg and vcfg[key] != value:
+            _refuse(name, f"verifier.{key}", vcfg[key], value)
+
+
+def check_committee(config: dict, cfg) -> None:
+    """The committee as built holds what the file states: its ``f``, the
+    speculation it runs (``default``: CommitteeConfig's own) and the
+    ``signatures`` scheme (its qc_mode). Called on the key set-up's
+    committee before the warm and on the one served_stage times."""
+    from simple_pbft_tpu.config import CommitteeConfig
+
+    name = config.get("name")
+    if "f" in config and config["f"] != cfg.f:
+        _refuse(name, "f", config["f"], cfg.f)
+    if cfg.speculative != CommitteeConfig.speculative:
+        _refuse(name, "speculative", config.get("speculative"),
+                cfg.speculative)
+    if cfg.qc_mode != qc_mode(config):
+        _refuse(name, "signatures", config.get("signatures", "ed25519"),
+                f"qc_mode={cfg.qc_mode}")
+
+
+def _refuse(name, key: str, stated, built, why: str = "") -> None:
+    raise SystemExit(f"config {name}: {key} is {stated!r} where the "
+                     f"harness builds {built!r}{why}")
+
+
+# ---------------------------------------------------------------------------
 # set-up: identify -> warm -> kernel
 # ---------------------------------------------------------------------------
 
@@ -235,10 +333,12 @@ def _summed(dicts) -> dict:
 
 
 def counter_surfaces(com, service) -> dict:
-    """The four counter surfaces a ``counter:<surface>.<key>`` reader sees:
+    """The five counter surfaces a ``counter:<surface>.<key>`` reader sees:
     ``verify`` (VerifyService.snapshot()), ``wire`` (every node's
     WireAccounting, summed: totals and ``<kind>.<total>``), ``clients`` and
-    ``replicas`` (their ``metrics`` dicts, summed)."""
+    ``replicas`` (their ``metrics`` dicts, summed), and ``qc`` (the
+    process's certificate lane, QcVerifyLane.snapshot(); empty where no
+    certificate was ever checked, as in a plain Ed25519 committee)."""
     wire: dict = {}
     for acct in com.net.wire_accts.values():
         for kind, row in acct.per_kind().items():
@@ -250,7 +350,14 @@ def counter_surfaces(com, service) -> dict:
         "wire": wire,
         "clients": _summed(c.metrics for c in com.clients),
         "replicas": _summed(r.metrics for r in com.replicas),
+        "qc": lane_counters(),
     }
+
+
+def lane_counters() -> dict:
+    from simple_pbft_tpu.consensus import qc
+
+    return _flatten(qc.lane_snapshot() or {})
 
 
 def counter_deltas(before: dict, after: dict) -> dict:
@@ -289,20 +396,23 @@ async def served_stage(cell: dict, config: dict, seed: int, seconds: float,
     if pumps > n_keys:
         raise SystemExit(f"in_flight {pumps} exceeds the {n_keys} keys: two "
                          "writes to one key would be in flight together")
+    qc = qc_mode(config)
     com = LocalCommittee.build(
         n=n, clients=config["clients"], verifier_factory=lambda: service,
         max_batch=config["block"], view_timeout=config["view_timeout_s"],
         checkpoint_interval=config["checkpoint_interval"],
-        watermark_window=config["watermark_window"],
+        watermark_window=config["watermark_window"], qc_mode=qc,
     )
     if [kp.pub for kp in com.keys.values()] != pubkeys:
         raise SystemExit("served: committee keys differ from the warmed "
                          "population")
+    check_committee(config, com.cfg)
     for c in com.clients:
         c.request_timeout = config["request_timeout_s"]
     say("served", n=n, f=com.cfg.f, reply_quorum=com.cfg.weak_quorum,
         clients=len(com.clients), in_flight=pumps, block=config["block"],
-        speculative=str(com.cfg.speculative).lower())
+        speculative=str(com.cfg.speculative).lower(),
+        signatures=config.get("signatures", "ed25519"))
 
     # each pump owns its keys and writes them in sequence, so two writes to
     # one key are never in flight together and the reference is exact. Every
@@ -334,6 +444,7 @@ async def served_stage(cell: dict, config: dict, seed: int, seconds: float,
             records.append((t0, time.perf_counter(), ok))
 
     loop = asyncio.get_running_loop()
+    lane_before = lane_counters()
     com.start()
     tasks = [asyncio.ensure_future(pump(i)) for i in range(pumps)]
     await asyncio.sleep(cell["warmup_seconds"])
@@ -403,6 +514,9 @@ async def served_stage(cell: dict, config: dict, seed: int, seconds: float,
                  if json.loads(r.app.snapshot()) != reference]
     client_metrics = _summed(c.metrics for c in com.clients)
     await com.stop()
+    if qc:
+        hold_lane(lane_before, lane_counters(),
+                  _summed(r.metrics for r in com.replicas), checks)
 
     checks.expect(not late, f"served: {len(late)} pumps did not drain")
     checks.expect(len(running) == n, f"served: {len(running)}/{n} replicas running")
@@ -430,6 +544,30 @@ async def served_stage(cell: dict, config: dict, seed: int, seconds: float,
         "spans": stage_summaries,
         "traced": traced,
     }
+
+
+def hold_lane(before: dict, after: dict, replicas: dict,
+              checks: Checks) -> None:
+    """A certificate committee, from its start to its stop: every
+    certificate the lane checked verified, none was shed or bisected, no
+    replica received a bad one, and certificates, not plain votes, drove
+    the committee."""
+    delta = {key: after.get(key, 0) - before.get(key, 0)
+             for key in ("submitted", "verified_true", "verified_false",
+                         "overload_rejections", "structural_rejects",
+                         "batch_fallbacks", "batches", "batch_items")}
+    say("qc", bad_qc=replicas.get("bad_qc", 0),
+        qcs_formed=replicas.get("qcs_formed", 0), **delta)
+    bad = {key: delta[key] for key in ("verified_false", "overload_rejections",
+                                       "structural_rejects", "batch_fallbacks")
+           if delta[key]}
+    if replicas.get("bad_qc", 0):
+        bad["bad_qc"] = replicas["bad_qc"]
+    checks.expect(not bad, f"qc: a certificate failed or was shed: {bad}")
+    checks.expect(delta["submitted"] > 0 and delta["verified_true"] > 0,
+                  "qc: the lane verified no certificate, so none drove the "
+                  f"committee: submitted {delta['submitted']}, verified_true "
+                  f"{delta['verified_true']}")
 
 
 def window_metrics(records: list, t_start: float, t_end: float) -> dict:

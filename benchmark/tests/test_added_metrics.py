@@ -75,4 +75,5 @@ def test_no_metric_file_uses_a_statistic_the_summary_lacks():
         kind, _, rest = source.partition(":")
         assert kind in ("span", "counter", "trace"), path
         if kind == "span":
-            assert rest.rpartition(":")[2] in ("mean", "sum", "p50", "p90", "p99")
+            assert rest.rpartition(":")[2] in ("mean", "sum", "count", "p50",
+                                               "p90", "p99")

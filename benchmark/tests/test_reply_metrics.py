@@ -40,8 +40,10 @@ def test_files_load_for_every_cell_and_name_their_counters():
     assert specs["batched_replies_per_req"]["better"] == "higher"
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as fh:
         layer = json.load(fh)["per_layer"]
-    # appended at the end of the list, in this order
-    assert [m["name"] for m in layer[-2:]] == list(NAMES)
+    # appended together, in this order (later metrics follow them)
+    names = [m["name"] for m in layer]
+    first = names.index(list(NAMES)[0])
+    assert names[first:first + len(NAMES)] == list(NAMES)
 
 
 def test_counter_surfaces_expose_them_from_the_first_frame():
