@@ -34,7 +34,7 @@ from collections import OrderedDict
 from concurrent.futures import Future
 from typing import Dict, List, Optional, Tuple
 
-from .. import devledger, sanitize, spans
+from .. import clock, devledger, sanitize, spans
 from ..crypto import bls
 from ..messages import QuorumCert, qc_payload
 
@@ -58,7 +58,12 @@ _inflight: Dict[tuple, threading.Event] = {}
 
 def sign_share(bls_sk: int, phase: str, view: int, seq: int, digest: str) -> str:
     """One replica's BLS share over the QC payload, hex for the wire."""
-    return bls.sign(bls_sk, qc_payload(phase, view, seq, digest)).hex()
+    payload = qc_payload(phase, view, seq, digest)
+    t0 = clock.now()
+    share = bls.sign(bls_sk, payload)
+    # histogram only: 2n shares a block would flush the autopsy's ring
+    spans.record(spans.QC_SIGN_SHARE, clock.now() - t0, persist=False)
+    return share.hex()
 
 
 def share_valid_shape(share_hex: str) -> bool:
@@ -89,7 +94,9 @@ def build_qc(
         raws = [bytes.fromhex(shares[s]) for s in signers]
     except ValueError:
         return None
+    t0 = clock.now()
     agg = bls.aggregate_signatures(raws)
+    spans.record(spans.QC_AGGREGATE, clock.now() - t0)
     if agg is None:
         return None
     return QuorumCert(

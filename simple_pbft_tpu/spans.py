@@ -38,6 +38,11 @@ no dict is built unless the span is exported or persisted. Stages:
   verify.bank_build  the warm's key-table build and its one upload
   qc.queue           QcVerifyLane wait (cert submit -> batch start)
   qc.pairing         one RLC multi-pairing batch
+  qc.sign_share      one BLS share signed (a vote's or a checkpoint's;
+                     inside loop.sign_vote where the loop signs it;
+                     histogram only)
+  qc.aggregate       one certificate's shares aggregated (build_qc: the
+                     primary's first build and its rebuild after a bisect)
   replica.verify_wait  a sweep's verify from the replica's seat (queue +
                        device + resolution, the full service round trip)
   phase.prepare      pre-prepare admission -> slot prepared
@@ -148,6 +153,8 @@ VERIFY_REROUTE = "verify.cpu_reroute"
 VERIFY_BANK_BUILD = "verify.bank_build"
 QC_QUEUE = "qc.queue"
 QC_PAIRING = "qc.pairing"
+QC_SIGN_SHARE = "qc.sign_share"
+QC_AGGREGATE = "qc.aggregate"
 REPLICA_VERIFY_WAIT = "replica.verify_wait"
 PHASE_PREPARE = "phase.prepare"
 PHASE_COMMIT = "phase.commit"

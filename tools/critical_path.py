@@ -19,7 +19,10 @@ Two views:
 2. **Pipeline stages** — every stage's own latency distribution
    (verify.queue / verify.device / verify.cpu / qc.* / transport.queue /
    client.e2e), with counts and total time, so "coalesce wait dominates
-   device RTT 3:1" is one table row comparison.
+   device RTT 3:1" is one table row comparison. The certificate stages
+   are qc.queue and qc.pairing (the lane) and qc.aggregate (the
+   primary's build); qc.sign_share, one a share, is histogram only and
+   so reaches snapshots, not span files.
 
 Usage:
   python tools/critical_path.py --log-dir dep/log
