@@ -24,13 +24,19 @@ threads and the drain sweep — the r5 qc256 wedge shape (15 s verify RTT,
 zero commits). The lane keeps certificate crypto off both the event loop
 and the shared executor, and its counters (queue depth, batch size,
 pairing latency) feed the telemetry plane.
+
+``QcSignLane`` is the same idea for the other end of a certificate: the
+replicas' BLS shares are signed on worker threads, a pile per native
+call, instead of on the event loop's thread (``sign_share_async``).
 """
 
 from __future__ import annotations
 
+import asyncio
+import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from concurrent.futures import Future
 from typing import Dict, List, Optional, Tuple
 
@@ -540,10 +546,20 @@ def qc_lane() -> QcVerifyLane:
 
 
 def lane_snapshot() -> Optional[dict]:
-    """Snapshot of the process lane, or None when no QC was ever
-    submitted (non-QC committees pay nothing for the lane existing)."""
+    """Snapshot of the process's certificate lanes: the verify lane's
+    counters and the signing lane's (``sign_shares``, ``sign_batches``,
+    ``sign_inline``, and the gauges ``sign_pending``, ``sign_inflight``),
+    or None when no certificate was ever checked and no
+    share signed (non-QC committees pay nothing for the lanes existing)."""
     with _lane_lock:
-        return _lane.snapshot() if _lane is not None else None
+        snap = _lane.snapshot() if _lane is not None else None
+        signing = _sign_lane.counters() if _sign_lane is not None else {
+            "sign_shares": 0, "sign_batches": 0, "sign_pending": 0,
+            "sign_inflight": 0}
+    signing["sign_inline"] = _sign_inline
+    if snap is None and not any(signing.values()):
+        return None
+    return {**(snap or {}), **signing}
 
 
 async def verify_qc_async(cfg, qc: QuorumCert) -> bool:
@@ -557,12 +573,177 @@ async def verify_qc_async(cfg, qc: QuorumCert) -> bool:
     race it. Loop-blocking is harmless there (nothing real-time shares
     a simulated loop), and the verdict memo keeps the cost one pairing
     per distinct certificate either way."""
-    import asyncio
-
-    from .. import clock
-
     if clock.simulated():
         # pbftlint: disable=PBL001 -- sim-only branch: clock.simulated() gates it off every production loop; blocking a simulated loop is the determinism contract, not a stall
         return verify_qc(cfg, qc)
     with spans.parked():  # suspends under loop.route: not loop-held time
         return await asyncio.wrap_future(qc_lane().submit(cfg, qc))
+
+
+# ---------------------------------------------------------------------------
+# Off-loop share signing lane
+# ---------------------------------------------------------------------------
+
+
+# shares a worker takes at most: what is pending when it wakes, so the
+# queue's depth sets the chunk. 32 shares sign for about 30 ms on a
+# served host (0.9 ms a share), some six switch intervals, so the wait
+# to retake the interpreter lock at a chunk's end (up to one interval
+# while the loop is busy) is a small part of it; a deeper pile goes to
+# the next worker.
+SIGN_CHUNK_MAX = 32
+
+
+def sign_workers() -> int:
+    """Signing threads for this process: the cores it may run on, less
+    the four threads a served replica keeps busy (the event loop, the
+    verify dispatcher and finisher, the certificate verify lane), 1 to
+    8."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except (AttributeError, OSError):
+        cores = os.cpu_count() or 1
+    return max(1, min(8, cores - 4))
+
+
+class QcSignLane:
+    """Worker threads that sign BLS shares off the event loop.
+
+    Each share is signed from scratch with its replica's own key, byte
+    for byte what ``bls.sign`` gives; nothing is cached or shared between
+    shares. A worker takes what is pending (at most ``SIGN_CHUNK_MAX``:
+    the queue's depth sets the chunk, and a deeper pile is the next
+    worker's), signs it in ONE
+    native call (``bls.sign_many``: one release and one retake of the
+    interpreter lock for the chunk, not one a share), and hands the
+    chunk's results back with one ``call_soon_threadsafe`` per event
+    loop. Nothing is shed: a committee has at most 2n shares a block
+    outstanding. Every share a worker takes resolves, to its bytes or to
+    the error that stopped the chunk.
+    """
+
+    def __init__(self, workers: int) -> None:
+        self._workers = workers
+        self._cond = threading.Condition(
+            sanitize.wrap_lock(threading.Lock(), "qc.sign_lane.cond")
+        )
+        self._pending: deque = deque()  # (sk, payload, loop, future)
+        self._started = False
+        self._inflight = 0  # shares taken and not yet handed back
+        self.shares = 0   # shares taken by a worker
+        self.batches = 0  # native calls (chunks)
+
+    def submit(self, sk: int, payload: bytes) -> "asyncio.Future[bytes]":
+        """Queue one share; the future resolves, on the caller's loop, to
+        its 96 bytes. Call from the loop's thread."""
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+        with self._cond:
+            self._pending.append((sk, payload, loop, fut))
+            if not self._started:
+                self._started = True
+                for i in range(self._workers):
+                    threading.Thread(
+                        target=self._worker, name=f"qc-sign-lane-{i}",
+                        daemon=True,
+                    ).start()
+            self._cond.notify()
+        return fut
+
+    def _take(self) -> list:
+        with self._cond:
+            while not self._pending:
+                self._cond.wait()
+            k = min(SIGN_CHUNK_MAX, len(self._pending))
+            take = [self._pending.popleft() for _ in range(k)]
+            self._inflight += k
+            self.shares += k
+            self.batches += 1
+            if self._pending:
+                self._cond.notify()  # the rest is another worker's
+            return take
+
+    def _worker(self) -> None:
+        while True:
+            take = self._take()
+            try:
+                self._sign(take)
+            except Exception:  # noqa: BLE001 — handed back in _sign's finally
+                pass  # a fault after the signing (the span) stops no lane
+            finally:
+                with self._cond:
+                    self._inflight -= len(take)
+
+    def _sign(self, take: list) -> None:
+        sigs: Optional[List[bytes]] = None
+        error: Optional[BaseException] = None
+        try:
+            t0 = time.perf_counter()
+            try:
+                sigs = bls.sign_many([t[0] for t in take],
+                                     [t[1] for t in take])
+            except Exception as exc:  # noqa: BLE001 — the votes must resume
+                error = exc
+            per_share = (time.perf_counter() - t0) / len(take)
+            for _ in take:  # one sample a share, as when the loop signed it
+                spans.record(spans.QC_SIGN_SHARE, per_share, persist=False)
+        finally:
+            if sigs is None and error is None:
+                error = RuntimeError("signing lane: the chunk was not signed")
+            by_loop: Dict[asyncio.AbstractEventLoop, list] = {}
+            for i, (_sk, _payload, loop, fut) in enumerate(take):
+                by_loop.setdefault(loop, []).append(
+                    (fut, sigs[i] if sigs is not None else None))
+            for loop, done in by_loop.items():
+                try:
+                    loop.call_soon_threadsafe(_resolve_shares, done, error)
+                except RuntimeError:
+                    pass  # that loop has closed: nothing awaits its shares
+
+    def counters(self) -> dict:
+        with self._cond:
+            return {"sign_shares": self.shares, "sign_batches": self.batches,
+                    "sign_pending": len(self._pending),
+                    "sign_inflight": self._inflight}
+
+
+def _resolve_shares(done: list, error: Optional[BaseException]) -> None:
+    for fut, sig in done:
+        if fut.done():
+            continue
+        if error is not None:
+            fut.set_exception(error)
+        else:
+            fut.set_result(sig)
+
+
+_sign_lane: Optional[QcSignLane] = None
+# shares signed on the caller's thread, under simulation
+_sign_inline = 0
+
+
+def sign_lane() -> QcSignLane:
+    """The process-wide signing lane (lazily created: a committee
+    without certificates never starts its threads)."""
+    global _sign_lane
+    with _lane_lock:
+        if _sign_lane is None:
+            _sign_lane = QcSignLane(sign_workers())
+        return _sign_lane
+
+
+async def sign_share_async(
+    bls_sk: int, phase: str, view: int, seq: int, digest: str
+) -> str:
+    """``sign_share`` as the runtime calls it: the share is signed on the
+    signing lane's threads, and the caller's open loop sections are
+    parked while it waits. Under simulation the share is signed inline:
+    the lane's threads run in wall time, which a virtual clock outruns
+    (as in ``verify_qc_async``)."""
+    global _sign_inline
+    if clock.simulated():
+        _sign_inline += 1
+        return sign_share(bls_sk, phase, view, seq, digest)
+    fut = sign_lane().submit(bls_sk, qc_payload(phase, view, seq, digest))
+    with spans.parked():
+        return (await fut).hex()

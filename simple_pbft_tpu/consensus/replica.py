@@ -1592,17 +1592,7 @@ class Replica:
         every replica (O(n^2) votes committee-wide). QC mode: attach a BLS
         share and send to the view's primary ONLY (O(n)); the primary
         aggregates 2f+1 shares into a QuorumCert."""
-        if self.vc.in_view_change:
-            # frozen: no votes leave this replica between VIEW-CHANGE and
-            # NEW-VIEW (QC-mode commit execution may still reach here)
-            self.metrics["vote_suppressed_in_vc"] += 1
-            return
-        if self.retired:
-            # removed by a committed reconfiguration: an honest retiree
-            # goes silent on the consensus plane (peers would role-gate
-            # the votes out anyway — see faults.StaleEpochVoter for the
-            # byzantine replica that refuses to)
-            self.metrics["vote_suppressed_retired"] += 1
+        if self._vote_suppressed():
             return
         vote = cls(view=act.view, seq=act.seq, digest=act.digest)
         # our own vote is self-delivered (_on_phase below) and never
@@ -1610,9 +1600,20 @@ class Replica:
         self.qstats.note_vote(phase, act.view, act.seq, self.id)
         if self.cfg.qc_mode:
             with spans.held(spans.LOOP_SIGN_VOTE):
-                vote.bls_share = qc_mod.sign_share(
+                # signed on the signing lane's threads; the section is
+                # parked while the share is away
+                share = await qc_mod.sign_share_async(
                     self.bls_sk, phase, act.view, act.seq, act.digest
                 )
+            if self._vote_suppressed():  # began while the share was away
+                return
+            if act.view != self.view:
+                # a view change began and ended while the share was
+                # away: the old view's primary collects it no longer
+                self.metrics["vote_suppressed_stale_view"] += 1
+                return
+            vote.bls_share = share
+            with spans.held(spans.LOOP_SIGN_VOTE):
                 self.signer.sign_msg(vote)
             primary = self.cfg.primary(act.view)
             if primary == self.id:
@@ -1638,6 +1639,21 @@ class Replica:
         finally:
             spans.end(spans.LOOP_SEND, len(self.cfg.replica_ids) - 1)
         await self._on_phase(vote)  # count own vote
+
+    def _vote_suppressed(self) -> bool:
+        if self.vc.in_view_change:
+            # frozen: no votes leave this replica between VIEW-CHANGE and
+            # NEW-VIEW (QC-mode commit execution may still reach here)
+            self.metrics["vote_suppressed_in_vc"] += 1
+            return True
+        if self.retired:
+            # removed by a committed reconfiguration: an honest retiree
+            # goes silent on the consensus plane (peers would role-gate
+            # the votes out anyway — see faults.StaleEpochVoter for the
+            # byzantine replica that refuses to)
+            self.metrics["vote_suppressed_retired"] += 1
+            return True
+        return False
 
     # ------------------------------------------------------------------
     # ordered execution
@@ -2203,7 +2219,7 @@ class Replica:
             if self.cfg.qc_mode and self.bls_sk is not None:
                 # share for the aggregate checkpoint certificate (view
                 # pinned to 0: checkpoints are view-independent)
-                cp.bls_share = qc_mod.sign_share(
+                cp.bls_share = await qc_mod.sign_share_async(
                     self.bls_sk, "checkpoint", 0, seq, digest
                 )
             self.signer.sign_msg(cp)

@@ -190,6 +190,8 @@ def _configure_bls(lib):
     lib.bls_verify_batch_rlc.restype = ctypes.c_int
     lib.bls_sign.argtypes = [u8p, u8p, i64, u8p, i64, u8p]
     lib.bls_sign.restype = ctypes.c_int
+    lib.bls_sign_many.argtypes = [u8p, u8p, _i64p, i64, u8p, i64, u8p, u8p]
+    lib.bls_sign_many.restype = i64
     lib.bls_pubkey.argtypes = [u8p, u8p]
     lib.bls_pubkey.restype = ctypes.c_int
     lib.bls_selftest.argtypes = []
@@ -322,6 +324,37 @@ def bls_sign(sk: int, msg: bytes, dst: bytes) -> Optional[bytes]:
         len(dst), out,
     )
     return bytes(out) if r else None
+
+
+def bls_sign_many(
+    sks: Sequence[int], msgs: Sequence[bytes], dst: bytes
+) -> Optional[List[Optional[bytes]]]:
+    """``bls_sign`` for each (sks[i], msgs[i]) in ONE native call (one
+    release of the interpreter lock for the pile): item i is what
+    ``bls_sign(sks[i], msgs[i], dst)`` returns, None where that refuses
+    (an out-of-range or degenerate scalar: the caller falls back). None
+    = library unavailable."""
+    lib = _load_bls()
+    if lib is None:
+        return None
+    n = len(sks)
+    in_range = [0 <= sk < (1 << 256) for sk in sks]
+    # a refused scalar signs as 0, which the native path refuses too
+    sk_cat = b"".join(
+        (sk if ok else 0).to_bytes(32, "big") for sk, ok in zip(sks, in_range)
+    )
+    msg_cat, offs = _concat_offsets(msgs)
+    out = (ctypes.c_uint8 * (96 * max(1, n)))()
+    ok = (ctypes.c_uint8 * max(1, n))()
+    lib.bls_sign_many(
+        _cbuf(sk_cat), msg_cat.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        offs, n, _cbuf(dst), len(dst), out, ok,
+    )
+    raw = bytes(out)
+    return [
+        raw[96 * i:96 * i + 96] if ok[i] and in_range[i] else None
+        for i in range(n)
+    ]
 
 
 def bls_pubkey(sk: int) -> Optional[bytes]:

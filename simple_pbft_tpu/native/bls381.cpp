@@ -1522,6 +1522,23 @@ int bls_sign(const uint8_t* sk_be, const uint8_t* msg, int64_t msg_len,
   return 1;
 }
 
+// n independent signatures in one call: share i is bls_sign(sks_be + 32i,
+// msgs[offs[i]:offs[i+1]], dst) into out96 + 96i, each from its own
+// hash_to_g1 and scalar multiplication (nothing is shared between
+// items); ok[i] is that call's 1 or 0. Returns the number of 1s.
+int64_t bls_sign_many(const uint8_t* sks_be, const uint8_t* msgs,
+                      const int64_t* offs, int64_t n, const uint8_t* dst,
+                      int64_t dst_len, uint8_t* out96, uint8_t* ok) {
+  int64_t good = 0;
+  for (int64_t i = 0; i < n; i++) {
+    ok[i] = (uint8_t)bls_sign(sks_be + 32 * i, msgs + offs[i],
+                              offs[i + 1] - offs[i], dst, dst_len,
+                              out96 + 96 * i);
+    good += ok[i];
+  }
+  return good;
+}
+
 // pubkey = G2_gen^sk, 192-byte uncompressed out. 1 = ok, 0 = degenerate.
 int bls_pubkey(const uint8_t* sk_be, uint8_t* out192) {
   bls_init();

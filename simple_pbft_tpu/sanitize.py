@@ -289,6 +289,7 @@ LOCK_RANKS: Dict[str, Dict[str, Any]] = {
     "verify_service.cond": {"rank": 20},
     "verify_service.done_cond": {"rank": 25},  # nests inside .cond
     "qc.lane.cond": {"rank": 30},
+    "qc.sign_lane.cond": {"rank": 35, "leaf": True},
     "spans.recorder": {"rank": 40, "group": "spans"},
     "spans.sink": {"rank": 45, "group": "spans"},
     "qc.cache": {"rank": 90, "leaf": True},

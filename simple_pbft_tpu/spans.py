@@ -38,9 +38,11 @@ no dict is built unless the span is exported or persisted. Stages:
   verify.bank_build  the warm's key-table build and its one upload
   qc.queue           QcVerifyLane wait (cert submit -> batch start)
   qc.pairing         one RLC multi-pairing batch
-  qc.sign_share      one BLS share signed (a vote's or a checkpoint's;
-                     inside loop.sign_vote where the loop signs it;
-                     histogram only)
+  qc.sign_share      one BLS share signed (a vote's or a checkpoint's):
+                     on the signing lane's threads, a chunk's wall time
+                     over its shares, one sample a share; inside
+                     loop.sign_vote only where the share is signed
+                     inline (under simulation); histogram only
   qc.aggregate       one certificate's shares aggregated (build_qc: the
                      primary's first build and its rebuild after a bisect)
   replica.verify_wait  a sweep's verify from the replica's seat (queue +
@@ -70,8 +72,10 @@ never spans an ``await`` that can suspend: where code awaits, the section
 starts after it. The awaits inside sections are ``transport.send`` /
 ``broadcast``, which no transport in the tree suspends in (they deliver
 or enqueue; transport/base.py states it, tests/test_loop_stages.py holds
-all three to it), and three in QC mode that do suspend and so sit in
-``with spans.parked()``, which sets the open sections aside meanwhile.
+all three to it), and four in QC mode that do suspend and so sit in
+``with spans.parked()``, which sets the open sections aside meanwhile
+(a certificate's verdict, a bisect, a view change's certificate check,
+and a share on the signing lane).
 
   loop.ingest        drain + decode + shed + signature items of a sweep
   loop.sigcache      sha256 cache keys and the LRU, when on the loop

@@ -778,7 +778,10 @@ class ProgressWatchdog:
         if getattr(svc, "_pending_items", 0) or getattr(svc, "_inflight", 0):
             return True
         lane = qc_lane_snapshot()
-        if lane is not None and (lane["pending"] or lane["inflight"]):
+        if lane is not None and any(
+            lane.get(k) for k in ("pending", "inflight",
+                                  "sign_pending", "sign_inflight")
+        ):
             return True
         return False
 

@@ -183,6 +183,12 @@ def test_committee_commits_through_coalescing_service():
                 *(com.clients[0].submit(f"put k{i} v{i}") for i in range(12))
             )
             assert results == ["ok"] * 12
+            # f+1 replies answer the client; let the slowest replica
+            # apply the last block before the states are compared
+            for _ in range(250):
+                if len({r.executed_seq for r in com.replicas}) == 1:
+                    break
+                await asyncio.sleep(0.02)
         finally:
             await com.stop()
             svc.close()

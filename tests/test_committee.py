@@ -72,6 +72,12 @@ def test_seven_node_committee():
         com.start()
         try:
             assert await com.clients[0].submit("put a 1") == "ok"
+            # f+1 replies answer the client; let the slowest replica
+            # apply the block before the replicas are counted
+            for _ in range(250):
+                if all(r.executed_seq == 1 for r in com.replicas):
+                    break
+                await asyncio.sleep(0.02)
         finally:
             await com.stop()
         assert sum(r.executed_seq == 1 for r in com.replicas) == 7
@@ -107,6 +113,12 @@ def test_progress_under_message_duplication():
         try:
             for i in range(5):
                 assert await com.clients[0].submit(f"put x{i} {i}") == "ok"
+            # f+1 replies answer the client; let the slowest replica
+            # apply the last block before the states are compared
+            for _ in range(250):
+                if len({r.executed_seq for r in com.replicas}) == 1:
+                    break
+                await asyncio.sleep(0.02)
         finally:
             await com.stop()
         digests = {r.app.state_digest() for r in com.replicas}

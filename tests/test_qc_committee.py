@@ -58,22 +58,22 @@ def test_qc_byzantine_share_bisected():
         evil = com.replica("r3")
         from simple_pbft_tpu.consensus import qc as qc_mod
 
-        orig = qc_mod.sign_share
+        orig = qc_mod.sign_share_async
         calls = {"n": 0}
 
-        def corrupt(sk, phase, view, seq, digest):
+        async def corrupt(sk, phase, view, seq, digest):
             if sk == evil.bls_sk:
                 calls["n"] += 1
-                return orig(sk, phase, view + 1000, seq, digest)
-            return orig(sk, phase, view, seq, digest)
+                return await orig(sk, phase, view + 1000, seq, digest)
+            return await orig(sk, phase, view, seq, digest)
 
-        qc_mod.sign_share = corrupt
+        qc_mod.sign_share_async = corrupt
         com.start()
         try:
             assert await com.clients[0].submit("put z 9") == "ok"
             await asyncio.sleep(0.5)
         finally:
-            qc_mod.sign_share = orig
+            qc_mod.sign_share_async = orig
             await com.stop()
         primary = com.replica("r0")
         assert calls["n"] >= 1  # the corrupt path actually ran
